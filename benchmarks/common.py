@@ -8,21 +8,12 @@ import time
 
 import numpy as np
 
-# measured on the 2-core CPU host: the legacy runtime executes this CNN's
-# train step ~15% faster than the thunk runtime (EXPERIMENTS.md §Engine)
-os.environ.setdefault("XLA_FLAGS", "--xla_cpu_use_thunk_runtime=false")
-try:                                 # compile-dominated 2-core host: reuse
-    import jax                       # XLA programs across benchmark runs
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-except Exception:                    # pragma: no cover
-    pass
-
 from repro.channel.params import ChannelParams
+from repro.compile_cache import configure_compile_cache
 from repro.core import run_simulation
 from repro.data import partition_vehicles, synth_mnist
+
+configure_compile_cache()       # reuse XLA programs across benchmark runs
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
 

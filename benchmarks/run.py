@@ -51,11 +51,6 @@ import os
 import sys
 import time
 
-# before any jax import: the legacy CPU runtime runs the paper CNN's train
-# step ~15% faster than the thunk runtime on this host (benchmarks only —
-# the library itself never forces backend flags)
-os.environ.setdefault("XLA_FLAGS", "--xla_cpu_use_thunk_runtime=false")
-
 
 def run_scenario_cmd(argv) -> None:
     from repro.core.scenarios import list_scenarios, run_scenario

@@ -15,33 +15,13 @@ sweep compiles once per batch, so both cold and warm timings are reported.
 ``python -m benchmarks.run sweep``; QUICK=1 swaps in a W=4 quick-k5 grid
 (2 betas x 2 seeds) with every serial world measured — the CI smoke
 artifact.
-
-This lane runs under XLA:CPU's **default thunk runtime**, not the legacy
-runtime the other benchmark lanes select for its ~15% faster train step:
-the legacy runtime contracts FMAs differently across the sweep and solo
-program structures, so the bitwise cross-check (and the conformance
-contract it mirrors — the tier-1 suite also runs under the default
-runtime) only holds on the thunk runtime.  The flag is stripped below
-before jax initializes; when another lane already initialized jax in
-this process (``benchmarks.run all``), ``run()`` re-execs this module in
-a clean subprocess instead.
 """
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
 import time
 
-_LEGACY = "--xla_cpu_use_thunk_runtime=false"
-_FOREIGN_RUNTIME = (_LEGACY in os.environ.get("XLA_FLAGS", "")
-                    and "jax" in sys.modules)
-if not _FOREIGN_RUNTIME and _LEGACY in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = " ".join(
-        t for t in os.environ["XLA_FLAGS"].split() if t != _LEGACY)
-
-from benchmarks.common import RESULTS_DIR, SEEDS, save_result
+from benchmarks.common import SEEDS, save_result
 from repro.checkpointing.checkpoint import tree_digest
 from repro.core.scenarios import SweepSpec, run_scenario, run_sweep
 
@@ -63,18 +43,6 @@ def _grid_spec(quick: bool) -> SweepSpec:
 
 
 def run(quick: bool = False) -> dict:
-    if _FOREIGN_RUNTIME:
-        # jax already came up on the legacy runtime in this process: the
-        # bitwise cross-check needs the thunk runtime, so measure in a
-        # clean subprocess and read back the artifact it wrote
-        env = dict(os.environ, QUICK="1" if quick else "0")
-        env["XLA_FLAGS"] = " ".join(
-            t for t in env.get("XLA_FLAGS", "").split() if t != _LEGACY)
-        subprocess.run([sys.executable, "-m", "benchmarks.sweep_bench"],
-                       check=True, env=env)
-        name = "BENCH_sweep_quick" if quick else "BENCH_sweep"
-        with open(os.path.join(RESULTS_DIR, f"{name}.json")) as f:
-            return json.load(f)
     spec = _grid_spec(quick)
     worlds = spec.worlds()
     W = len(worlds)

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.check.findings import Finding
 
@@ -35,7 +36,7 @@ CONTRACTION_PRIMS = frozenset({"dot_general", "conv_general_dilated"})
 # structured control flow / call primitives: their bodies are walked
 # separately, so the wrapper eqn itself is not an arithmetic consumer
 _WRAPPER_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "xla_call", "scan", "while",
+    "pjit", "jit", "closed_call", "core_call", "xla_call", "scan", "while",
     "cond", "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
     "remat", "remat2", "checkpoint", "custom_lin", "pallas_call",
 })
@@ -45,9 +46,9 @@ def _sub_jaxprs(params):
     for v in params.values():
         vs = v if isinstance(v, (tuple, list)) else (v,)
         for x in vs:
-            if isinstance(x, jax.core.ClosedJaxpr):
+            if isinstance(x, ClosedJaxpr):
                 yield x.jaxpr
-            elif isinstance(x, jax.core.Jaxpr):
+            elif isinstance(x, Jaxpr):
                 yield x
 
 
@@ -69,7 +70,7 @@ def check_jaxpr(jaxpr, *, allow_bf16: bool, path: str) -> list[Finding]:
     """DTF findings for one (closed or open) jaxpr.  One finding per
     (rule, primitive) with an occurrence count — a single bad chain shows
     up in hundreds of eqns and a per-eqn flood would bury the report."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     counts: dict = {}
 

@@ -16,7 +16,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -71,8 +70,8 @@ def cross_pod_reconcile(params, mesh, pod_axis: str = "pod",
             return mean
         return ema_toward(t, mean, tau, use_kernel=use_kernel)
 
-    fn = shard_map(step, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                   check_rep=False)
+    fn = jax.shard_map(step, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                       check_vma=False)
     return fn(params)
 
 

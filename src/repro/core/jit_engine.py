@@ -287,14 +287,21 @@ def _wave_train(local_scan, mesh, n_events, shared: bool,
     n_data = mesh.shape["data"]
     if n_events % n_data != 0:
         return f                      # ragged wave: replicate instead
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     pay_spec = P() if shared else P("data")
     in_specs = ((pay_spec, P("data"), P("data"), P())
                 + ((P("data"),) if partial else ()))
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=(P("data"), P("data")), check_rep=False)
+    sharded = jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                            out_specs=(P("data"), P("data")),
+                            check_vma=False)
+
+    def train(*args):
+        # the wave's outputs are scattered into the replicated upload
+        # buffer; under an explicit-axis mesh that scatter cannot infer its
+        # out-sharding from a "data"-sharded update, so replicate first
+        return jax.sharding.reshard(sharded(*args), NamedSharding(mesh, P()))
+    return train
 
 
 def _ring_interpret(use_kernel: bool):

@@ -193,7 +193,6 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
         fct_tab = jnp.asarray(flt_plan.counts_table(l_iters))
 
     if n_shards > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
     def aggregate(g, loc, t, cu, cl, dl_t):
@@ -374,11 +373,11 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
         # cohort stack sharded over the RSU axis; queue columns (and the
         # bandit accumulators, when carried) replicated
         st_spec = (P(_RSU_AXIS),) + (P(),) * (len(st) - 1)
-        fn = shard_map(
+        fn = jax.shard_map(
             seg_fn, mesh=mesh,
             in_specs=(st_spec, P(), P(), P(), P()),
             out_specs=(st_spec, P(), P()),
-            check_rep=False)
+            check_vma=False)
         return fn(st, locals_buf, gains, x0, qcl)
 
     def reconcile(G):
@@ -392,8 +391,8 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
                 lambda x: jax.lax.pmean(x, _RSU_AXIS), stack_mean(G))
             return mix_rows(G, cons)
 
-        return shard_map(rec_fn, mesh=mesh, in_specs=(P(_RSU_AXIS),),
-                         out_specs=P(_RSU_AXIS), check_rep=False)(G)
+        return jax.shard_map(rec_fn, mesh=mesh, in_specs=(P(_RSU_AXIS),),
+                             out_specs=P(_RSU_AXIS), check_vma=False)(G)
 
     def consensus(G):
         """Corridor-wide model (mean of cohorts) for eval/final params."""
@@ -406,8 +405,8 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
             return jax.tree_util.tree_map(
                 lambda x: jax.lax.pmean(x, _RSU_AXIS), stack_mean(G))
 
-        cons = shard_map(cons_fn, mesh=mesh, in_specs=(P(_RSU_AXIS),),
-                         out_specs=P(), check_rep=False)(G)
+        cons = jax.shard_map(cons_fn, mesh=mesh, in_specs=(P(_RSU_AXIS),),
+                             out_specs=P(), check_vma=False)(G)
         return jax.tree_util.tree_map(
             lambda x, g: x.astype(g.dtype), cons,
             jax.tree_util.tree_map(lambda g: g[0], G))
@@ -424,8 +423,8 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
                     jnp.where(mine, x[j % Rl], jnp.zeros_like(x[j % Rl])),
                     _RSU_AXIS), G)
 
-        return shard_map(pick, mesh=mesh, in_specs=(P(_RSU_AXIS),),
-                         out_specs=P(), check_rep=False)(G)
+        return jax.shard_map(pick, mesh=mesh, in_specs=(P(_RSU_AXIS),),
+                             out_specs=P(), check_vma=False)(G)
 
     def gather_cohorts(G):
         """Full [R, ...] stack on every device (cohort snapshots only)."""
@@ -436,8 +435,8 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
             return jax.tree_util.tree_map(
                 lambda x: jax.lax.all_gather(x, _RSU_AXIS, tiled=True), G)
 
-        return shard_map(allg, mesh=mesh, in_specs=(P(_RSU_AXIS),),
-                         out_specs=P(), check_rep=False)(G)
+        return jax.shard_map(allg, mesh=mesh, in_specs=(P(_RSU_AXIS),),
+                             out_specs=P(), check_vma=False)(G)
 
     eval_set = set(eval_rounds)
     reconcile_set = {b for b in range(reconcile_every, M + 1,

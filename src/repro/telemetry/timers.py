@@ -58,22 +58,15 @@ def memory_stats() -> dict:
     """Process peak RSS plus backend allocator stats when available.
 
     ``ru_maxrss`` is KiB on Linux; ``device.memory_stats()`` is only
-    populated on backends with an instrumented allocator (absent on the
-    CPU backend — the keys are simply omitted there)."""
-    out: dict = {}
-    try:
-        import resource
-        out["peak_rss_bytes"] = int(
-            resource.getrusage(resource.RUSAGE_SELF).ru_maxrss) * 1024
-    except Exception:  # pragma: no cover - non-POSIX
-        pass
-    try:
-        import jax
-        stats = jax.local_devices()[0].memory_stats()
-        if stats:
-            for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
-                if k in stats:
-                    out[f"device_{k}"] = int(stats[k])
-    except Exception:  # pragma: no cover - backend without allocator stats
-        pass
+    populated on backends with an instrumented allocator (None on the CPU
+    backend — the device keys are omitted there)."""
+    import resource
+
+    import jax
+    out = {"peak_rss_bytes": int(
+        resource.getrusage(resource.RUSAGE_SELF).ru_maxrss) * 1024}
+    stats = jax.local_devices()[0].memory_stats() or {}
+    for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
+        if k in stats:
+            out[f"device_{k}"] = int(stats[k])
     return out

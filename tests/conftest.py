@@ -5,16 +5,8 @@ import sys
 # in its own process) — per the brief, never set the device-count flag here.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# Persistent XLA compilation cache: the suite is compile-dominated on a
-# 2-core CPU host, and every process re-paid every trace before this.
-# Warm re-runs of the tier-1 lane skip most compile time; cold runs are
-# unaffected except for writing the cache.
-try:
-    import jax
+# Persistent XLA compilation cache: the suite is compile-dominated, and
+# warm re-runs of the tier-1 lane skip most compile time.
+from repro.compile_cache import configure_compile_cache  # noqa: E402
 
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-except Exception:                                    # pragma: no cover
-    pass
+configure_compile_cache()

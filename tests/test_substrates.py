@@ -131,7 +131,7 @@ def test_param_specs_structure_and_rules():
     from repro.launch.steps import param_shapes
     from repro.sharding import param_specs
 
-    mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cfg = get_config("llama3-405b")
     specs = param_specs(cfg, mesh, fsdp=True)
     shapes = param_shapes(cfg)
@@ -150,7 +150,7 @@ def test_param_specs_degrade_on_indivisible():
     from repro.configs import get_config
     from repro.sharding import param_specs
 
-    mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cfg = get_config("smollm-360m")          # 15 heads: not divisible by 16
     specs = param_specs(cfg, mesh, fsdp=False)
     wq_spec = specs["stack"]["sub0"]["mixer"]["wq"]
@@ -164,7 +164,7 @@ def test_cache_specs_shard_batch_and_seq():
     from repro.configs import get_config
     from repro.sharding import cache_specs
 
-    mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cfg = get_config("mistral-nemo-12b")
     specs = cache_specs(cfg, mesh, batch=128, max_seq=32768)
     kspec = specs["stack"]["sub0"]["mixer"]["k"]
