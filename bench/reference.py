@@ -1,0 +1,401 @@
+"""Plain reference of one MAFL study: the yardstick that decides `correct`.
+
+A straightforward, sequential implementation of the semantics a
+configuration file states, written from the paper (arXiv:2208.01901
+Section III, Eqs. 1-11, Table I) and the configuration alone.  It imports
+nothing of the program under test and takes nothing the program made: the
+synthetic data set, the vehicles' shards, the Rayleigh channel, the event
+timeline, the initial CNN weights and every minibatch are drawn here from
+the seed, in the same order and through the same numpy / jax.random calls
+that the configuration's semantics name.
+
+One study, in order of simulated time:
+
+1. every vehicle downloads the initial model at t = 0, trains for C_l
+   (Eq. 8), uploads for C_u (Eqs. 3-6, Rayleigh AR(1) gain of the slot in
+   which the upload starts) and arrives at t_download + C_l + C_u;
+2. the RSU pops arrivals in time order (ties by scheduling order); the
+   arriving vehicle's upload is one SGD step (Eqs. 1-2) from the model it
+   downloaded, stored at the configuration's upload width, and is mixed
+   into the model of the RSU serving the vehicle at arrival:
+   ``g <- (1 - a) g + a l`` with ``a = clip((1 - beta) gamma^(C_u - 1)
+   zeta^(C_l - 1), 0, 1)`` (Eqs. 7, 9, 10-11, mixing reading);
+3. the vehicle downloads again at once, from that RSU (the stored
+   snapshot of its model after this arrival);
+4. with several RSUs, every ``reconcile_every`` arrivals all cohort models
+   adopt their mean (FedAvg) before the re-download;
+5. every ``eval_every`` arrivals and at the last one the test set is
+   evaluated on the stored snapshot (one RSU) or on the mean of the cohort
+   models (several RSUs).
+
+Arithmetic is float32, with convolutions and matmuls at ``HIGHEST``
+precision; only the stored snapshot and upload rows take the
+configuration's storage dtype.
+"""
+from __future__ import annotations
+
+import heapq
+import math
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+STORAGE_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16,
+                  "fp8": jnp.float8_e4m3fn}
+# the nearest storage one step below each stated width: the control
+NEXT_LOWER = {"f32": "bf16", "bf16": "fp8"}
+
+
+# ---------------------------------------------------------------------------
+# data: the synthetic MNIST stand-in and the Section V-A partition
+# ---------------------------------------------------------------------------
+def _blur(img):
+    k = (0.25, 0.5, 0.25)
+    for ax in (0, 1):
+        n = img.shape[ax]
+        img = (np.take(img, np.arange(n) - 1, axis=ax, mode="clip") * k[0]
+               + img * k[1]
+               + np.take(img, np.arange(n) + 1, axis=ax, mode="clip") * k[2])
+    return img
+
+
+def synthetic_digits(n_train, n_test, noise, seed=0, n_classes=10):
+    """Ten smooth random class prototypes on 28x28, each sample shifted by
+    up to 2 px and given Gaussian noise, clipped to [0, 1]."""
+    rng = np.random.default_rng(seed)
+    protos = []
+    for _ in range(n_classes):
+        img = _blur(np.kron(rng.normal(size=(7, 7)), np.ones((4, 4))))
+        protos.append((img - img.min()) / (np.ptp(img) + 1e-9))
+    protos = np.stack(protos)
+
+    def make(n, rng):
+        labels = rng.integers(0, n_classes, n)
+        base = protos[labels]
+        sx = rng.integers(-2, 3, n)
+        sy = rng.integers(-2, 3, n)
+        imgs = np.empty((n, 28, 28), np.float32)
+        for dx in range(-2, 3):
+            for dy in range(-2, 3):
+                m = (sx == dx) & (sy == dy)
+                if m.any():
+                    imgs[m] = np.roll(np.roll(base[m], dx, axis=1), dy,
+                                      axis=2)
+        imgs += rng.normal(scale=noise, size=imgs.shape).astype(np.float32)
+        return np.clip(imgs, 0, 1)[..., None], labels.astype(np.int32)
+
+    tr = make(n_train, rng)
+    te = make(n_test, np.random.default_rng(seed + 1))
+    return tr + te
+
+
+# ---------------------------------------------------------------------------
+# the channel and the event timeline (Table I, Eqs. 3-9)
+# ---------------------------------------------------------------------------
+@dataclass
+class Channel:
+    K: int
+    v: float
+    H: float
+    d_y: float
+    C_y: float
+    model_bits: float
+    B: float
+    p_m: float
+    alpha: float
+    sigma2: float
+    beta: float
+    zeta: float
+    gamma: float
+    fading_rho: float
+    coverage: float
+    platoon: int = 0
+
+    def _leader(self, i1):
+        """Vehicles in platoons of ``platoon`` share their leader's data
+        volume and CPU, so their training delays are equal."""
+        if self.platoon > 1:
+            return ((i1 - 1) // self.platoon) * self.platoon + 1
+        return i1
+
+    def data_count(self, i1):
+        """D_i of the 1-based vehicle index (Section V-A)."""
+        return 2250 + 3750 * self._leader(i1)
+
+    def train_delay(self, i1):
+        """Eq. 8 with delta_i = 1.5 (i + 5) 1e8 cycles/s."""
+        return self.data_count(i1) * self.C_y / (
+            1.5 * (self._leader(i1) + 5) * 1e8)
+
+    def upload_delay(self, gain, dist):
+        """Eqs. 5-6."""
+        rate = self.B * math.log2(1.0 + self.p_m * gain * dist ** -self.alpha
+                                  / self.sigma2)
+        return self.model_bits / max(rate, 1e-12)
+
+
+class Road:
+    """Positions (Eq. 3) and the distance to the serving RSU (Eq. 4).  RSU
+    j sits at the centre of segment j of width 2*coverage; a vehicle is
+    served by the segment it is in (hard handover at the edges) and
+    re-enters at the west end.  The fleet starts spread evenly over the
+    road (``uniform``) or packed into the westmost segment (``rush``)."""
+
+    def __init__(self, ch: Channel, n_rsus: int, entry: str = "uniform"):
+        self.ch = ch
+        self.n = n_rsus
+        self.cell = 2.0 * ch.coverage
+        self.span = self.cell * n_rsus
+        width = {"uniform": self.span, "rush": self.cell}[entry]
+        self.x0 = -self.span / 2 + width * (np.arange(ch.K) / ch.K)
+
+    def x(self, i, t):
+        return ((self.x0[i] + self.ch.v * t + self.span / 2) % self.span
+                - self.span / 2)
+
+    def rsu(self, i, t):
+        j = int((self.x(i, t) + self.span / 2) // self.cell)
+        return min(max(j, 0), self.n - 1)
+
+    def distance(self, i, t):
+        centre = -self.span / 2 + (self.rsu(i, t) + 0.5) * self.cell
+        return math.sqrt((self.x(i, t) - centre) ** 2 + self.ch.d_y ** 2
+                         + self.ch.H ** 2)
+
+
+class RayleighGains:
+    """|g|^2 of a per-vehicle complex Gaussian with AR(1) coherence rho,
+    sampled once per one-second slot: slot s is s + 1 steps from the
+    initial draw (real then imaginary parts, then per slot a (2, K) block
+    of innovations)."""
+
+    def __init__(self, ch: Channel, seed):
+        self.rng = np.random.default_rng(seed)
+        self.rho = ch.fading_rho
+        self.K = ch.K
+        self.g = (self.rng.normal(size=ch.K)
+                  + 1j * self.rng.normal(size=ch.K)) / np.sqrt(2)
+        self.slots = []
+
+    def at(self, t):
+        s = int(t)
+        while len(self.slots) <= s:
+            n = s + 1 - len(self.slots)
+            z = self.rng.normal(size=(n, 2, self.K))
+            z = (z[:, 0] + 1j * z[:, 1]) / np.sqrt(2)
+            for row in z:
+                self.g = self.rho * self.g + np.sqrt(1 - self.rho ** 2) * row
+                self.slots.append(np.abs(self.g) ** 2)
+        return self.slots[s]
+
+
+def timeline(ch: Channel, n_rsus: int, seed, rounds, entry="uniform"):
+    """The first ``rounds`` arrivals: per round the vehicle, its serving
+    RSU at arrival, its arrival time, C_u, C_l and the round after which
+    it downloaded (-1: the initial model)."""
+    road = Road(ch, n_rsus, entry)
+    gains = RayleighGains(ch, seed)
+    heap, seq = [], 0
+
+    def schedule(i, t_dl):
+        nonlocal seq
+        c_l = ch.train_delay(i + 1)
+        t_up = t_dl + c_l
+        c_u = ch.upload_delay(gains.at(t_up)[i], road.distance(i, t_up))
+        heapq.heappush(heap, (t_up + c_u, seq, i, c_u, c_l))
+        seq += 1
+
+    for i in range(ch.K):
+        schedule(i, 0.0)
+    out = {k: [] for k in ("veh", "rsu", "time", "c_u", "c_l", "dl_round")}
+    last = {}
+    for r in range(rounds):
+        t, _, i, c_u, c_l = heapq.heappop(heap)
+        out["veh"].append(i)
+        out["rsu"].append(road.rsu(i, t))
+        out["time"].append(t)
+        out["c_u"].append(c_u)
+        out["c_l"].append(c_l)
+        out["dl_round"].append(last.get(i, -1))
+        last[i] = r
+        schedule(i, t)
+    return {k: np.asarray(v) for k, v in out.items()}
+
+
+# ---------------------------------------------------------------------------
+# the paper's CNN (Section V-A) and plain SGD
+# ---------------------------------------------------------------------------
+def init_cnn(seed, cnn):
+    """HWIO convolutions and dense layers, N(0, 1/fan_in), zero biases;
+    one jax.random split of the seed's key per weight."""
+    c1, c2, f1, nc = (cnn["conv1"], cnn["conv2"], cnn["fc1"],
+                      cnn["classes"])
+    k = cnn["kernel"]
+    flat = (cnn["image"] // 4) ** 2 * c2
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return {
+        "conv1_w": jax.random.normal(ks[0], (k, k, 1, c1)) / np.sqrt(k * k),
+        "conv1_b": jnp.zeros((c1,), jnp.float32),
+        "conv2_w": (jax.random.normal(ks[1], (k, k, c1, c2))
+                    / np.sqrt(k * k * c1)),
+        "conv2_b": jnp.zeros((c2,), jnp.float32),
+        "fc1_w": jax.random.normal(ks[2], (flat, f1)) / np.sqrt(flat),
+        "fc1_b": jnp.zeros((f1,), jnp.float32),
+        "fc2_w": jax.random.normal(ks[3], (f1, nc)) / np.sqrt(f1),
+        "fc2_b": jnp.zeros((nc,), jnp.float32),
+    }
+
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _conv(x, w):
+    return jax.lax.conv_general_dilated(
+        x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=HIGHEST)
+
+
+def _pool(x):
+    b, h, w, c = x.shape
+    return x.reshape(b, h // 2, 2, w // 2, 2, c).max(axis=(2, 4))
+
+
+def forward(p, x):
+    x = _pool(jax.nn.relu(_conv(x, p["conv1_w"]) + p["conv1_b"]))
+    x = _pool(jax.nn.relu(_conv(x, p["conv2_w"]) + p["conv2_b"]))
+    x = jax.nn.relu(jnp.dot(x.reshape(x.shape[0], -1), p["fc1_w"],
+                            precision=HIGHEST) + p["fc1_b"])
+    return jnp.dot(x, p["fc2_w"], precision=HIGHEST) + p["fc2_b"]
+
+
+def _nll(p, x, y):
+    logp = jax.nn.log_softmax(forward(p, x), axis=-1)
+    return -jnp.take_along_axis(logp, y[:, None], axis=-1)[:, 0]
+
+
+@jax.jit
+def _local_update(p, xs, ys, lr):
+    """len(xs) SGD steps (Eq. 2) on the mean cross-entropy (Eq. 1)."""
+    for x, y in zip(xs, ys):
+        g = jax.grad(lambda q: jnp.mean(_nll(q, x, y)))(p)
+        p = jax.tree_util.tree_map(lambda w, d: w - lr * d, p, g)
+    return p
+
+
+@jax.jit
+def _evaluate(p, x, y):
+    logits = forward(p, x)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, y[:, None], axis=-1)[:, 0]
+    return (jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32)),
+            jnp.mean(nll))
+
+
+@jax.jit
+def _mix(g, l, c, d):
+    return jax.tree_util.tree_map(
+        lambda a, b: c * a + d * b.astype(jnp.float32), g, l)
+
+
+@jax.jit
+def _mean(*gs):
+    return jax.tree_util.tree_map(lambda *xs: sum(xs) / len(xs), *gs)
+
+
+def _store(p, dtype):
+    return jax.tree_util.tree_map(lambda x: x.astype(dtype), p)
+
+
+def _widen(p):
+    return jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), p)
+
+
+# ---------------------------------------------------------------------------
+# one world, many studies
+# ---------------------------------------------------------------------------
+class World:
+    """Everything a seed fixes: data, shards, timeline, initial weights and
+    the minibatch of every arrival.  ``study`` then runs one study at a
+    learning rate and a storage width."""
+
+    def __init__(self, cfg: dict, seed):
+        sc = cfg["scenario"]
+        covered = {"scheme": "mafl", "dirichlet_alpha": None,
+                   "reconcile_mode": "fedavg", "selection": None,
+                   "faults": None}
+        for key, value in covered.items():
+            if sc.get(key) != value:
+                raise ValueError(f"the reference covers {key}={value!r}, "
+                                 f"not {sc.get(key)!r}")
+        self.cfg = cfg
+        self.sc = sc
+        self.ch = Channel(K=sc["K"], **cfg["channel"])
+        tr_x, tr_y, self.te_x, self.te_y = synthetic_digits(
+            sc["n_train"], sc["n_test"], sc["noise"])
+        rng = np.random.default_rng(seed)
+        shards = []
+        for i1 in range(1, sc["K"] + 1):
+            d = max(int(self.ch.data_count(i1) * sc["scale"]), 8)
+            if sc["max_per_vehicle"] is not None:
+                d = min(d, sc["max_per_vehicle"])
+            shards.append(rng.choice(len(tr_y), size=min(d, len(tr_y)),
+                                     replace=False))
+        self.trace = timeline(self.ch, sc["n_rsus"], seed, sc["rounds"],
+                              sc["corridor_entry"])
+        b = min(128, min(len(s) for s in shards))
+        vrng = {}
+        xs, ys = [], []
+        for i in self.trace["veh"]:
+            r = vrng.setdefault(i, np.random.default_rng(seed + i + 1))
+            pick = np.stack([r.choice(len(shards[i]), b, replace=False)
+                             for _ in range(sc["l_iters"])])
+            idx = shards[i][pick]
+            xs.append(tr_x[idx])
+            ys.append(tr_y[idx])
+        self.xs = jnp.asarray(np.stack(xs))
+        self.ys = jnp.asarray(np.stack(ys))
+        self.w0 = init_cnn(seed, cfg["cnn"])
+        c = self.ch
+        a = np.clip((1.0 - c.beta) * c.gamma ** (self.trace["c_u"] - 1.0)
+                    * c.zeta ** (self.trace["c_l"] - 1.0), 0.0, 1.0)
+        self.coeffs = [(jnp.float32(1.0 - x), jnp.float32(x)) for x in a]
+
+    def start(self, storage="f32"):
+        """The model every vehicle first downloads: the initial weights
+        at the storage width, widened to f32, on the host."""
+        return jax.device_get(_widen(_store(self.w0,
+                                            STORAGE_DTYPES[storage])))
+
+    def answer(self, lr, eval_every, storage) -> dict:
+        """A study in the form ``compare.numbers`` reads."""
+        final, evals = self.study(lr, eval_every, storage)
+        return {"trace": list(zip(self.trace["veh"].tolist(),
+                                  self.trace["rsu"].tolist())),
+                "final": final, "losses": [(r, lo) for r, _, lo in evals]}
+
+    def study(self, lr, eval_every, storage="f32"):
+        """Final model (f32 pytree) and ``[(round, accuracy, loss)]``."""
+        dt = STORAGE_DTYPES[storage]
+        R = self.sc["n_rsus"]
+        M = self.sc["rounds"]
+        every = self.sc["reconcile_every"] if R > 1 else 0
+        G = [self.w0] * R
+        ring = [_store(self.w0, dt)]
+        evals = []
+        lr = jnp.float32(lr)
+        for r in range(M):
+            j = int(self.trace["rsu"][r])
+            pay = _widen(ring[int(self.trace["dl_round"][r]) + 1])
+            loc = _store(_local_update(pay, self.xs[r], self.ys[r], lr), dt)
+            G[j] = _mix(G[j], loc, *self.coeffs[r])
+            if every and (r + 1) % every == 0:
+                G = [_mean(*G)] * R
+            ring.append(_store(G[j], dt))
+            if (r + 1) % eval_every == 0 or r + 1 == M:
+                model = _widen(ring[-1]) if R == 1 else _mean(*G)
+                acc, loss = _evaluate(model, self.te_x, self.te_y)
+                evals.append((r + 1, float(acc), float(loss)))
+        final = G[0] if R == 1 else _mean(*G)
+        return jax.device_get(final), evals

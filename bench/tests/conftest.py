@@ -1,0 +1,7 @@
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+sys.path[:0] = [os.path.join(os.path.dirname(BENCH), "src"), BENCH]
