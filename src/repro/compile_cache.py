@@ -4,6 +4,11 @@
 the deployment decides where compiled programs live.  Otherwise the cache
 goes to ``.jax_cache`` at the root of this checkout — a fixed path, because
 the directory is part of what a later process looks the cache up by.
+
+Either way the cache key includes the program's metadata: a profile reads
+its ``jax.named_scope`` names from the executable, and a key without them
+would hand one program's executable, scope names and all, to another that
+differs only in them.
 """
 from __future__ import annotations
 
@@ -18,8 +23,9 @@ DEFAULT_DIR = os.path.join(
 
 def configure_compile_cache() -> str:
     """Point JAX's persistent compilation cache at its directory and return
-    that directory.  Sets nothing when ``JAX_COMPILATION_CACHE_DIR`` is
-    set."""
+    that directory.  Sets no directory when ``JAX_COMPILATION_CACHE_DIR``
+    is set."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -62,7 +62,6 @@ scheme, both of which carry host-side state between arrivals.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -938,47 +937,52 @@ def _stage_run(vehicles_data, *, scheme, rounds, l_iters, lr, params, seed,
             fault_counters=plan.flt is not None)
     M = rounds
 
-    _t0 = time.perf_counter()
-    key = jax.random.PRNGKey(seed)
-    w0 = init_params if init_params is not None else init_cnn(key)
+    with timers.phase("stage"):
+        key = jax.random.PRNGKey(seed)
+        w0 = init_params if init_params is not None else init_cnn(key)
 
-    # one minibatch stack per consumed round, drawn from the same
-    # per-vehicle RNG streams in the same per-cycle order as the host
-    # engines (DESIGN.md §3), so every engine trains identical batches
-    fleet_batch = min(batch_size, min(d.size for d in vehicles_data))
-    clients = [Vehicle(d, lr=lr, batch_size=fleet_batch, seed=seed)
-               for d in vehicles_data]
-    im_list, lab_list = [], []
-    for r in range(M):
-        im, lab = clients[plan.veh[r]].sample_batches(l_iters)
-        im_list.append(im)
-        lab_list.append(lab)
-    imgs = jnp.asarray(np.stack(im_list))
-    labs = jnp.asarray(np.stack(lab_list))
+        # one minibatch stack per consumed round, drawn from the same
+        # per-vehicle RNG streams in the same per-cycle order as the host
+        # engines (DESIGN.md §3), so every engine trains identical batches
+        fleet_batch = min(batch_size, min(d.size for d in vehicles_data))
+        clients = [Vehicle(d, lr=lr, batch_size=fleet_batch, seed=seed)
+                   for d in vehicles_data]
+        im_list, lab_list = [], []
+        for r in range(M):
+            im, lab = clients[plan.veh[r]].sample_batches(l_iters)
+            im_list.append(im)
+            lab_list.append(lab)
+        imgs = jnp.asarray(np.stack(im_list))
+        labs = jnp.asarray(np.stack(lab_list))
 
-    gains = jnp.asarray(slot_gain_table(p, seed, plan.n_slots), jnp.float32)
-    x0 = jnp.asarray(Mobility(p).x0, jnp.float32)
-    qt = jnp.asarray(plan.q0["time"], jnp.float32)
-    qdl = jnp.asarray(plan.q0["download_time"], jnp.float32)
-    qcu = jnp.asarray(plan.q0["upload_delay"], jnp.float32)
-    qcl = jnp.asarray(plan.q0["train_delay"], jnp.float32)
+        gains = jnp.asarray(slot_gain_table(p, seed, plan.n_slots),
+                            jnp.float32)
+        x0 = jnp.asarray(Mobility(p).x0, jnp.float32)
+        qt = jnp.asarray(plan.q0["time"], jnp.float32)
+        qdl = jnp.asarray(plan.q0["download_time"], jnp.float32)
+        qcu = jnp.asarray(plan.q0["upload_delay"], jnp.float32)
+        qcl = jnp.asarray(plan.q0["train_delay"], jnp.float32)
 
-    shapes = (imgs.shape, tuple(
-        (str(path), v.shape, str(v.dtype))
-        for path, v in jax.tree_util.tree_leaves_with_path(w0)))
-    layout = ParamLayout.from_tree(w0) if flat else None
-    eval_rounds = tuple(rr for rr in range(1, M + 1)
-                        if rr % eval_every == 0 or rr == rounds)
-    prog = _get_program(plan, p, scheme=scheme, interpretation=interpretation,
-                        use_kernel=use_kernel, mesh=mesh,
-                        fedasync_mix=DEFAULT_FEDASYNC_MIX, shapes=shapes,
-                        flat_layout=layout, ring_dtype=ring_dtype,
-                        eval_rounds=eval_rounds, metrics=met,
-                        l_iters=l_iters)
-    with_state = (plan.sel is not None and not plan.sel.is_noop
-                  and plan.sel.spec.policy == "eps-bandit")
-    args = (w0, gains, x0, qt, qdl, qcu, qcl, imgs, labs, jnp.float32(lr))
-    timers.add("stage", time.perf_counter() - _t0)
+        shapes = (imgs.shape, tuple(
+            (str(path), v.shape, str(v.dtype))
+            for path, v in jax.tree_util.tree_leaves_with_path(w0)))
+        layout = ParamLayout.from_tree(w0) if flat else None
+        eval_rounds = tuple(rr for rr in range(1, M + 1)
+                            if rr % eval_every == 0 or rr == rounds)
+        prog = _get_program(plan, p, scheme=scheme,
+                            interpretation=interpretation,
+                            use_kernel=use_kernel, mesh=mesh,
+                            fedasync_mix=DEFAULT_FEDASYNC_MIX, shapes=shapes,
+                            flat_layout=layout, ring_dtype=ring_dtype,
+                            eval_rounds=eval_rounds, metrics=met,
+                            l_iters=l_iters)
+        with_state = (plan.sel is not None and not plan.sel.is_noop
+                      and plan.sel.spec.policy == "eps-bandit")
+        args = (w0, gains, x0, qt, qdl, qcu, qcl, imgs, labs,
+                jnp.float32(lr))
+        # K samplers take milliseconds to free: inside the phase, not
+        # between phases on the way out
+        del clients
     return prog, args, plan, layout, eval_rounds, with_state, met
 
 
@@ -1057,62 +1061,65 @@ def run_simulation_jit(
     M = rounds
     with timers.phase("run"):
         out = jax.block_until_ready(prog(*args))
-    met_dev = None
-    if met is not None:
-        out, met_dev = out[:-1], out[-1]
-    if with_state:
-        g, ring, trace, (dev_rs, dev_rc) = out
-    else:
-        g, ring, trace = out
-    t_veh, t_time, t_cu, t_cl, t_dlt, t_w = (np.asarray(x) for x in trace)
+    with timers.phase("guard"):
+        met_dev = None
+        if met is not None:
+            out, met_dev = out[:-1], out[-1]
+        if with_state:
+            g, ring, trace, (dev_rs, dev_rc) = out
+        else:
+            g, ring, trace = out
+        t_veh, t_time, t_cu, t_cl, t_dlt, t_w = (np.asarray(x)
+                                                 for x in trace)
 
-    # divergence guard: the minibatch stacks were paired to rounds by the
-    # host plan — if the device pop order ever disagreed, fail loudly
-    # (mirrors the batched engine's dry-run guard) instead of silently
-    # training the wrong vehicle's batches.
-    if not np.array_equal(t_veh, plan.veh):
-        bad = int(np.argmax(t_veh != plan.veh))
-        raise RuntimeError(
-            "jit engine: device pop order diverged from the host dry run "
-            f"at round {bad} (device vehicle {int(t_veh[bad])}, host "
-            f"{int(plan.veh[bad])}) — f32 time ties are not expected")
-    if not np.allclose(t_time, plan.times, rtol=1e-4, atol=1e-3):
-        bad = int(np.argmax(~np.isclose(t_time, plan.times,
-                                        rtol=1e-4, atol=1e-3)))
-        raise RuntimeError(
-            "jit engine: device event times diverged from the host dry run "
-            f"at round {bad}: {t_time[bad]} vs {plan.times[bad]}")
-    if with_state:
-        # selection divergence guard (DESIGN.md §11): the f32 reward
-        # accumulators carried through the scan must reproduce the host
-        # f64 replay's — the admission decisions were planned from that
-        # reward stream, so disagreement means the device saw different
-        # arrivals than the masks were computed for
-        exp_rs, exp_rc = plan.sel_bandit
-        if not np.array_equal(np.asarray(dev_rc), exp_rc):
+        # divergence guard: the minibatch stacks were paired to rounds by
+        # the host plan — if the device pop order ever disagreed, fail
+        # loudly (mirrors the batched engine's dry-run guard) instead of
+        # silently training the wrong vehicle's batches.
+        if not np.array_equal(t_veh, plan.veh):
+            bad = int(np.argmax(t_veh != plan.veh))
             raise RuntimeError(
-                "jit engine: device bandit arrival counts diverged from "
-                "the host selection replay")
-        if not np.allclose(np.asarray(dev_rs), exp_rs,
-                           rtol=1e-4, atol=1e-3):
+                "jit engine: device pop order diverged from the host dry "
+                f"run at round {bad} (device vehicle {int(t_veh[bad])}, host "
+                f"{int(plan.veh[bad])}) — f32 time ties are not expected")
+        if not np.allclose(t_time, plan.times, rtol=1e-4, atol=1e-3):
+            bad = int(np.argmax(~np.isclose(t_time, plan.times,
+                                            rtol=1e-4, atol=1e-3)))
             raise RuntimeError(
-                "jit engine: device bandit reward accumulators diverged "
-                "from the host selection replay")
+                "jit engine: device event times diverged from the host dry "
+                f"run at round {bad}: {t_time[bad]} vs {plan.times[bad]}")
+        if with_state:
+            # selection divergence guard (DESIGN.md §11): the f32 reward
+            # accumulators carried through the scan must reproduce the
+            # host f64 replay's — the admission decisions were planned from
+            # that reward stream, so disagreement means the device saw
+            # different arrivals than the masks were computed for
+            exp_rs, exp_rc = plan.sel_bandit
+            if not np.array_equal(np.asarray(dev_rc), exp_rc):
+                raise RuntimeError(
+                    "jit engine: device bandit arrival counts diverged "
+                    "from the host selection replay")
+            if not np.allclose(np.asarray(dev_rs), exp_rs,
+                               rtol=1e-4, atol=1e-3):
+                raise RuntimeError(
+                    "jit engine: device bandit reward accumulators "
+                    "diverged from the host selection replay")
 
-    if flat and ring_dtype == "bf16":
-        # bf16 divergence guard (DESIGN.md §12): the timeline guards above
-        # stay exact (times never depend on params); the parameters may
-        # only diverge by bf16 rounding — a non-finite master means the
-        # quantized chain blew up, so fail loudly instead of returning it
-        if not all(bool(jnp.isfinite(x).all())
-                   for x in jax.tree_util.tree_leaves(g)):
-            raise RuntimeError(
-                "jit engine: non-finite master weights under "
-                "ring_dtype='bf16' — the quantized snapshot ring diverged "
-                "(rerun with ring_dtype='f32' to bisect)")
-    eval_idx = {rr: k for k, rr in enumerate(eval_rounds)}
-    result = SimResult(scheme=scheme, rounds=[], acc_history=[],
-                       loss_history=[], final_params=g)
+        if flat and ring_dtype == "bf16":
+            # bf16 divergence guard (DESIGN.md §12): the timeline guards
+            # above stay exact (times never depend on params); the
+            # parameters may only diverge by bf16 rounding — a non-finite
+            # master means the quantized chain blew up, so fail loudly
+            # instead of returning it
+            if not all(bool(jnp.isfinite(x).all())
+                       for x in jax.tree_util.tree_leaves(g)):
+                raise RuntimeError(
+                    "jit engine: non-finite master weights under "
+                    "ring_dtype='bf16' — the quantized snapshot ring "
+                    "diverged (rerun with ring_dtype='f32' to bisect)")
+        eval_idx = {rr: k for k, rr in enumerate(eval_rounds)}
+        result = SimResult(scheme=scheme, rounds=[], acc_history=[],
+                           loss_history=[], final_params=g)
     with timers.phase("eval"):
         for r in range(M):
             rec = RoundRecord(round=r + 1, time=float(t_time[r]),
@@ -1134,44 +1141,48 @@ def run_simulation_jit(
                 if progress:
                     progress(rr, acc)
             result.rounds.append(rec)
-    sel_summary = None if plan.sel is None else plan.sel.summary()
-    flt_plan = plan.flt
-    flt_report = None
-    if flt_plan is not None:
-        import dataclasses
-        result.extras["faults"] = flt_plan.summary(l_iters)
-        flt_report = {"spec": dataclasses.asdict(flt_plan.spec),
-                      "counts": flt_plan.counts(l_iters)}
-    p = params or ChannelParams()
-    channels = {}
-    if met is not None:
-        channels = {k: np.asarray(v) for k, v in met_dev.items()}
-        if flt_plan is not None and "fault_counts" in channels:
-            # fault-counter divergence guard (DESIGN.md §16): the scan-
-            # carry accumulators must reproduce the f64 fault replay's
-            # totals — disagreement means the device consumed a different
-            # pop sequence than the fault tables were planned for
-            exp = flt_plan.counts_table(l_iters).sum(axis=0)
-            if not np.array_equal(channels["fault_counts"], exp):
-                raise RuntimeError(
-                    "jit engine: device fault counters diverged from the "
-                    f"host fault replay ({channels['fault_counts']} vs "
-                    f"{exp})")
-        # bandit-style reward trace derived from the pop trace — the
-        # per-arrival quality signal the selection layer would score
-        # (gamma^(cu-1) * zeta^(cl-1)), published whether or not a
-        # bandit policy is active
-        channels["reward"] = (p.gamma ** (t_cu.astype(np.float64) - 1.0)
-                              * p.zeta ** (t_cl.astype(np.float64) - 1.0))
-        if with_state:
-            channels["reward_sum"] = np.asarray(dev_rs)
-            channels["reward_count"] = np.asarray(dev_rc)
+    with timers.phase("report"):
+        sel_summary = None if plan.sel is None else plan.sel.summary()
+        flt_plan = plan.flt
+        flt_report = None
+        if flt_plan is not None:
+            import dataclasses
+            result.extras["faults"] = flt_plan.summary(l_iters)
+            flt_report = {"spec": dataclasses.asdict(flt_plan.spec),
+                          "counts": flt_plan.counts(l_iters)}
+        p = params or ChannelParams()
+        channels = {}
+        if met is not None:
+            channels = {k: np.asarray(v) for k, v in met_dev.items()}
+            if flt_plan is not None and "fault_counts" in channels:
+                # fault-counter divergence guard (DESIGN.md §16): the scan-
+                # carry accumulators must reproduce the f64 fault replay's
+                # totals — disagreement means the device consumed a
+                # different pop sequence than the fault tables were planned
+                # for
+                exp = flt_plan.counts_table(l_iters).sum(axis=0)
+                if not np.array_equal(channels["fault_counts"], exp):
+                    raise RuntimeError(
+                        "jit engine: device fault counters diverged from "
+                        f"the host fault replay ({channels['fault_counts']} "
+                        f"vs {exp})")
+            # bandit-style reward trace derived from the pop trace — the
+            # per-arrival quality signal the selection layer would score
+            # (gamma^(cu-1) * zeta^(cl-1)), published whether or not a
+            # bandit policy is active
+            channels["reward"] = (
+                p.gamma ** (t_cu.astype(np.float64) - 1.0)
+                * p.zeta ** (t_cl.astype(np.float64) - 1.0))
+            if with_state:
+                channels["reward_sum"] = np.asarray(dev_rs)
+                channels["reward_count"] = np.asarray(dev_rc)
+        memory = memory_stats()
+        waves = wave_stats(plan.waves, p.K)
     result.report = RunReport(
         engine="jit", scheme=scheme, rounds=rounds, seed=seed,
         metrics_on=met is not None,
         spec=None if met is None else met.to_json(),
-        phases=timers.snapshot(), memory=memory_stats(),
-        selection=sel_summary, faults=flt_report,
-        waves=wave_stats(plan.waves, p.K),
-        channels=channels)
+        phases=timers.snapshot(), compile=timers.compile_counts(),
+        memory=memory, selection=sel_summary, faults=flt_report,
+        waves=waves, channels=channels)
     return result

@@ -362,8 +362,9 @@ def _host_report(*, engine, scheme, rounds, seed, metrics, met_req, p,
 
     report = RunReport(engine=engine, scheme=scheme, rounds=rounds,
                        seed=seed, metrics_on=met_req,
-                       phases=timers.snapshot(), memory=memory_stats(),
-                       selection=selection)
+                       phases=timers.snapshot(),
+                       compile=timers.compile_counts(),
+                       memory=memory_stats(), selection=selection)
     if faults is not None:
         import dataclasses
         report.faults = {"spec": dataclasses.asdict(faults.spec),

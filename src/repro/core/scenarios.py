@@ -382,7 +382,10 @@ def run_scenario(scenario: str | Scenario, *, seed: int = 0,
         return _stamp(run_simulation_vmap(
             [(sc, seed)], eval_every=eval_every, metrics=metrics,
             progress=cb)[0], sc)
-    veh, te_i, te_l, p = build_world(sc, seed=seed)
+    from repro.telemetry.timers import PhaseTimers
+    world = PhaseTimers()
+    with world.phase("world"):
+        veh, te_i, te_l, p = build_world(sc, seed=seed)
     if sc.n_rsus > 1:
         if eng == "serial":
             if mesh is not None or record_cohorts:
@@ -392,30 +395,41 @@ def run_scenario(scenario: str | Scenario, *, seed: int = 0,
                     "mesh/record_cohorts require engine='corridor'; the "
                     "serial reference runs unsharded and keeps no cohort "
                     "snapshots")
-            return _stamp(run_handover_simulation(
+            result = run_handover_simulation(
                 sc, veh, te_i, te_l, p, seed=seed, eval_every=eval_every,
                 use_kernel=use_kernel, progress=progress,
-                metrics=metrics, faults=flt), sc)
-        return _stamp(run_corridor_simulation(
-            sc, veh, te_i, te_l, p, seed=seed, eval_every=eval_every,
-            use_kernel=use_kernel, mesh=mesh,
-            record_cohorts=record_cohorts, progress=progress, flat=flat,
-            metrics=metrics, faults=flt), sc)
-    kw = {} if flat is None else {"flat": flat}
-    return _stamp(run_simulation(
-        veh, te_i, te_l, scheme=sc.scheme,
-        rounds=sc.rounds, l_iters=sc.l_iters, lr=sc.lr,
-        params=p, seed=seed, eval_every=eval_every,
-        use_kernel=use_kernel, engine=eng,
-        progress=progress, selection=sc.selection_spec(),
-        ring_dtype=sc.ring_dtype, metrics=metrics, faults=flt,
-        **kw), sc)
+                metrics=metrics, faults=flt)
+        else:
+            result = run_corridor_simulation(
+                sc, veh, te_i, te_l, p, seed=seed, eval_every=eval_every,
+                use_kernel=use_kernel, mesh=mesh,
+                record_cohorts=record_cohorts, progress=progress,
+                flat=flat, metrics=metrics, faults=flt)
+    else:
+        kw = {} if flat is None else {"flat": flat}
+        result = run_simulation(
+            veh, te_i, te_l, scheme=sc.scheme,
+            rounds=sc.rounds, l_iters=sc.l_iters, lr=sc.lr,
+            params=p, seed=seed, eval_every=eval_every,
+            use_kernel=use_kernel, engine=eng,
+            progress=progress, selection=sc.selection_spec(),
+            ring_dtype=sc.ring_dtype, metrics=metrics, faults=flt,
+            **kw)
+    with world.phase("world"):
+        # freeing K vehicles' shards takes milliseconds: inside the phase,
+        # not between phases on the way out
+        del veh, te_i, te_l
+    return _stamp(result, sc, world)
 
 
-def _stamp(result: SimResult, sc: Scenario) -> SimResult:
-    """Stamp the scenario name onto the run's telemetry report."""
+def _stamp(result: SimResult, sc: Scenario, world=None) -> SimResult:
+    """Stamp the scenario name onto the run's telemetry report, and fold
+    in the ``world`` phase timed around ``build_world`` and the world's
+    teardown."""
     if getattr(result, "report", None) is not None:
         result.report.scenario = sc.name
+        if world is not None:
+            world.fold_into(result.report)
     return result
 
 

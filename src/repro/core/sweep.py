@@ -50,7 +50,6 @@ engine="vmap")`` (a W=1 batch).
 """
 from __future__ import annotations
 
-import time
 from collections import OrderedDict
 from typing import Sequence
 
@@ -495,9 +494,9 @@ def run_simulation_vmap(worlds, *, eval_every: int = 10, batch_size: int = 128,
     K = sc0.K
 
     timers = PhaseTimers()
-    _t0 = time.perf_counter()
     # -- host staging: per-world worlds, plans, padded tables --------------
-    built = [build_world(sc, seed=seed) for sc, seed in worlds]
+    with timers.phase("world"):
+        built = [build_world(sc, seed=seed) for sc, seed in worlds]
     with timers.phase("plan"):
         plans = [plan_fleet(p, seed, M, sc.selection_spec())
                  for sc, seed, p in zip(scs, seeds, ps)]
@@ -525,51 +524,52 @@ def run_simulation_vmap(worlds, *, eval_every: int = 10, batch_size: int = 128,
 
     # -- one minibatch stack per GROUP (members share data + pop order;
     #    same per-vehicle RNG streams as every other engine, DESIGN.md §3)
-    _t1 = time.perf_counter()
-    g_imgs, g_labs = [], []
-    for G in groups:
-        w = G[0]
-        veh_data = built[w][0]
-        clients = [Vehicle(d, lr=scs[w].lr, batch_size=fleet_batches[w],
-                           seed=seeds[w]) for d in veh_data]
-        im_list, lab_list = [], []
-        for r in range(M):
-            im, lab = clients[plans[w].veh[r]].sample_batches(scs[w].l_iters)
-            im_list.append(im)
-            lab_list.append(lab)
-        g_imgs.append(jnp.asarray(np.stack(im_list)))
-        g_labs.append(jnp.asarray(np.stack(lab_list)))
-    group_shapes = tuple(x.shape for x in g_imgs)
+    with timers.phase("stage"):
+        g_imgs, g_labs = [], []
+        for G in groups:
+            w = G[0]
+            veh_data = built[w][0]
+            clients = [Vehicle(d, lr=scs[w].lr, batch_size=fleet_batches[w],
+                               seed=seeds[w]) for d in veh_data]
+            im_list, lab_list = [], []
+            for r in range(M):
+                im, lab = clients[plans[w].veh[r]].sample_batches(
+                    scs[w].l_iters)
+                im_list.append(im)
+                lab_list.append(lab)
+            g_imgs.append(jnp.asarray(np.stack(im_list)))
+            g_labs.append(jnp.asarray(np.stack(lab_list)))
+        group_shapes = tuple(x.shape for x in g_imgs)
 
-    # -- stacked device inputs ---------------------------------------------
-    w0_list = [init_cnn(jax.random.PRNGKey(seed)) for seed in seeds]
-    w0s = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *w0_list)
-    layout = ParamLayout.from_tree(w0_list[0])
-    gains = jnp.asarray(stack_gain_tables(ps, seeds,
-                                          [plan.n_slots for plan in plans]))
-    x0s = jnp.asarray(np.stack([Mobility(p).x0 for p in ps]), jnp.float32)
-    qt = jnp.asarray(tabs["q0_time"], jnp.float32)
-    qdl = jnp.asarray(tabs["q0_download_time"], jnp.float32)
-    qcu = jnp.asarray(tabs["q0_upload_delay"], jnp.float32)
-    qcl = jnp.asarray(tabs["q0_train_delay"], jnp.float32)
-    lrs = jnp.asarray(np.asarray([sc.lr for sc in scs], np.float32))
+        # -- stacked device inputs -----------------------------------------
+        w0_list = [init_cnn(jax.random.PRNGKey(seed)) for seed in seeds]
+        w0s = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *w0_list)
+        layout = ParamLayout.from_tree(w0_list[0])
+        gains = jnp.asarray(stack_gain_tables(ps, seeds,
+                                              [plan.n_slots
+                                               for plan in plans]))
+        x0s = jnp.asarray(np.stack([Mobility(p).x0 for p in ps]), jnp.float32)
+        qt = jnp.asarray(tabs["q0_time"], jnp.float32)
+        qdl = jnp.asarray(tabs["q0_download_time"], jnp.float32)
+        qcu = jnp.asarray(tabs["q0_upload_delay"], jnp.float32)
+        qcl = jnp.asarray(tabs["q0_train_delay"], jnp.float32)
+        lrs = jnp.asarray(np.asarray([sc.lr for sc in scs], np.float32))
 
-    scal = [_world_scalars(p, plan) for p, plan in zip(ps, plans)]
-    varied_names = tuple(sorted(
-        n for n in scal[0] if len({s[n] for s in scal}) > 1))
-    var = {n: jnp.asarray(np.asarray(
-        [s[n] for s in scal],
-        np.int32 if n == "n_slots" else np.float32)) for n in varied_names}
+        scal = [_world_scalars(p, plan) for p, plan in zip(ps, plans)]
+        varied_names = tuple(sorted(
+            n for n in scal[0] if len({s[n] for s in scal}) > 1))
+        var = {n: jnp.asarray(np.asarray(
+            [s[n] for s in scal],
+            np.int32 if n == "n_slots" else np.float32)) for n in varied_names}
 
-    eval_rounds = tuple(rr for rr in range(1, M + 1)
-                        if rr % eval_every == 0 or rr == M)
-    prog = _get_sweep_program(
-        plans, ps, [sc.lr for sc in scs], groups, scheme=sc0.scheme,
-        interpretation="mixing", layout=layout, ring_dtype=sc0.ring_dtype,
-        eval_rounds=eval_rounds, group_shapes=group_shapes)
-    args = (w0s, gains, x0s, qt, qdl, qcu, qcl, tuple(g_imgs),
-            tuple(g_labs), lrs, var)
-    timers.add("stage", time.perf_counter() - _t1)
+        eval_rounds = tuple(rr for rr in range(1, M + 1)
+                            if rr % eval_every == 0 or rr == M)
+        prog = _get_sweep_program(
+            plans, ps, [sc.lr for sc in scs], groups, scheme=sc0.scheme,
+            interpretation="mixing", layout=layout, ring_dtype=sc0.ring_dtype,
+            eval_rounds=eval_rounds, group_shapes=group_shapes)
+        args = (w0s, gains, x0s, qt, qdl, qcu, qcl, tuple(g_imgs),
+                tuple(g_labs), lrs, var)
 
     with timers.phase("run"):
         out = jax.block_until_ready(prog(*args))
@@ -640,7 +640,6 @@ def run_simulation_vmap(worlds, *, eval_every: int = 10, batch_size: int = 128,
                         progress(w, rr, acc)
                 result.rounds.append(rec)
             results.append(result)
-    timers.add("total", time.perf_counter() - _t0)
     # shared phase timers: one plan/stage/run/eval cost for the whole batch
     # — every world's report carries the same snapshot plus its world index
     for w, ((sc, seed), result) in enumerate(zip(worlds, results)):
@@ -648,7 +647,7 @@ def run_simulation_vmap(worlds, *, eval_every: int = 10, batch_size: int = 128,
         result.report = RunReport(
             engine="vmap", scheme=sc.scheme, rounds=M, seed=seed,
             metrics_on=False, spec=None, phases=timers.snapshot(),
-            memory=memory_stats(),
+            compile=timers.compile_counts(), memory=memory_stats(),
             selection=(None if plan_w.sel is None
                        else plan_w.sel.summary()),
             waves=wave_stats(plan_w.waves, K),

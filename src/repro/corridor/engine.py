@@ -58,7 +58,6 @@ divergence raise instead of silently mis-pairing batches or cohorts.
 """
 from __future__ import annotations
 
-import time
 from collections import OrderedDict
 from typing import Optional, Sequence
 
@@ -634,19 +633,24 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
                                 cc = jnp.where(keep_seg, cc, 1.0)
                                 dd = jnp.where(keep_seg, dd, 0.0)
                             coeffs = jnp.stack([cc, dd], axis=1)
-                            for jr, chunks in rsu_chain_groups(
-                                    plan, a, b, needed):
-                                g_j = G[jr]
-                                for chunk in chunks:
-                                    idx = np.asarray(chunk)
-                                    g_j = agg_ops.ring_agg(
-                                        g_j, locals_buf[jnp.asarray(idx)],
-                                        coeffs[jnp.asarray(idx - a)],
-                                        interpret=ring_interp)
-                                    last = chunk[-1] + 1
-                                    if last in needed:
-                                        ring[last] = store_row(g_j)
-                                G = G.at[jr].set(g_j)
+                            # beside the segment's event_scan scope, not
+                            # inside it: a trace keys an op by its first
+                            # scope
+                            with jax.named_scope(f"ring_chain_{a}_{b}"):
+                                for jr, chunks in rsu_chain_groups(
+                                        plan, a, b, needed):
+                                    g_j = G[jr]
+                                    for chunk in chunks:
+                                        idx = np.asarray(chunk)
+                                        g_j = agg_ops.ring_agg(
+                                            g_j,
+                                            locals_buf[jnp.asarray(idx)],
+                                            coeffs[jnp.asarray(idx - a)],
+                                            interpret=ring_interp)
+                                        last = chunk[-1] + 1
+                                        if last in needed:
+                                            ring[last] = store_row(g_j)
+                                    G = G.at[jr].set(g_j)
                         else:
                             rows = ys[7]
                             for r in range(a, b):
@@ -880,67 +884,69 @@ def run_corridor_simulation(
     flat = layout is not None
     with timers.phase("run"):
         out = jax.block_until_ready(prog(*args))
-    met_dev = None
-    if met is not None:
-        out, met_dev = out[:-1], out[-1]
-    if with_state:
-        G, cons_snaps, cohort_snaps, trace, (dev_rs, dev_rc) = out
-    else:
-        G, cons_snaps, cohort_snaps, trace = out
-    t_veh, t_rsu, t_time, t_cu, t_cl, t_dlt, t_w = (
-        np.asarray(x) for x in trace)
+    with timers.phase("guard"):
+        met_dev = None
+        if met is not None:
+            out, met_dev = out[:-1], out[-1]
+        if with_state:
+            G, cons_snaps, cohort_snaps, trace, (dev_rs, dev_rc) = out
+        else:
+            G, cons_snaps, cohort_snaps, trace = out
+        t_veh, t_rsu, t_time, t_cu, t_cl, t_dlt, t_w = (
+            np.asarray(x) for x in trace)
 
-    # divergence guard (mirrors the jit engine): the minibatch stacks and
-    # the cohort/ring pairing were planned on the host — if the device pop
-    # order or serving-cell assignment ever disagreed, fail loudly
-    if not np.array_equal(t_veh, plan.veh):
-        bad = int(np.argmax(t_veh != plan.veh))
-        raise RuntimeError(
-            "corridor engine: device pop order diverged from the host dry "
-            f"run at round {bad} (device vehicle {int(t_veh[bad])}, host "
-            f"{int(plan.veh[bad])}) — f32 time ties are not expected")
-    if not np.array_equal(t_rsu, plan.up_rsu):
-        bad = int(np.argmax(t_rsu != plan.up_rsu))
-        raise RuntimeError(
-            "corridor engine: device serving-RSU assignment diverged from "
-            f"the host dry run at round {bad} (device RSU {int(t_rsu[bad])},"
-            f" host {int(plan.up_rsu[bad])}) — an f32 boundary flip is not "
-            "expected")
-    if not np.allclose(t_time, plan.times, rtol=1e-4, atol=1e-3):
-        bad = int(np.argmax(~np.isclose(t_time, plan.times,
-                                        rtol=1e-4, atol=1e-3)))
-        raise RuntimeError(
-            "corridor engine: device event times diverged from the host "
-            f"dry run at round {bad}: {t_time[bad]} vs {plan.times[bad]}")
-    if with_state:
-        # selection divergence guard (DESIGN.md §11): the carried f32
-        # reward accumulators must reproduce the host f64 replay the
-        # admission masks were planned from
-        exp_rs, exp_rc = plan.sel_bandit
-        if not np.array_equal(np.asarray(dev_rc), exp_rc):
+        # divergence guard (mirrors the jit engine): the minibatch stacks
+        # and the cohort/ring pairing were planned on the host — if the
+        # device pop order or serving-cell assignment ever disagreed, fail
+        # loudly
+        if not np.array_equal(t_veh, plan.veh):
+            bad = int(np.argmax(t_veh != plan.veh))
             raise RuntimeError(
-                "corridor engine: device bandit arrival counts diverged "
-                "from the host selection replay")
-        if not np.allclose(np.asarray(dev_rs), exp_rs,
-                           rtol=1e-4, atol=1e-3):
+                "corridor engine: device pop order diverged from the host dry "
+                f"run at round {bad} (device vehicle {int(t_veh[bad])}, host "
+                f"{int(plan.veh[bad])}) — f32 time ties are not expected")
+        if not np.array_equal(t_rsu, plan.up_rsu):
+            bad = int(np.argmax(t_rsu != plan.up_rsu))
             raise RuntimeError(
-                "corridor engine: device bandit reward accumulators "
-                "diverged from the host selection replay")
+                "corridor engine: device serving-RSU assignment diverged from "
+                f"the host dry run at round {bad} (device RSU "
+                f"{int(t_rsu[bad])}, host {int(plan.up_rsu[bad])}) — an f32 "
+                "boundary flip is not expected")
+        if not np.allclose(t_time, plan.times, rtol=1e-4, atol=1e-3):
+            bad = int(np.argmax(~np.isclose(t_time, plan.times,
+                                            rtol=1e-4, atol=1e-3)))
+            raise RuntimeError(
+                "corridor engine: device event times diverged from the host "
+                f"dry run at round {bad}: {t_time[bad]} vs {plan.times[bad]}")
+        if with_state:
+            # selection divergence guard (DESIGN.md §11): the carried f32
+            # reward accumulators must reproduce the host f64 replay the
+            # admission masks were planned from
+            exp_rs, exp_rc = plan.sel_bandit
+            if not np.array_equal(np.asarray(dev_rc), exp_rc):
+                raise RuntimeError(
+                    "corridor engine: device bandit arrival counts diverged "
+                    "from the host selection replay")
+            if not np.allclose(np.asarray(dev_rs), exp_rs,
+                               rtol=1e-4, atol=1e-3):
+                raise RuntimeError(
+                    "corridor engine: device bandit reward accumulators "
+                    "diverged from the host selection replay")
 
-    if flat and ring_dtype == "bf16":
-        # bf16 divergence guard (DESIGN.md §12): the trace guards above
-        # keep the timeline exact; a non-finite cohort stack means the
-        # quantized ring diverged — fail loudly
-        if not all(bool(jnp.isfinite(x).all())
-                   for x in jax.tree_util.tree_leaves(G)):
-            raise RuntimeError(
-                "corridor engine: non-finite cohort stack under "
-                "ring_dtype='bf16' — the quantized snapshot ring diverged "
-                "(rerun with ring_dtype='f32' to bisect)")
-    result = SimResult(scheme=f"{scheme}+corridor", rounds=[],
-                       acc_history=[], loss_history=[])
-    per_rsu_round = np.zeros(R, np.int64)
-    eval_idx = {rr: k for k, rr in enumerate(eval_rounds)}
+        if flat and ring_dtype == "bf16":
+            # bf16 divergence guard (DESIGN.md §12): the trace guards above
+            # keep the timeline exact; a non-finite cohort stack means the
+            # quantized ring diverged — fail loudly
+            if not all(bool(jnp.isfinite(x).all())
+                       for x in jax.tree_util.tree_leaves(G)):
+                raise RuntimeError(
+                    "corridor engine: non-finite cohort stack under "
+                    "ring_dtype='bf16' — the quantized snapshot ring "
+                    "diverged (rerun with ring_dtype='f32' to bisect)")
+        result = SimResult(scheme=f"{scheme}+corridor", rounds=[],
+                           acc_history=[], loss_history=[])
+        per_rsu_round = np.zeros(R, np.int64)
+        eval_idx = {rr: k for k, rr in enumerate(eval_rounds)}
     with timers.phase("eval"):
         for r in range(M):
             j = int(t_rsu[r])
@@ -960,50 +966,53 @@ def run_corridor_simulation(
                 if progress:
                     progress(rr, acc)
             result.rounds.append(rec)
-    result.final_params = cons_snaps[eval_idx[M]]
-    result.extras = {
-        "n_rsus": R,
-        "up_rsu": t_rsu,
-        "eval_rounds": list(eval_rounds),
-        "final_cohorts": G,
-    }
-    if record_cohorts:
-        result.extras["cohort_snapshots"] = cohort_snaps
-    sel_summary = None if plan.sel is None else plan.sel.summary()
-    flt_plan = plan.flt
-    flt_report = None
-    if flt_plan is not None:
-        import dataclasses
-        flt_report = {"spec": dataclasses.asdict(flt_plan.spec),
-                      "counts": flt_plan.counts(sc.l_iters)}
-        result.extras["faults"] = flt_plan.summary(sc.l_iters)
-    channels = {}
-    if met is not None:
-        channels = {k: np.asarray(v) for k, v in met_dev.items()}
-        if "fault_counts" in channels:
-            # fault-counter divergence guard (DESIGN.md §16): the carried
-            # i32[4] accumulator must reproduce the f64 fault replay the
-            # counts table was planned from
-            exp = flt_plan.counts_table(sc.l_iters).sum(axis=0)
-            if not np.array_equal(channels["fault_counts"], exp):
-                raise RuntimeError(
-                    "corridor engine: device fault counters diverged from "
-                    f"the host fault replay ({channels['fault_counts']} vs "
-                    f"{exp})")
-        # per-arrival quality signal (Eqs. 7, 9 delay weight) — the
-        # bandit-style reward trace, published for every scheme
-        channels["reward"] = (p.gamma ** (t_cu.astype(np.float64) - 1.0)
-                              * p.zeta ** (t_cl.astype(np.float64) - 1.0))
-        if with_state:
-            channels["reward_sum"] = np.asarray(dev_rs)
-            channels["reward_count"] = np.asarray(dev_rc)
+    with timers.phase("report"):
+        result.final_params = cons_snaps[eval_idx[M]]
+        result.extras = {
+            "n_rsus": R,
+            "up_rsu": t_rsu,
+            "eval_rounds": list(eval_rounds),
+            "final_cohorts": G,
+        }
+        if record_cohorts:
+            result.extras["cohort_snapshots"] = cohort_snaps
+        sel_summary = None if plan.sel is None else plan.sel.summary()
+        flt_plan = plan.flt
+        flt_report = None
+        if flt_plan is not None:
+            import dataclasses
+            flt_report = {"spec": dataclasses.asdict(flt_plan.spec),
+                          "counts": flt_plan.counts(sc.l_iters)}
+            result.extras["faults"] = flt_plan.summary(sc.l_iters)
+        channels = {}
+        if met is not None:
+            channels = {k: np.asarray(v) for k, v in met_dev.items()}
+            if "fault_counts" in channels:
+                # fault-counter divergence guard (DESIGN.md §16): the
+                # carried i32[4] accumulator must reproduce the f64 fault
+                # replay the counts table was planned from
+                exp = flt_plan.counts_table(sc.l_iters).sum(axis=0)
+                if not np.array_equal(channels["fault_counts"], exp):
+                    raise RuntimeError(
+                        "corridor engine: device fault counters diverged "
+                        "from the host fault replay "
+                        f"({channels['fault_counts']} vs {exp})")
+            # per-arrival quality signal (Eqs. 7, 9 delay weight) — the
+            # bandit-style reward trace, published for every scheme
+            channels["reward"] = (p.gamma ** (t_cu.astype(np.float64) - 1.0)
+                                  * p.zeta ** (t_cl.astype(np.float64) - 1.0))
+            if with_state:
+                channels["reward_sum"] = np.asarray(dev_rs)
+                channels["reward_count"] = np.asarray(dev_rc)
+        memory = memory_stats()
+        waves = wave_stats(plan.waves, p.K)
     result.report = RunReport(
         engine="corridor", scheme=f"{scheme}+corridor", rounds=M,
         seed=seed, metrics_on=met is not None,
         spec=None if met is None else met.to_json(),
-        phases=timers.snapshot(), memory=memory_stats(),
-        selection=sel_summary, faults=flt_report,
-        waves=wave_stats(plan.waves, p.K), channels=channels)
+        phases=timers.snapshot(), compile=timers.compile_counts(),
+        memory=memory, selection=sel_summary, faults=flt_report,
+        waves=waves, channels=channels)
     return result
 
 
@@ -1072,74 +1081,78 @@ def _stage_run(sc, vehicles_data, p=None, *, seed, eval_every,
             times=plan.times, n_rsus=R,
             ring_guard=(ring_dtype == "bf16"),
             fault_counters=plan.flt is not None)
-    _t0 = time.perf_counter()
-    M = rounds
-    eval_rounds = tuple(sorted({rr for rr in range(1, M + 1)
-                                if rr % eval_every == 0} | {M}))
+    with timers.phase("stage"):
+        M = rounds
+        eval_rounds = tuple(sorted({rr for rr in range(1, M + 1)
+                                    if rr % eval_every == 0} | {M}))
 
-    key = jax.random.PRNGKey(seed)
-    w0 = init_params if init_params is not None else init_cnn(key)
+        key = jax.random.PRNGKey(seed)
+        w0 = init_params if init_params is not None else init_cnn(key)
 
-    # one minibatch stack per consumed round, drawn from the same
-    # per-vehicle RNG streams in the same pop order as the serial
-    # reference, so both engines train identical batches
-    fleet_batch = min(batch_size, min(d.size for d in vehicles_data))
-    clients = [Vehicle(d, lr=sc.lr, batch_size=fleet_batch, seed=seed)
-               for d in vehicles_data]
-    im_list, lab_list = [], []
-    for r in range(M):
-        im, lab = clients[plan.veh[r]].sample_batches(sc.l_iters)
-        im_list.append(im)
-        lab_list.append(lab)
-    imgs = jnp.asarray(np.stack(im_list))
-    labs = jnp.asarray(np.stack(lab_list))
+        # one minibatch stack per consumed round, drawn from the same
+        # per-vehicle RNG streams in the same pop order as the serial
+        # reference, so both engines train identical batches
+        fleet_batch = min(batch_size, min(d.size for d in vehicles_data))
+        clients = [Vehicle(d, lr=sc.lr, batch_size=fleet_batch, seed=seed)
+                   for d in vehicles_data]
+        im_list, lab_list = [], []
+        for r in range(M):
+            im, lab = clients[plan.veh[r]].sample_batches(sc.l_iters)
+            im_list.append(im)
+            lab_list.append(lab)
+        imgs = jnp.asarray(np.stack(im_list))
+        labs = jnp.asarray(np.stack(lab_list))
 
-    gains = jnp.asarray(slot_gain_table(p, seed, plan.n_slots), jnp.float32)
-    x0 = jnp.asarray(CorridorMobility(p, R, entry=entry).x0, jnp.float32)
-    qt0 = np.full((R, p.K), np.inf, np.float32)
-    qt0[plan.row0, np.arange(p.K)] = plan.q0["time"]
-    qt = jnp.asarray(qt0)
-    qdl = jnp.asarray(plan.q0["download_time"], jnp.float32)
-    qcu = jnp.asarray(plan.q0["upload_delay"], jnp.float32)
-    qcl = jnp.asarray(plan.q0["train_delay"], jnp.float32)
+        gains = jnp.asarray(slot_gain_table(p, seed, plan.n_slots),
+                            jnp.float32)
+        x0 = jnp.asarray(CorridorMobility(p, R, entry=entry).x0, jnp.float32)
+        qt0 = np.full((R, p.K), np.inf, np.float32)
+        qt0[plan.row0, np.arange(p.K)] = plan.q0["time"]
+        qt = jnp.asarray(qt0)
+        qdl = jnp.asarray(plan.q0["download_time"], jnp.float32)
+        qcu = jnp.asarray(plan.q0["upload_delay"], jnp.float32)
+        qcl = jnp.asarray(plan.q0["train_delay"], jnp.float32)
 
-    from repro.core.flat import ParamLayout
-    layout = ParamLayout.from_tree(w0) if flat else None
-    shapes = (imgs.shape, tuple(
-        (str(path), v.shape, str(v.dtype))
-        for path, v in jax.tree_util.tree_leaves_with_path(w0)))
-    cache_key = (plan.waves, tuple(plan.dl_round.tolist()),
-                 tuple(plan.up_rsu.tolist()), plan.n_slots, R, p, scheme,
-                 interpretation, use_kernel, mode,
-                 float(getattr(sc, "reconcile_tau", 0.5)),
-                 sc.reconcile_every, eval_rounds, record_cohorts,
-                 _mesh_key(mesh), shapes,
-                 None if plan.sel is None else plan.sel.signature(),
-                 client_mod._local_scan,
-                 None if layout is None else layout.signature(), ring_dtype,
-                 None if met is None else met.signature(),
-                 None if plan.flt is None else
-                 (plan.flt.signature(), sc.l_iters,
-                  client_mod._local_scan_partial))
-    prog = _PROGRAM_CACHE.get(cache_key)
-    if prog is None:
-        prog = _build_program(
-            plan, p, scheme=scheme, interpretation=interpretation,
-            use_kernel=use_kernel, mesh=mesh,
-            reconcile_every=sc.reconcile_every, reconcile_mode=mode,
-            reconcile_tau=float(getattr(sc, "reconcile_tau", 0.5)),
-            eval_rounds=eval_rounds, fedasync_mix=DEFAULT_FEDASYNC_MIX,
-            record_cohorts=record_cohorts, flat_layout=layout,
-            ring_dtype=ring_dtype, metrics=met, l_iters=sc.l_iters)
-        _PROGRAM_CACHE[cache_key] = prog
-        while len(_PROGRAM_CACHE) > _PROGRAM_CACHE_SIZE:
-            _PROGRAM_CACHE.popitem(last=False)
-    else:
-        _PROGRAM_CACHE.move_to_end(cache_key)
+        from repro.core.flat import ParamLayout
+        layout = ParamLayout.from_tree(w0) if flat else None
+        shapes = (imgs.shape, tuple(
+            (str(path), v.shape, str(v.dtype))
+            for path, v in jax.tree_util.tree_leaves_with_path(w0)))
+        cache_key = (plan.waves, tuple(plan.dl_round.tolist()),
+                     tuple(plan.up_rsu.tolist()), plan.n_slots, R, p, scheme,
+                     interpretation, use_kernel, mode,
+                     float(getattr(sc, "reconcile_tau", 0.5)),
+                     sc.reconcile_every, eval_rounds, record_cohorts,
+                     _mesh_key(mesh), shapes,
+                     None if plan.sel is None else plan.sel.signature(),
+                     client_mod._local_scan,
+                     None if layout is None else layout.signature(),
+                     ring_dtype,
+                     None if met is None else met.signature(),
+                     None if plan.flt is None else
+                     (plan.flt.signature(), sc.l_iters,
+                      client_mod._local_scan_partial))
+        prog = _PROGRAM_CACHE.get(cache_key)
+        if prog is None:
+            prog = _build_program(
+                plan, p, scheme=scheme, interpretation=interpretation,
+                use_kernel=use_kernel, mesh=mesh,
+                reconcile_every=sc.reconcile_every, reconcile_mode=mode,
+                reconcile_tau=float(getattr(sc, "reconcile_tau", 0.5)),
+                eval_rounds=eval_rounds, fedasync_mix=DEFAULT_FEDASYNC_MIX,
+                record_cohorts=record_cohorts, flat_layout=layout,
+                ring_dtype=ring_dtype, metrics=met, l_iters=sc.l_iters)
+            _PROGRAM_CACHE[cache_key] = prog
+            while len(_PROGRAM_CACHE) > _PROGRAM_CACHE_SIZE:
+                _PROGRAM_CACHE.popitem(last=False)
+        else:
+            _PROGRAM_CACHE.move_to_end(cache_key)
 
-    with_state = (plan.sel is not None and not plan.sel.is_noop
-                  and plan.sel.spec.policy == "eps-bandit")
-    args = (w0, gains, x0, qt, qdl, qcu, qcl, imgs, labs,
-            jnp.float32(sc.lr))
-    timers.add("stage", time.perf_counter() - _t0)
+        with_state = (plan.sel is not None and not plan.sel.is_noop
+                      and plan.sel.spec.policy == "eps-bandit")
+        args = (w0, gains, x0, qt, qdl, qcu, qcl, imgs, labs,
+                jnp.float32(sc.lr))
+        # K samplers take milliseconds to free: inside the phase, not
+        # between phases on the way out
+        del clients
     return prog, args, plan, layout, eval_rounds, with_state, met

@@ -13,8 +13,9 @@ device-executes invariant:
 - :mod:`repro.telemetry.replay` — the f64 conformance oracle: re-drives the
   event timeline on the host and produces the exact channel values the
   device accumulators must reproduce.
-- :mod:`repro.telemetry.timers` — host-side phase timers (plan / stage /
-  compile / run / eval wall clock, peak memory) around the compiled region.
+- :mod:`repro.telemetry.timers` — host-side phase timers (world / plan /
+  stage / run / guard / eval / report wall clock, each a ``repro.<phase>``
+  profiler span), compile counters and peak memory.
 - :mod:`repro.telemetry.report` — the typed, versioned :class:`RunReport`
   every engine attaches to ``SimResult.report`` (replacing the ad-hoc
   ``extras["selection"]`` dict entries).

@@ -7,8 +7,10 @@ report splits into:
 
 - identity: engine / scheme / rounds / seed (+ scenario name when run
   through ``run_scenario``),
-- host instrumentation (always on): ``phases`` wall-clock seconds and
-  ``memory`` peaks from :mod:`repro.telemetry.timers`,
+- host instrumentation (always on): ``phases`` wall-clock seconds,
+  ``compile`` counters (executables built or loaded, and the seconds
+  spent tracing, lowering and compiling them) and ``memory`` peaks from
+  :mod:`repro.telemetry.timers`,
 - plan-derived statics: ``selection`` (the former extras entry) and
   ``waves`` fill/utilization — known before the device runs,
 - device channels (``metrics=on`` only): staleness histogram, occupancy
@@ -56,6 +58,7 @@ class RunReport:
     metrics_on: bool = False
     spec: Optional[dict] = None          # MetricsSpec.to_json() when on
     phases: dict = field(default_factory=dict)
+    compile: dict = field(default_factory=dict)     # compile_counts()
     memory: dict = field(default_factory=dict)
     selection: Optional[dict] = None     # SelectionPlan.summary()
     faults: Optional[dict] = None        # fault spec + decision counts

@@ -12,6 +12,7 @@ import json
 from typing import Union
 
 from repro.telemetry.report import RunReport
+from repro.telemetry.timers import PHASES
 
 
 def append(path: str, report: Union[RunReport, dict]) -> None:
@@ -37,6 +38,25 @@ def load(path: str) -> list[dict]:
 
 def _fmt_seconds(s: float) -> str:
     return f"{s * 1e3:.1f} ms" if s < 1.0 else f"{s:.2f} s"
+
+
+def _phase_order(names) -> list:
+    """Phase names in the order a study runs them (JSONL sorts keys)."""
+    return sorted(names, key=lambda n: (PHASES.index(n) if n in PHASES
+                                        else len(PHASES), n))
+
+
+def _compile_value(c: dict, n: str) -> str:
+    if n not in c:
+        return "-"
+    return str(c[n]) if n == "executables" else _fmt_seconds(c[n])
+
+
+def _compile_line(c: dict) -> str:
+    return (f"{c.get('executables', 0)} executables, trace "
+            f"{_fmt_seconds(c.get('trace_s', 0.0))}, lower "
+            f"{_fmt_seconds(c.get('lower_s', 0.0))}, backend "
+            f"{_fmt_seconds(c.get('backend_s', 0.0))}")
 
 
 def _channel_summary(ch: dict) -> list[str]:
@@ -76,7 +96,11 @@ def render(runs: list[dict]) -> str:
         phases = d.get("phases") or {}
         if phases:
             out.append("  phases: " + "  ".join(
-                f"{n}={_fmt_seconds(s)}" for n, s in sorted(phases.items())))
+                f"{n}={_fmt_seconds(phases[n])}"
+                for n in _phase_order(phases)))
+        comp = d.get("compile") or {}
+        if comp:
+            out.append("  compile: " + _compile_line(comp))
         mem = d.get("memory") or {}
         if "peak_rss_bytes" in mem:
             out.append(f"  peak rss: {mem['peak_rss_bytes'] / 2**30:.2f} GiB")
@@ -101,7 +125,8 @@ def render(runs: list[dict]) -> str:
 
 def diff(a: dict, b: dict) -> str:
     """Compare two runs: identity fields, phase timings (with relative
-    delta), and summary statistics of the shared channels."""
+    delta), compile counters, and summary statistics of the shared
+    channels."""
     import numpy as np
     out = []
     for f in ("engine", "scheme", "rounds", "seed", "scenario",
@@ -110,7 +135,7 @@ def diff(a: dict, b: dict) -> str:
         mark = "" if va == vb else "   <-- differs"
         out.append(f"{f:12} {va!r:>20} | {vb!r:<20}{mark}")
     pa, pb = a.get("phases") or {}, b.get("phases") or {}
-    for n in sorted(set(pa) | set(pb)):
+    for n in _phase_order(set(pa) | set(pb)):
         sa, sb = pa.get(n), pb.get(n)
         if sa is not None and sb is not None and sa > 0:
             rel = f"  ({(sb - sa) / sa * 100.0:+.1f}%)"
@@ -119,6 +144,11 @@ def diff(a: dict, b: dict) -> str:
         out.append(f"phase {n:10} "
                    f"{_fmt_seconds(sa) if sa is not None else '-':>12} | "
                    f"{_fmt_seconds(sb) if sb is not None else '-':<12}{rel}")
+    ka, kb = a.get("compile") or {}, b.get("compile") or {}
+    for n in dict.fromkeys([*ka, *kb]):
+        va, vb = _compile_value(ka, n), _compile_value(kb, n)
+        mark = "" if va == vb or n != "executables" else "   <-- differs"
+        out.append(f"compile {n:12} {va:>10} | {vb:<12}{mark}")
     ca, cb = a.get("channels") or {}, b.get("channels") or {}
     for n in sorted(set(ca) & set(cb)):
         xa = np.asarray(ca[n], float).ravel()

@@ -57,3 +57,31 @@ def test_compile_cache_placement(tmp_path, from_env):
         assert written
         if os.path.isdir(DEFAULT_DIR):
             assert not written & set(os.listdir(DEFAULT_DIR))
+
+
+def test_compile_cache_keeps_each_programs_scope_names(tmp_path):
+    """Two programs that differ only in a ``jax.named_scope`` get their own
+    cache entries: the second does not load the first's executable, whose
+    metadata names the first's scope."""
+    code = textwrap.dedent("""
+        import sys
+        import jax, jax.numpy as jnp
+        from repro.compile_cache import configure_compile_cache
+        configure_compile_cache()
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+        def f(x):
+            with jax.named_scope(sys.argv[1]):
+                return jnp.sin(x) * 3.0 + 1.0
+        text = jax.jit(f).lower(jnp.arange(7.0)).compile().as_text()
+        print(sorted(n for n in ("scope_first", "scope_second")
+                     if n in text))
+    """)
+    env = _cpu_env(JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    for name in ("scope_first", "scope_second"):
+        res = subprocess.run([sys.executable, "-c", code, name],
+                             capture_output=True, text=True, timeout=300,
+                             env=env, cwd=ROOT)
+        assert res.returncode == 0, res.stderr
+        assert res.stdout.strip().splitlines()[-1] == repr([name])
