@@ -23,16 +23,50 @@ import numpy as np
 from repro.models.cnn import cnn_forward, cross_entropy_loss
 
 
+# shard rows are int32 indices into the pool
+ROW_DTYPE = np.int32
+ROW_BYTES = np.dtype(ROW_DTYPE).itemsize
+
+
+@dataclass(eq=False)
+class ShardPool:
+    """The one training pool that every vehicle's shard indexes into.
+
+    ``shard_rows`` counts the rows handed out to shards, ``rows_gathered``
+    the images copied out of the pool by minibatch draws: integer sums that
+    ``world_counts`` reports."""
+    images: np.ndarray      # [N, 28, 28, 1]
+    labels: np.ndarray      # [N]
+    shard_rows: int = 0
+    rows_gathered: int = 0
+
+    def world_counts(self, vehicles: int) -> dict:
+        """What a world of ``vehicles`` shards over this pool holds on the
+        host, and how much of it the minibatch draws have read."""
+        return {"vehicles": vehicles, "shard_rows": self.shard_rows,
+                "host_bytes": (self.images.nbytes + self.labels.nbytes
+                               + self.shard_rows * ROW_BYTES),
+                "rows_gathered": self.rows_gathered}
+
+
 @dataclass
 class VehicleData:
-    """Private shard of vehicle i (1-based index per the paper)."""
+    """Private shard of vehicle i (1-based index per the paper): the rows of
+    the shared training pool it was dealt, not a copy of their images."""
     index: int
-    images: np.ndarray      # [D_i, 28, 28, 1]
-    labels: np.ndarray      # [D_i]
+    pool: ShardPool
+    rows: np.ndarray        # [D_i] ROW_DTYPE indices into the pool
 
     @property
     def size(self) -> int:
-        return len(self.labels)
+        return len(self.rows)
+
+    def gather(self, sel: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        """Images and labels of shard positions ``sel`` (any shape), copied
+        out of the pool in one gather each."""
+        r = self.rows[sel]
+        self.pool.rows_gathered += r.size
+        return self.pool.images[r], self.pool.labels[r]
 
 
 @jax.jit
@@ -126,7 +160,7 @@ class Vehicle:
         sel = np.stack([self.rng.choice(self.data.size, self.batch_size,
                                         replace=False)
                         for _ in range(l_iters)])
-        return self.data.images[sel], self.data.labels[sel]
+        return self.data.gather(sel)
 
     def local_update(self, global_params, l_iters: int, n_ep=None):
         """``n_ep`` truncates the update to the first n_ep of the l_iters

@@ -145,7 +145,7 @@ register(Scenario(
 ))
 register(Scenario(
     name="fleet-k100",
-    description="Fleet-scale: 100 vehicles under one RSU; shard storage "
+    description="Fleet-scale: 100 vehicles under one RSU; shard rows "
                 "capped so the wave engine batches ~uniform minibatches.",
     K=100, rounds=120, scale=0.022, max_per_vehicle=512,
     n_train=4000, n_test=800,
@@ -416,20 +416,24 @@ def run_scenario(scenario: str | Scenario, *, seed: int = 0,
             ring_dtype=sc.ring_dtype, metrics=metrics, faults=flt,
             **kw)
     with world.phase("world"):
+        counts = veh[0].pool.world_counts(len(veh))
         # freeing K vehicles' shards takes milliseconds: inside the phase,
         # not between phases on the way out
         del veh, te_i, te_l
-    return _stamp(result, sc, world)
+    return _stamp(result, sc, world, counts)
 
 
-def _stamp(result: SimResult, sc: Scenario, world=None) -> SimResult:
+def _stamp(result: SimResult, sc: Scenario, world=None,
+           counts=None) -> SimResult:
     """Stamp the scenario name onto the run's telemetry report, and fold
     in the ``world`` phase timed around ``build_world`` and the world's
-    teardown."""
+    teardown, with the world's ``ShardPool.world_counts``."""
     if getattr(result, "report", None) is not None:
         result.report.scenario = sc.name
         if world is not None:
             world.fold_into(result.report)
+        if counts is not None:
+            result.report.world = counts
     return result
 
 

@@ -642,12 +642,16 @@ def run_simulation_vmap(worlds, *, eval_every: int = 10, batch_size: int = 128,
             results.append(result)
     # shared phase timers: one plan/stage/run/eval cost for the whole batch
     # — every world's report carries the same snapshot plus its world index
+    # — and its own world's counts (only a group's first world gathers
+    # minibatches, so the others read rows_gathered 0)
     for w, ((sc, seed), result) in enumerate(zip(worlds, results)):
         plan_w = plans[w]
+        veh_w = built[w][0]
         result.report = RunReport(
             engine="vmap", scheme=sc.scheme, rounds=M, seed=seed,
             metrics_on=False, spec=None, phases=timers.snapshot(),
             compile=timers.compile_counts(), memory=memory_stats(),
+            world=veh_w[0].pool.world_counts(len(veh_w)),
             selection=(None if plan_w.sel is None
                        else plan_w.sel.summary()),
             waves=wave_stats(plan_w.waves, K),

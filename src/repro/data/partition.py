@@ -7,7 +7,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.channel.params import ChannelParams
-from repro.core.client import VehicleData
+from repro.core.client import ROW_DTYPE, ShardPool, VehicleData
 
 
 def partition_vehicles(images: np.ndarray, labels: np.ndarray,
@@ -16,11 +16,16 @@ def partition_vehicles(images: np.ndarray, labels: np.ndarray,
                        dirichlet_alpha: float | None = None,
                        max_per_vehicle: int | None = None
                        ) -> list[VehicleData]:
-    """``scale`` shrinks every D_i proportionally (CPU-budget knob; relative
+    """Deal each vehicle its shard: the rows of ``images``/``labels`` it
+    draws, held as indices into one :class:`ShardPool` over those arrays
+    (no image is copied; minibatches gather from the pool).
+
+    ``scale`` shrinks every D_i proportionally (CPU-budget knob; relative
     data imbalance between vehicles — the thing the paper's Eq. 8 feeds on —
-    is preserved exactly).  ``max_per_vehicle`` caps each shard's *storage*
+    is preserved exactly).  ``max_per_vehicle`` caps each shard's *rows*
     for K=100+ fleets (delays still use the uncapped Table-I D_i)."""
     rng = np.random.default_rng(seed)
+    pool = ShardPool(images, labels)
     out = []
     for i1 in range(1, params.K + 1):
         d_i = max(int(params.data_count(i1) * scale), 8)
@@ -36,6 +41,7 @@ def partition_vehicles(images: np.ndarray, labels: np.ndarray,
             weights = weights / weights.sum()
             sel = rng.choice(len(labels), size=min(d_i, len(labels)),
                              replace=False, p=weights)
-        out.append(VehicleData(index=i1, images=images[sel],
-                               labels=labels[sel]))
+        pool.shard_rows += len(sel)
+        out.append(VehicleData(index=i1, pool=pool,
+                               rows=sel.astype(ROW_DTYPE)))
     return out

@@ -10,7 +10,9 @@ report splits into:
 - host instrumentation (always on): ``phases`` wall-clock seconds,
   ``compile`` counters (executables built or loaded, and the seconds
   spent tracing, lowering and compiling them) and ``memory`` peaks from
-  :mod:`repro.telemetry.timers`,
+  :mod:`repro.telemetry.timers`, and ``world`` counts (vehicles, shard
+  rows, host bytes of the pool and its row indices, rows gathered into
+  minibatches) from ``ShardPool.world_counts``,
 - plan-derived statics: ``selection`` (the former extras entry) and
   ``waves`` fill/utilization — known before the device runs,
 - device channels (``metrics=on`` only): staleness histogram, occupancy
@@ -59,6 +61,7 @@ class RunReport:
     spec: Optional[dict] = None          # MetricsSpec.to_json() when on
     phases: dict = field(default_factory=dict)
     compile: dict = field(default_factory=dict)     # compile_counts()
+    world: dict = field(default_factory=dict)       # world_counts()
     memory: dict = field(default_factory=dict)
     selection: Optional[dict] = None     # SelectionPlan.summary()
     faults: Optional[dict] = None        # fault spec + decision counts

@@ -101,6 +101,12 @@ def render(runs: list[dict]) -> str:
         comp = d.get("compile") or {}
         if comp:
             out.append("  compile: " + _compile_line(comp))
+        world = d.get("world") or {}
+        if world:
+            out.append(f"  world: {world['vehicles']} vehicles, "
+                       f"{world['shard_rows']} shard rows, "
+                       f"{world['host_bytes'] / 2**20:.2f} MiB on the host, "
+                       f"{world['rows_gathered']} rows gathered")
         mem = d.get("memory") or {}
         if "peak_rss_bytes" in mem:
             out.append(f"  peak rss: {mem['peak_rss_bytes'] / 2**30:.2f} GiB")
