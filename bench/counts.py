@@ -1,55 +1,22 @@
 """Work a study needs, counted from the configuration's shapes and the
-event trace: the paper CNN's FLOPs and the bytes ``ring_agg``'s chains must
-move.  Nothing here reads how the program implements either, so a count
-stays the same whatever computes the work."""
+event trace: the model's FLOPs and the bytes ``ring_agg``'s chains must
+move.  The model's own counts (FLOPs per sample, the packed width P) come
+from the module the configuration names (``bench/models``).  Nothing here
+reads how the program implements either, so a count stays the same
+whatever computes the work."""
 from __future__ import annotations
 
-LANE = 128
-
-
-def cnn_layers(cnn: dict) -> list:
-    """``(name, macs per image, has an input gradient)`` of each conv and
-    dense layer; SAME 3x3 convolutions, 2x2 pooling after each conv."""
-    s, k = cnn["image"], cnn["kernel"]
-    c1, c2, f1, nc = cnn["conv1"], cnn["conv2"], cnn["fc1"], cnn["classes"]
-    flat = (s // 4) ** 2 * c2
-    return [
-        ("conv1", s * s * c1 * k * k * 1, False),      # the image needs none
-        ("conv2", (s // 2) ** 2 * c2 * k * k * c1, True),
-        ("fc1", flat * f1, True),
-        ("fc2", f1 * nc, True),
-    ]
-
-
-def forward_flops(cnn: dict) -> int:
-    """Multiply-adds of one image's forward pass, two FLOPs each."""
-    return sum(2 * m for _, m, _ in cnn_layers(cnn))
-
-
-def train_flops(cnn: dict) -> int:
-    """Forward, weight gradient, and input gradient of every layer but the
-    first, for one image."""
-    return sum(2 * m * (3 if dx else 2) for _, m, dx in cnn_layers(cnn))
-
-
-def packed_params(cnn: dict) -> int:
-    """Length P of the model packed leaf by leaf, each leaf padded to a
-    multiple of the 128-lane width."""
-    s, k = cnn["image"], cnn["kernel"]
-    c1, c2, f1, nc = cnn["conv1"], cnn["conv2"], cnn["fc1"], cnn["classes"]
-    sizes = [k * k * c1, c1, k * k * c1 * c2, c2,
-             (s // 4) ** 2 * c2 * f1, f1, f1 * nc, nc]
-    return sum(-(-n // LANE) * LANE for n in sizes)
+import models
 
 
 def study_flops(cfg: dict, eval_every: int) -> int:
-    """Model FLOPs of one study: every trained image of every arrival and
-    every evaluated test image."""
-    sc, cnn = cfg["scenario"], cfg["cnn"]
+    """Model FLOPs of one study: every trained sample of every arrival and
+    every evaluated test sample."""
+    sc, model = cfg["scenario"], models.of(cfg)
     trained = sc["rounds"] * sc["l_iters"] * minibatch(sc)
     evals = len(eval_rounds(sc["rounds"], eval_every))
-    return trained * train_flops(cnn) + evals * sc["n_test"] * forward_flops(
-        cnn)
+    return (trained * model.train_flops(cfg)
+            + evals * sc["n_test"] * model.forward_flops(cfg))
 
 
 def minibatch(sc: dict) -> int:
@@ -77,7 +44,7 @@ def ring_agg_bytes(cfg: dict, veh, rsu, eval_every: int) -> tuple:
     trace.  Returns ``(bytes read, bytes written)``."""
     sc = cfg["scenario"]
     M, R = sc["rounds"], sc["n_rsus"]
-    P = packed_params(cfg["cnn"])
+    P = models.of(cfg).packed_params(cfg)
     width = {"f32": 4, "bf16": 2}[sc["ring_dtype"]]
     last, needed = {}, set()
     for r, v in enumerate(veh):

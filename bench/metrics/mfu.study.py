@@ -1,7 +1,7 @@
-"""The whole study's share of the chip's bf16 peak: the CNN's model FLOPs
-of the traced studies (forward and backward of every trained image,
-forward of every evaluated one, counted from shapes by ``counts``) over
-the traced window's wall time."""
+"""The whole study's share of the chip's bf16 peak: the model's FLOPs of
+the traced studies (forward and backward of every trained sample, forward
+of every evaluated one, counted from shapes by ``counts`` and the model's
+module) over the traced window's wall time."""
 import counts
 
 

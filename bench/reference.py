@@ -4,10 +4,13 @@ A straightforward, sequential implementation of the semantics a
 configuration file states, written from the paper (arXiv:2208.01901
 Section III, Eqs. 1-11, Table I) and the configuration alone.  It imports
 nothing of the program under test and takes nothing the program made: the
-synthetic data set, the vehicles' shards, the Rayleigh channel, the event
-timeline, the initial CNN weights and every minibatch are drawn here from
+data set, the vehicles' shards, the Rayleigh channel, the event
+timeline, the initial weights and every minibatch are drawn here from
 the seed, in the same order and through the same numpy / jax.random calls
-that the configuration's semantics name.
+that the configuration's semantics name.  What is the model's (the data
+set, the initial weights, the local step and the evaluation) comes from
+the module the configuration names (``bench/models/<model>.py``); what is
+here the paper fixes for any model.
 
 One study, in order of simulated time:
 
@@ -28,9 +31,9 @@ One study, in order of simulated time:
    evaluated on the stored snapshot (one RSU) or on the mean of the cohort
    models (several RSUs).
 
-Arithmetic is float32, with convolutions and matmuls at ``HIGHEST``
-precision; only the stored snapshot and upload rows take the
-configuration's storage dtype.
+Arithmetic is float32 (the model module states its matmul precision);
+only the stored snapshot and upload rows take the configuration's storage
+dtype.
 """
 from __future__ import annotations
 
@@ -42,53 +45,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import models
+
 STORAGE_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16,
                   "fp8": jnp.float8_e4m3fn}
 # the nearest storage one step below each stated width: the control
 NEXT_LOWER = {"f32": "bf16", "bf16": "fp8"}
-
-
-# ---------------------------------------------------------------------------
-# data: the synthetic MNIST stand-in and the Section V-A partition
-# ---------------------------------------------------------------------------
-def _blur(img):
-    k = (0.25, 0.5, 0.25)
-    for ax in (0, 1):
-        n = img.shape[ax]
-        img = (np.take(img, np.arange(n) - 1, axis=ax, mode="clip") * k[0]
-               + img * k[1]
-               + np.take(img, np.arange(n) + 1, axis=ax, mode="clip") * k[2])
-    return img
-
-
-def synthetic_digits(n_train, n_test, noise, seed=0, n_classes=10):
-    """Ten smooth random class prototypes on 28x28, each sample shifted by
-    up to 2 px and given Gaussian noise, clipped to [0, 1]."""
-    rng = np.random.default_rng(seed)
-    protos = []
-    for _ in range(n_classes):
-        img = _blur(np.kron(rng.normal(size=(7, 7)), np.ones((4, 4))))
-        protos.append((img - img.min()) / (np.ptp(img) + 1e-9))
-    protos = np.stack(protos)
-
-    def make(n, rng):
-        labels = rng.integers(0, n_classes, n)
-        base = protos[labels]
-        sx = rng.integers(-2, 3, n)
-        sy = rng.integers(-2, 3, n)
-        imgs = np.empty((n, 28, 28), np.float32)
-        for dx in range(-2, 3):
-            for dy in range(-2, 3):
-                m = (sx == dx) & (sy == dy)
-                if m.any():
-                    imgs[m] = np.roll(np.roll(base[m], dx, axis=1), dy,
-                                      axis=2)
-        imgs += rng.normal(scale=noise, size=imgs.shape).astype(np.float32)
-        return np.clip(imgs, 0, 1)[..., None], labels.astype(np.int32)
-
-    tr = make(n_train, rng)
-    te = make(n_test, np.random.default_rng(seed + 1))
-    return tr + te
 
 
 # ---------------------------------------------------------------------------
@@ -225,74 +187,8 @@ def timeline(ch: Channel, n_rsus: int, seed, rounds, entry="uniform"):
 
 
 # ---------------------------------------------------------------------------
-# the paper's CNN (Section V-A) and plain SGD
+# aggregation and storage
 # ---------------------------------------------------------------------------
-def init_cnn(seed, cnn):
-    """HWIO convolutions and dense layers, N(0, 1/fan_in), zero biases;
-    one jax.random split of the seed's key per weight."""
-    c1, c2, f1, nc = (cnn["conv1"], cnn["conv2"], cnn["fc1"],
-                      cnn["classes"])
-    k = cnn["kernel"]
-    flat = (cnn["image"] // 4) ** 2 * c2
-    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    return {
-        "conv1_w": jax.random.normal(ks[0], (k, k, 1, c1)) / np.sqrt(k * k),
-        "conv1_b": jnp.zeros((c1,), jnp.float32),
-        "conv2_w": (jax.random.normal(ks[1], (k, k, c1, c2))
-                    / np.sqrt(k * k * c1)),
-        "conv2_b": jnp.zeros((c2,), jnp.float32),
-        "fc1_w": jax.random.normal(ks[2], (flat, f1)) / np.sqrt(flat),
-        "fc1_b": jnp.zeros((f1,), jnp.float32),
-        "fc2_w": jax.random.normal(ks[3], (f1, nc)) / np.sqrt(f1),
-        "fc2_b": jnp.zeros((nc,), jnp.float32),
-    }
-
-
-HIGHEST = jax.lax.Precision.HIGHEST
-
-
-def _conv(x, w):
-    return jax.lax.conv_general_dilated(
-        x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        precision=HIGHEST)
-
-
-def _pool(x):
-    b, h, w, c = x.shape
-    return x.reshape(b, h // 2, 2, w // 2, 2, c).max(axis=(2, 4))
-
-
-def forward(p, x):
-    x = _pool(jax.nn.relu(_conv(x, p["conv1_w"]) + p["conv1_b"]))
-    x = _pool(jax.nn.relu(_conv(x, p["conv2_w"]) + p["conv2_b"]))
-    x = jax.nn.relu(jnp.dot(x.reshape(x.shape[0], -1), p["fc1_w"],
-                            precision=HIGHEST) + p["fc1_b"])
-    return jnp.dot(x, p["fc2_w"], precision=HIGHEST) + p["fc2_b"]
-
-
-def _nll(p, x, y):
-    logp = jax.nn.log_softmax(forward(p, x), axis=-1)
-    return -jnp.take_along_axis(logp, y[:, None], axis=-1)[:, 0]
-
-
-@jax.jit
-def _local_update(p, xs, ys, lr):
-    """len(xs) SGD steps (Eq. 2) on the mean cross-entropy (Eq. 1)."""
-    for x, y in zip(xs, ys):
-        g = jax.grad(lambda q: jnp.mean(_nll(q, x, y)))(p)
-        p = jax.tree_util.tree_map(lambda w, d: w - lr * d, p, g)
-    return p
-
-
-@jax.jit
-def _evaluate(p, x, y):
-    logits = forward(p, x)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, y[:, None], axis=-1)[:, 0]
-    return (jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32)),
-            jnp.mean(nll))
-
-
 @jax.jit
 def _mix(g, l, c, d):
     return jax.tree_util.tree_map(
@@ -332,8 +228,8 @@ class World:
         self.cfg = cfg
         self.sc = sc
         self.ch = Channel(K=sc["K"], **cfg["channel"])
-        tr_x, tr_y, self.te_x, self.te_y = synthetic_digits(
-            sc["n_train"], sc["n_test"], sc["noise"])
+        self.model = models.of(cfg)
+        tr_x, tr_y, self.te_x, self.te_y = self.model.data(cfg)
         rng = np.random.default_rng(seed)
         shards = []
         for i1 in range(1, sc["K"] + 1):
@@ -356,7 +252,7 @@ class World:
             ys.append(tr_y[idx])
         self.xs = jnp.asarray(np.stack(xs))
         self.ys = jnp.asarray(np.stack(ys))
-        self.w0 = init_cnn(seed, cfg["cnn"])
+        self.w0 = self.model.init(seed, cfg)
         c = self.ch
         a = np.clip((1.0 - c.beta) * c.gamma ** (self.trace["c_u"] - 1.0)
                     * c.zeta ** (self.trace["c_l"] - 1.0), 0.0, 1.0)
@@ -388,14 +284,15 @@ class World:
         for r in range(M):
             j = int(self.trace["rsu"][r])
             pay = _widen(ring[int(self.trace["dl_round"][r]) + 1])
-            loc = _store(_local_update(pay, self.xs[r], self.ys[r], lr), dt)
+            loc = _store(self.model.local_update(pay, self.xs[r],
+                                                 self.ys[r], lr), dt)
             G[j] = _mix(G[j], loc, *self.coeffs[r])
             if every and (r + 1) % every == 0:
                 G = [_mean(*G)] * R
             ring.append(_store(G[j], dt))
             if (r + 1) % eval_every == 0 or r + 1 == M:
                 model = _widen(ring[-1]) if R == 1 else _mean(*G)
-                acc, loss = _evaluate(model, self.te_x, self.te_y)
+                acc, loss = self.model.evaluate(model, self.te_x, self.te_y)
                 evals.append((r + 1, float(acc), float(loss)))
         final = G[0] if R == 1 else _mean(*G)
         return jax.device_get(final), evals

@@ -4,9 +4,11 @@
 
 Run from the root of a checkout.  The cell names a configuration
 (``bench/configs/<name>.json``: the scenario's fields, the channel's Table I
-values, the CNN's widths, the engine, and the limits of the comparison) and
-a traffic mix (``bench/traffic/<name>.json``: the evaluation cadence and the
-cycle of learning rates).
+values, the model it names and that model's widths, the engine, and the
+limits of the comparison) and a traffic mix (``bench/traffic/<name>.json``:
+the evaluation cadence and the cycle of learning rates).  The model is the
+module ``bench/models/<model>.py``, loaded before the warm-up: a
+configuration that names a missing one exits 1 at once.
 
 Set-up (``setup_s``, from process start): imports, device start, and two
 whole studies as the warm-up: the first builds the world, plans, stages
@@ -34,9 +36,11 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from contextlib import ExitStack, nullcontext  # noqa: E402
@@ -75,15 +79,26 @@ def load_cell(name: str) -> tuple:
 
 class Counters:
     """Executables compiled (fresh or from the persistent cache) and
-    persistent-cache hits, from JAX's monitoring events."""
+    persistent-cache hits, from JAX's monitoring events, and the seconds
+    the interpreter spent in cyclic garbage collection."""
 
     def __init__(self):
         import jax
         self.compiles = 0
         self.compile_s = 0.0
         self.cache_hits = 0
+        self.gc_s = 0.0
+        self._gc_start = None
         jax.monitoring.register_event_duration_secs_listener(self._duration)
         jax.monitoring.register_event_listener(self._event)
+        gc.callbacks.append(self._gc)
+
+    def _gc(self, phase, info):
+        if phase == "start":
+            self._gc_start = time.perf_counter()
+        elif self._gc_start is not None:
+            self.gc_s += time.perf_counter() - self._gc_start
+            self._gc_start = None
 
     def _duration(self, event, secs, **_):
         if event == BACKEND_COMPILE:
@@ -216,7 +231,8 @@ def window(run, lrs, seconds, counters, tracer=None) -> tuple:
     while True:
         lr = lrs[attempted % len(lrs)]
         attempted += 1
-        n0 = counters.compiles
+        n0, gc0 = counters.compiles, counters.gc_s
+        use0 = resource.getrusage(resource.RUSAGE_SELF)
         s0 = time.perf_counter()
         try:
             with tracer.study() if tracer else nullcontext():
@@ -226,6 +242,7 @@ def window(run, lrs, seconds, counters, tracer=None) -> tuple:
                   flush=True)
             res = None
         wall = time.perf_counter() - s0
+        use = resource.getrusage(resource.RUSAGE_SELF)
         if tracer:
             tracer.study_done()
         compiled = counters.compiles - n0
@@ -236,7 +253,12 @@ def window(run, lrs, seconds, counters, tracer=None) -> tuple:
             # with the reference; holding every answer for the whole
             # window would grow the host heap the later studies allocate in
             first = all(s["lr"] != lr for s in studies)
+            # host CPU seconds (all threads) and garbage collection tell a
+            # stall of this process's own work from one in which it waited
             studies.append({"lr": lr, "wall": wall, "compiled": compiled,
+                            "cpu": (use.ru_utime + use.ru_stime
+                                    - use0.ru_utime - use0.ru_stime),
+                            "gc": counters.gc_s - gc0,
                             "phases": dict(res.report.phases),
                             "rounds": len(res.rounds),
                             "answer": answer(res) if first else None})
@@ -303,6 +325,9 @@ def run_cell(spec, cell, cfg, traffic, seed, seconds, traced, devs,
     object.  ``run_study(lr)`` defaults to the program's ``run_scenario``
     at the cell's configuration."""
     import jax
+
+    import models
+    models.of(cfg)          # a missing model fails here, before any study
     if run_study is None:
         from repro.core.scenarios import run_scenario
         sc = scenario(cfg)
@@ -348,9 +373,10 @@ def run_cell(spec, cell, cfg, traffic, seed, seconds, traced, devs,
     for i, s in enumerate(studies):
         ph = s["phases"]
         print(f"study {i}: lr={s['lr']!r} wall_s={s['wall']!r} "
-              f"world_s={s['wall'] - sum(ph.values())!r} "
+              f"no_phase_s={s['wall'] - sum(ph.values())!r} "
               + " ".join(f"{k}_s={v!r}" for k, v in ph.items())
-              + f" rounds={s['rounds']} compiles={s['compiled']}",
+              + f" rounds={s['rounds']} compiles={s['compiled']}"
+              f" cpu_s={s['cpu']!r} gc_s={s['gc']!r}",
               flush=True)
     print(f"window: {wall!r} s, {attempted} studies, {failed} failed, "
           f"{rounds} rounds; peak HBM {mem} B", flush=True)

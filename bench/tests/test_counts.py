@@ -1,8 +1,10 @@
-"""Hand counts of the paper CNN and of ring_agg's needed bytes."""
+"""Hand counts of the paper CNN (``bench/models/cnn.py``) and of ring_agg's
+needed bytes."""
 import json
 import os
 
 import counts
+import models
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -13,22 +15,23 @@ def config(name):
 
 
 def test_cnn_forward_flops_per_image():
-    cnn = config("fleet-k10000")["cnn"]
+    cfg = config("fleet-k10000")
+    cnn = models.of(cfg)
     # conv1 451,584 + conv2 7,225,344 + fc1 802,816 + fc2 2,560
-    assert counts.forward_flops(cnn) == 8_482_304
-    assert [2 * m for _, m, _ in counts.cnn_layers(cnn)] == [
+    assert cnn.forward_flops(cfg) == 8_482_304
+    assert [2 * m for _, m, _ in cnn.layers(cfg)] == [
         451_584, 7_225_344, 802_816, 2_560]
 
 
 def test_cnn_train_flops_skip_the_image_gradient():
-    cnn = config("fleet-k10000")["cnn"]
-    assert counts.train_flops(cnn) == 3 * 8_482_304 - 451_584
+    cfg = config("fleet-k10000")
+    assert models.of(cfg).train_flops(cfg) == 3 * 8_482_304 - 451_584
 
 
 def test_packed_width_is_the_lane_aligned_model():
     for name in ("fleet-k10000", "corridor-r8-k4000"):
         cfg = config(name)
-        assert counts.packed_params(cfg["cnn"]) == 422_016
+        assert models.of(cfg).packed_params(cfg) == 422_016
         assert cfg["cnn"]["params_packed"] == 422_016
 
 
