@@ -19,6 +19,16 @@ plain reference and the work counts everything that is the model's:
 A count depends on the configuration's shapes only, never on a trace or on
 how the program computes the work.  The module is plain ``jax.numpy`` and
 imports nothing of the program under test.
+
+A model may expose further counts from shapes alone, such as the FLOPs of
+the experts one chip holds, for its own readers.  Its per-layer metrics
+are new reader files ``bench/metrics/<metric>.py`` whose ``read(ctx)``
+takes the trace reduced over the named scopes and Pallas kernels the
+reader declares (``SCOPES = (...)``, ``KERNELS = (...)``; time inside a
+loop's body reads in ``ctx.trace["scope_any_depth_s"]``), or a counter of
+the traced studies' run reports (``ctx.reports``, each
+``RunReport.to_json()``, in study order).  Such a metric's entry in
+``BENCHMARK.json`` lists only that model's cells in its ``workloads``.
 """
 from __future__ import annotations
 

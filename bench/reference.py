@@ -271,28 +271,53 @@ class World:
                                   self.trace["rsu"].tolist())),
                 "final": final, "losses": [(r, lo) for r, _, lo in evals]}
 
+    def last_reads(self, eval_every) -> dict:
+        """Of each stored model that a study reads, the last round that
+        reads it.  Row ``k`` is the model stored after round ``k - 1``
+        (row 0 the initial one); the arrivals that downloaded it read it,
+        and with one RSU so does the evaluation at round ``k - 1``."""
+        M = self.sc["rounds"]
+        last = {int(d) + 1: r for r, d in enumerate(self.trace["dl_round"])}
+        if self.sc["n_rsus"] == 1:
+            for r in range(M):
+                if (r + 1) % eval_every == 0 or r + 1 == M:
+                    last[r + 1] = max(last.get(r + 1, r), r)
+        return last
+
     def study(self, lr, eval_every, storage="f32"):
-        """Final model (f32 pytree) and ``[(round, accuracy, loss)]``."""
+        """Final model (f32 pytree) and ``[(round, accuracy, loss)]``.
+
+        Only the stored models that a later round reads are kept, each
+        until its last reader: a model of half a billion parameters
+        stores a row of a gigabyte a round."""
         dt = STORAGE_DTYPES[storage]
         R = self.sc["n_rsus"]
         M = self.sc["rounds"]
         every = self.sc["reconcile_every"] if R > 1 else 0
+        last = self.last_reads(eval_every)
         G = [self.w0] * R
-        ring = [_store(self.w0, dt)]
+        ring = {0: _store(self.w0, dt)} if 0 in last else {}
+
+        def read(k, r):
+            return ring.pop(k) if last[k] == r else ring[k]
+
         evals = []
         lr = jnp.float32(lr)
+        # the downloaded, uploaded and evaluated models are temporaries,
+        # freed once used rather than held into the next round
         for r in range(M):
             j = int(self.trace["rsu"][r])
-            pay = _widen(ring[int(self.trace["dl_round"][r]) + 1])
-            loc = _store(self.model.local_update(pay, self.xs[r],
-                                                 self.ys[r], lr), dt)
-            G[j] = _mix(G[j], loc, *self.coeffs[r])
+            G[j] = _mix(G[j], _store(self.model.local_update(
+                _widen(read(int(self.trace["dl_round"][r]) + 1, r)),
+                self.xs[r], self.ys[r], lr), dt), *self.coeffs[r])
             if every and (r + 1) % every == 0:
                 G = [_mean(*G)] * R
-            ring.append(_store(G[j], dt))
+            if r + 1 in last:
+                ring[r + 1] = _store(G[j], dt)
             if (r + 1) % eval_every == 0 or r + 1 == M:
-                model = _widen(ring[-1]) if R == 1 else _mean(*G)
-                acc, loss = self.model.evaluate(model, self.te_x, self.te_y)
+                acc, loss = self.model.evaluate(
+                    _widen(read(r + 1, r)) if R == 1 else _mean(*G),
+                    self.te_x, self.te_y)
                 evals.append((r + 1, float(acc), float(loss)))
         final = G[0] if R == 1 else _mean(*G)
         return jax.device_get(final), evals

@@ -23,7 +23,9 @@ After the window the plain reference (``bench/reference.py``) runs every
 learning rate the window used, and the first study of each is compared
 with it (``bench/compare.py``).  ``--trace 1`` profiles the window's first
 studies and reports the per-layer metrics instead, each read by
-``bench/metrics/<name>.py``.
+``bench/metrics/<name>.py`` from the studies' phases, the traced studies'
+run reports and the trace, reduced over the named scopes and kernels that
+the cell's readers declare.
 
 The last line of standard output is the result as one JSON object.  The
 run needs a TPU with as many chips as the cell asks for and exits 1 without
@@ -225,7 +227,8 @@ class Tracer:
 
 def window(run, lrs, seconds, counters, tracer=None) -> tuple:
     """Back-to-back studies for ``seconds``, ending with the study in
-    progress.  Returns ``(wall seconds, studies, attempted, failed)``."""
+    progress.  Returns ``(wall seconds, studies, attempted, failed)``; a
+    traced study keeps its run report, which is read after the window."""
     studies, attempted, failed = [], 0, 0
     t0 = time.perf_counter()
     while True:
@@ -233,6 +236,7 @@ def window(run, lrs, seconds, counters, tracer=None) -> tuple:
         attempted += 1
         n0, gc0 = counters.compiles, counters.gc_s
         use0 = resource.getrusage(resource.RUSAGE_SELF)
+        traced = bool(tracer and tracer.on)
         s0 = time.perf_counter()
         try:
             with tracer.study() if tracer else nullcontext():
@@ -261,6 +265,7 @@ def window(run, lrs, seconds, counters, tracer=None) -> tuple:
                             "gc": counters.gc_s - gc0,
                             "phases": dict(res.report.phases),
                             "rounds": len(res.rounds),
+                            "report": res.report if traced else None,
                             "answer": answer(res) if first else None})
         if time.perf_counter() - t0 >= seconds:
             return time.perf_counter() - t0, studies, attempted, failed
@@ -274,9 +279,17 @@ def memory_peak(devs) -> int:
 def check(cfg, traffic, seed, studies) -> tuple:
     """Numbers of the worst of ``studies`` (one per learning rate)
     against the reference at the stated storage width, and whether all
-    are within the configuration's limits."""
+    are within the configuration's limits.  The reference starts once the
+    window's device results are freed, so that a large model's fits."""
+    import jax
+
     import compare
     import reference
+    gc.collect()
+    live = jax.live_arrays()
+    print(f"before the reference: {len(live)} device arrays live, "
+          f"{sum(a.nbytes for a in live)} B", flush=True)
+    del live
     world = reference.World(cfg, seed)
     storage = cfg["scenario"]["ring_dtype"]
     start = world.start(storage)
@@ -293,16 +306,39 @@ def check(cfg, traffic, seed, studies) -> tuple:
     return worst, ok
 
 
-def read_metrics(names, ctx) -> dict:
-    """Each per-layer metric from ``bench/metrics/<name>.py``'s ``read``;
-    a reader that finds nothing returns None and the metric is left out."""
-    out = {}
-    for m in names:
-        path = os.path.join(BENCH, "metrics", m["name"] + ".py")
-        spec = importlib.util.spec_from_file_location(
+def load_readers(spec, cell) -> list:
+    """``[(metric entry, module)]``: the reader ``bench/metrics/<name>.py``
+    of each per-layer metric that ``cell`` reports."""
+    out = []
+    for m in spec["per_layer"]:
+        if cell["name"] not in m.get("workloads", [cell["name"]]):
+            continue
+        path = os.path.join(ROOT, "bench", "metrics", m["name"] + ".py")
+        found = importlib.util.spec_from_file_location(
             "bench_metric_" + m["name"].replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
+        mod = importlib.util.module_from_spec(found)
+        found.loader.exec_module(mod)
+        out.append((m, mod))
+    return out
+
+
+def reduce_trace(events, readers) -> dict:
+    """The trace reduced over the default named scopes and kernels and
+    those the readers declare (``SCOPES``, ``KERNELS``), in that order."""
+    import trace_reduce as tr
+    scopes, kernels = list(tr.SCOPES), list(tr.KERNELS)
+    for _, mod in readers:
+        scopes += [s for s in getattr(mod, "SCOPES", ()) if s not in scopes]
+        kernels += [k for k in getattr(mod, "KERNELS", ())
+                    if k not in kernels]
+    return tr.reduce(events, tuple(scopes), tuple(kernels))
+
+
+def read_metrics(readers, ctx) -> dict:
+    """Each per-layer metric from its reader's ``read``; a reader that
+    finds nothing returns None and the metric is left out."""
+    out = {}
+    for m, mod in readers:
         value = mod.read(ctx)
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
@@ -386,13 +422,14 @@ def run_cell(spec, cell, cfg, traffic, seed, seconds, traced, devs,
     out = {}
     if traced:
         import trace_reduce as tr
-        red = tr.reduce(tr.load(trace_dir))
+        readers = load_readers(spec, cell)
+        red = reduce_trace(tr.load(trace_dir), readers)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        ctx = SimpleNamespace(studies=studies, trace=red, cfg=cfg,
-                              traffic=traffic, peaks=peaks, setup=setup)
-        names = [m for m in spec["per_layer"]
-                 if cell["name"] in m.get("workloads", [cell["name"]])]
-        metrics = read_metrics(names, ctx)
+        ctx = SimpleNamespace(
+            studies=studies, trace=red, cfg=cfg, traffic=traffic,
+            peaks=peaks, setup=setup,
+            reports=[s["report"].to_json() for s in studies if s["report"]])
+        metrics = read_metrics(readers, ctx)
         if red:
             device.update(busy_s=red["busy_s"], window_s=red["window_s"])
             out["breakdown"] = {

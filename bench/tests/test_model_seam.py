@@ -11,6 +11,7 @@ import hashlib
 import json
 import os
 import shutil
+import sys
 import textwrap
 from types import SimpleNamespace
 
@@ -22,6 +23,8 @@ import counts
 import models
 import reference
 import run
+import trace_reduce
+from test_trace_reduce import loop_events
 
 SEED = 2_147_483_659            # past 32 signed bits
 P_CNN = 422_016
@@ -93,6 +96,39 @@ def test_reference_answer_is_bitwise_unchanged(name, want):
     ans = world.answer(cfg["scenario"]["lr"], 10,
                        cfg["scenario"]["ring_dtype"])
     assert digest(ans) == want
+
+
+@pytest.mark.parametrize("name", ["fleet-k10000", "corridor-r8-k4000"])
+def test_reference_keeps_only_the_rows_it_reads(name, monkeypatch):
+    """Every row ``_store`` makes (a stored model or an upload) is held
+    here too; a row that another reference holds is one the study still
+    keeps.  The most held at once is what the trace's downloads and
+    evaluations need, and each read row is stored once."""
+    cfg = small(config(name))
+    world = reference.World(cfg, SEED)
+    made, most = [], []
+    real = reference._store
+
+    def store(p, dtype):
+        # a row held only by ``made``, the loop's name and getrefcount's
+        # argument has been dropped by the study
+        most.append(sum(sys.getrefcount(r) > 3 for r in made))
+        made.append(real(p, dtype))
+        return made[-1]
+    monkeypatch.setattr(reference, "_store", store)
+    world.study(cfg["scenario"]["lr"], 10, cfg["scenario"]["ring_dtype"])
+
+    M = cfg["scenario"]["rounds"]
+    last = world.last_reads(10)
+    dl = world.trace["dl_round"]
+    assert set(last) == {d + 1 for d in dl} | (
+        {10, 12} if cfg["scenario"]["n_rsus"] == 1 else set())
+    assert len(made) == M + len(last)
+    # rows stored by round r (row k after round k - 1) that round r or
+    # a later one still reads
+    need = max(sum(k <= r + 1 and last[k] >= r for k in last)
+               for r in range(M))
+    assert max(most) <= need < M
 
 
 # ---------------------------------------------------------------------------
@@ -275,3 +311,68 @@ def test_missing_model_fails_before_any_study(counters):
     assert calls == []
     with pytest.raises(SystemExit):
         reference.World(cfg, SEED)
+
+
+# ---------------------------------------------------------------------------
+# a per-layer metric of that model, from a new reader file only
+# ---------------------------------------------------------------------------
+STUB_READER = textwrap.dedent('''
+    """Rows gathered into minibatches per millisecond of expert time."""
+    SCOPES = ("moe_experts",)
+    KERNELS = ("expert_mm",)
+
+
+    def read(ctx):
+        t = ctx.trace
+        if not t or not t["kernel_s"].get("expert_mm") or not ctx.reports:
+            return None
+        rows = sum(r["world"]["rows_gathered"] for r in ctx.reports)
+        return rows / (t["scope_any_depth_s"]["moe_experts"] * 1e3)
+''')
+
+
+def test_stub_reader_declares_the_scope_and_kernel_it_reads(stub_root,
+                                                            monkeypatch):
+    (stub_root / "bench" / "metrics").mkdir()
+    (stub_root / "bench" / "metrics" / "moe.rows_per_expert_ms.py"
+     ).write_text(STUB_READER)
+    spec = run.load_json("BENCHMARK.json")
+    spec["per_layer"].append({
+        "name": "moe.rows_per_expert_ms", "unit": "rows/ms",
+        "better": "higher", "source": "device_trace", "layer": "experts",
+        "moves": "rounds_per_s", "workloads": ["mlp-k30.study"]})
+    (stub_root / "BENCHMARK.json").write_text(json.dumps(spec))
+    spec, cell, cfg, traffic = run.load_cell("mlp-k30.study")
+    readers = run.load_readers(spec, cell)
+    assert [m["name"] for m, _ in readers] == ["moe.rows_per_expert_ms"]
+
+    seen = []
+    real = trace_reduce.reduce
+    monkeypatch.setattr(trace_reduce, "reduce", lambda events, scopes,
+                        kernels: seen.append((scopes, kernels))
+                        or real(events, scopes, kernels))
+    red = run.reduce_trace(loop_events(), readers)
+    assert seen == [(trace_reduce.SCOPES + ("moe_experts",),
+                     trace_reduce.KERNELS + ("expert_mm",))]
+    assert red["kernel_s"]["expert_mm"] == pytest.approx(60e-6)
+
+    ctx = SimpleNamespace(studies=[], trace=red, cfg=cfg, traffic=traffic,
+                          peaks=run.peaks_of("TPU v5 lite"), setup={},
+                          reports=[{"world": {"rows_gathered": 8}},
+                                   {"world": {"rows_gathered": 12}}])
+    got = run.read_metrics(readers, ctx)
+    assert got == {"moe.rows_per_expert_ms": {
+        "value": pytest.approx(20 / 0.11), "unit": "rows/ms"}}
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in
+                                  run.load_json("BENCHMARK.json")["workloads"]])
+def test_benchmark_cells_reduce_over_the_defaults(cell, monkeypatch):
+    """The CNN's readers declare nothing: each cell's trace is reduced
+    exactly as before readers could declare."""
+    spec, cell, _, _ = run.load_cell(cell)
+    seen = []
+    monkeypatch.setattr(trace_reduce, "reduce",
+                        lambda *a: seen.append(a[1:]) or {})
+    run.reduce_trace([], run.load_readers(spec, cell))
+    assert seen == [(trace_reduce.SCOPES, trace_reduce.KERNELS)]

@@ -120,3 +120,96 @@ def test_reduce_counts_each_studys_kernel_events():
 def test_reduce_refuses_a_study_that_lost_kernel_events():
     with pytest.raises(ValueError, match="lost"):
         tr.reduce(_second_study(with_kernel=False))
+
+
+# reduce's answer on the fixtures above at the parent of the change that
+# added ``scope_any_depth_s``: the fields it had, digit for digit
+BEFORE = [
+    (events, {
+        'window_s': 0.001, 'busy_s': 0.00035, 'studies': 1,
+        'scope_s': {'event_scan': 0.0002, 'wave_train': 5e-05,
+                    'ring_chain': 5e-05},
+        'kernel_s': {'ring_agg_2d': 5e-05},
+        'kernel_events': {'ring_agg_2d': 1},
+        'device_ops': [['event_scan/while', 0.0002],
+                       ['wave_train/fusion', 5e-05],
+                       ['ring_chain/ring_agg_2d', 5e-05],
+                       ['jit__eval_step/convolution', 5e-05]],
+        'idle': {'stage': 4e-05, 'plan': 6e-05, 'study': 0.0004,
+                 'eval': 0.00015000000000000001}}),
+    (lambda: _second_study(with_kernel=True), {
+        'window_s': 0.003, 'busy_s': 0.0007, 'studies': 2,
+        'scope_s': {'event_scan': 0.0004, 'wave_train': 0.0001,
+                    'ring_chain': 0.0001},
+        'kernel_s': {'ring_agg_2d': 0.0001},
+        'kernel_events': {'ring_agg_2d': 1},
+        'device_ops': [['event_scan/while', 0.0004],
+                       ['wave_train/fusion', 0.0001],
+                       ['ring_chain/ring_agg_2d', 0.0001],
+                       ['jit__eval_step/convolution', 0.0001]],
+        'idle': {'stage': 4e-05, 'plan': 0.00012, 'study': 0.00084,
+                 'eval': 0.00030000000000000003, '(no span)': 0.001}}),
+]
+
+
+@pytest.mark.parametrize("make,want", BEFORE, ids=["one", "two"])
+def test_reduce_with_todays_scopes_reads_as_before(make, want):
+    r = tr.reduce(make())
+    assert set(r) == set(want) | {"scope_any_depth_s"}
+    assert {k: r[k] for k in want} == want
+    # at any depth: the loop's body ops under event_scan, each op once
+    assert r["scope_any_depth_s"] == pytest.approx(
+        {k: v * want["studies"] for k, v in
+         {"event_scan": 110e-6, "wave_train": 50e-6,
+          "ring_chain": 50e-6}.items()})
+
+
+def loop_events():
+    """One study, 0..1000 us, whose wave trains in a loop: a ``while``
+    op 100-400 (no scope of its own) holds a dense op (110-160) and an
+    ``expert_mm`` kernel (170-230, with a copy 180-200 nested in it) under
+    ``wave_train_1/moe_experts_2``, and a router op (240-300) under
+    ``wave_train_1`` alone; a ``ring_agg`` kernel 400-450 follows."""
+    body = "jit(program_flat)/wave_train_1/"
+    return [
+        meta(3, name="/device:TPU:0"), meta(3, 2, "XLA Modules"),
+        meta(3, 3, "XLA Ops"), meta(700, name="/host:CPU"),
+        op(100, 350, "jit_program_flat(1)", tid=2),
+        op(100, 300, "while.9"),
+        op(110, 50, "fusion.1", body + "moe_experts_2/dot"),
+        op(170, 60, "expert_mm.2",
+           body + "moe_experts_2/jit(expert_mm)/pallas_call"),
+        op(180, 20, "copy.3", body + "moe_experts_2/copy"),
+        op(240, 60, "fusion.4", body + "router_0/add"),
+        op(400, 50, "ring_agg_2d.5",
+           "jit(program_flat)/ring_chain_0_9/jit(ring_agg_2d)/pallas_call"),
+        span(0, 1000, "study"),
+    ]
+
+
+def test_declared_scope_reads_ops_nested_in_a_loop():
+    plain = tr.reduce(loop_events())
+    declared = tr.reduce(loop_events(), tr.SCOPES + ("moe_experts",))
+    # the loop's body: the dense op and the kernel, the copy inside the
+    # kernel not again
+    assert declared["scope_any_depth_s"]["moe_experts"] == pytest.approx(
+        110e-6)
+    assert "moe_experts" not in plain["scope_any_depth_s"]
+    assert plain["scope_any_depth_s"] == pytest.approx(
+        {"wave_train": 170e-6, "ring_chain": 50e-6})
+    # the top-level rule charges the whole loop to wave_train either way
+    assert plain["scope_s"] == pytest.approx(
+        {"wave_train": 300e-6, "ring_chain": 50e-6})
+    for k in set(plain) | set(declared):
+        if k == "scope_any_depth_s":
+            assert ({s: v for s, v in declared[k].items()
+                     if s != "moe_experts"} == plain[k])
+        else:
+            assert declared[k] == plain[k], k
+
+
+def test_any_depth_counts_an_op_once_per_scope():
+    pat = re.compile(r"\b(a|b)_[0-9_]+")
+    ops = [op(0, 100, "outer", "a_1/x"), op(10, 50, "mid", "a_1/b_2/y"),
+           op(20, 10, "inner", "a_1/b_2/z"), op(200, 5, "alone", "b_3/w")]
+    assert tr.any_depth(ops, pat) == {"a": 100, "b": 55}

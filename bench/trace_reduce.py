@@ -21,6 +21,10 @@ from collections import Counter, defaultdict
 
 SPAN_PREFIX = "bench."
 STUDY = SPAN_PREFIX + "study"
+# what every cell's trace is reduced over; a reader adds its own
+# (``SCOPES`` and ``KERNELS`` in ``bench/metrics/<name>.py``)
+SCOPES = ("wave_train", "event_scan", "ring_chain")
+KERNELS = ("ring_agg_2d",)
 _SUFFIX = re.compile(r"\.\d+$")
 
 
@@ -73,6 +77,22 @@ def top_level(ops) -> list:
         else:
             out.append((e, []))
         stack.append(e)
+    return out
+
+
+def any_depth(ops, pattern: re.Pattern) -> dict:
+    """Microseconds of the ops whose own ``tf_op`` carries each scope
+    ``pattern`` finds, at any depth of nesting; an op inside another that
+    carries the same scope is already counted by it."""
+    out, stack = defaultdict(float), []
+    for e in sorted(ops, key=lambda e: (e["ts"], -e["dur"])):
+        while stack and stack[-1][0] <= e["ts"]:
+            stack.pop()
+        outer = stack[-1][1] if stack else frozenset()
+        own = set(pattern.findall(e.get("args", {}).get("tf_op", "")))
+        for sc in own - outer:
+            out[sc] += e["dur"]
+        stack.append((e["ts"] + e["dur"], outer | own))
     return out
 
 
@@ -141,14 +161,16 @@ def label_time(intervals, spans) -> dict:
     return dict(out)
 
 
-def reduce(events, scopes=("wave_train", "event_scan", "ring_chain"),
-           kernels=("ring_agg_2d",)) -> dict:
+def reduce(events, scopes=SCOPES, kernels=KERNELS) -> dict:
     """The traced window's numbers, averaged over the devices traced:
 
     - ``window_s``: first study span's start to the last one's end;
     - ``busy_s``: the union of device op intervals inside the window;
     - ``studies``: study spans in the window;
     - ``scope_s``: device seconds of top-level ops per named scope;
+    - ``scope_any_depth_s``: device seconds per named scope of the ops
+      that carry it at any depth, each op once (``any_depth``): a scope
+      inside a loop's body reads here and not in ``scope_s``;
     - ``kernel_s``: device seconds of each Pallas kernel's events;
     - ``kernel_events``: each kernel's events in one study.  Every study
       of a run runs the same plan, so a study that holds fewer than the
@@ -170,6 +192,7 @@ def reduce(events, scopes=("wave_train", "event_scan", "ring_chain"),
     n = len(ops)
     busy_s = 0.0
     scope_s, kernel_s = defaultdict(float), defaultdict(float)
+    depth_s = defaultdict(float)
     op_s, idle = defaultdict(float), defaultdict(float)
     for dev, dev_ops in ops.items():
         inside = [e for e in dev_ops if lo <= e["ts"] < hi]
@@ -189,6 +212,8 @@ def reduce(events, scopes=("wave_train", "event_scan", "ring_chain"),
                            else "?")
             op_s[f"{where}/{_SUFFIX.sub('', e['name'])}"] += (
                 e["dur"] / 1e6 / n)
+        for sc, us in any_depth(inside, pattern).items():
+            depth_s[sc] += us / 1e6 / n
         for e in inside:
             tf_op = e.get("args", {}).get("tf_op", "")
             for name in kernels:
@@ -206,6 +231,7 @@ def reduce(events, scopes=("wave_train", "event_scan", "ring_chain"),
     top_ops = sorted(op_s.items(), key=lambda kv: -kv[1])[:10]
     return {"window_s": (hi - lo) / 1e6, "busy_s": busy_s,
             "studies": len(studies), "scope_s": dict(scope_s),
+            "scope_any_depth_s": dict(depth_s),
             "kernel_s": dict(kernel_s),
             "kernel_events": {k: c[0] for k, c in per_study.items()},
             "device_ops": [list(kv) for kv in top_ops],
