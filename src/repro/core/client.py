@@ -33,12 +33,14 @@ class ShardPool:
     """The one training pool that every vehicle's shard indexes into.
 
     ``shard_rows`` counts the rows handed out to shards, ``rows_gathered``
-    the images copied out of the pool by minibatch draws: integer sums that
+    the images copied out of the pool by minibatch draws, ``samplers`` the
+    ``Vehicle`` samplers built over its shards: integer sums that
     ``world_counts`` reports."""
     images: np.ndarray      # [N, 28, 28, 1]
     labels: np.ndarray      # [N]
     shard_rows: int = 0
     rows_gathered: int = 0
+    samplers: int = 0
 
     def world_counts(self, vehicles: int) -> dict:
         """What a world of ``vehicles`` shards over this pool holds on the
@@ -46,7 +48,8 @@ class ShardPool:
         return {"vehicles": vehicles, "shard_rows": self.shard_rows,
                 "host_bytes": (self.images.nbytes + self.labels.nbytes
                                + self.shard_rows * ROW_BYTES),
-                "rows_gathered": self.rows_gathered}
+                "rows_gathered": self.rows_gathered,
+                "samplers": self.samplers}
 
 
 @dataclass
@@ -150,6 +153,7 @@ class Vehicle:
         # documented deviation (DESIGN.md §6) that preserves Eq. (2).
         self.batch_size = min(batch_size, data.size)
         self.rng = np.random.default_rng(seed + data.index)
+        data.pool.samplers += 1
 
     def sample_batches(self, l_iters: int):
         """Draw the l minibatches for one local update (host RNG).
@@ -176,6 +180,30 @@ class Vehicle:
                 global_params, jnp.asarray(imgs), jnp.asarray(labs),
                 self.lr, jnp.int32(n_ep))
         return params, float(loss)
+
+
+class Samplers:
+    """The fleet's ``Vehicle`` samplers, each built on its vehicle's first
+    access and kept for later ones.
+
+    ``samplers[k]`` is ``Vehicle(vehicles_data[k], lr=lr,
+    batch_size=batch_size, seed=seed)``.  A vehicle's stream is
+    ``default_rng(seed + index)`` whenever it is built, and no stream
+    depends on another, so a study whose M arrivals draw from at most M of
+    K vehicles builds only those generators and draws the minibatches K
+    samplers built up front would (DESIGN.md §3)."""
+
+    def __init__(self, vehicles_data: Sequence[VehicleData], *, lr: float,
+                 batch_size: int, seed: int):
+        self._data = vehicles_data
+        self._kw = dict(lr=lr, batch_size=batch_size, seed=seed)
+        self._built: dict[int, Vehicle] = {}
+
+    def __getitem__(self, k) -> Vehicle:
+        v = self._built.get(k)
+        if v is None:
+            v = self._built[k] = Vehicle(self._data[k], **self._kw)
+        return v
 
 
 def local_update_many(payloads: Sequence, batches: Sequence, lr: float,

@@ -72,7 +72,7 @@ import numpy as np
 from repro.channel import (ChannelParams, Mobility, slot_gain_table,
                            training_delay)
 from repro.core import client as client_mod
-from repro.core.client import Vehicle, VehicleData
+from repro.core.client import Samplers, VehicleData
 from repro.core.server import DEFAULT_FEDASYNC_MIX, RoundRecord
 from repro.models.cnn import init_cnn
 from repro.selection import make_selection_state
@@ -945,8 +945,8 @@ def _stage_run(vehicles_data, *, scheme, rounds, l_iters, lr, params, seed,
         # per-vehicle RNG streams in the same per-cycle order as the host
         # engines (DESIGN.md §3), so every engine trains identical batches
         fleet_batch = min(batch_size, min(d.size for d in vehicles_data))
-        clients = [Vehicle(d, lr=lr, batch_size=fleet_batch, seed=seed)
-                   for d in vehicles_data]
+        clients = Samplers(vehicles_data, lr=lr, batch_size=fleet_batch,
+                           seed=seed)
         im_list, lab_list = [], []
         for r in range(M):
             im, lab = clients[plan.veh[r]].sample_batches(l_iters)
@@ -980,9 +980,6 @@ def _stage_run(vehicles_data, *, scheme, rounds, l_iters, lr, params, seed,
                       and plan.sel.spec.policy == "eps-bandit")
         args = (w0, gains, x0, qt, qdl, qcu, qcl, imgs, labs,
                 jnp.float32(lr))
-        # K samplers take milliseconds to free: inside the phase, not
-        # between phases on the way out
-        del clients
     return prog, args, plan, layout, eval_rounds, with_state, met
 
 

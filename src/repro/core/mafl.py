@@ -41,7 +41,7 @@ import numpy as np
 from repro.channel import (ChannelParams, Mobility, RayleighAR1,
                            SlotGainCache, shannon_rate, training_delay,
                            upload_delay)
-from repro.core.client import Vehicle, VehicleData, local_update_many
+from repro.core.client import Samplers, VehicleData, local_update_many
 from repro.core.events import EventQueue
 from repro.core.server import RSUServer
 from repro.faults import arrival_step, initial_vehicles, make_fault_state
@@ -196,8 +196,8 @@ def run_simulation(
     server = RSUServer(global_params, p, scheme=scheme, use_kernel=use_kernel,
                        interpretation=interpretation)
     fleet_batch = min(batch_size, min(d.size for d in vehicles_data))
-    clients = [Vehicle(d, lr=lr, batch_size=fleet_batch, seed=seed)
-               for d in vehicles_data]
+    clients = Samplers(vehicles_data, lr=lr, batch_size=fleet_batch,
+                       seed=seed)
 
     timers = PhaseTimers()
     met_req = metrics_requested(metrics)

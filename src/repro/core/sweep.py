@@ -59,7 +59,7 @@ import numpy as np
 
 from repro.channel import Mobility, slot_gain_table
 from repro.core import client as client_mod
-from repro.core.client import Vehicle
+from repro.core.client import Samplers
 from repro.core.jit_engine import _SUPPORTED_SCHEMES, _wave_train, plan_fleet
 from repro.core.server import DEFAULT_FEDASYNC_MIX, RoundRecord
 
@@ -529,8 +529,8 @@ def run_simulation_vmap(worlds, *, eval_every: int = 10, batch_size: int = 128,
         for G in groups:
             w = G[0]
             veh_data = built[w][0]
-            clients = [Vehicle(d, lr=scs[w].lr, batch_size=fleet_batches[w],
-                               seed=seeds[w]) for d in veh_data]
+            clients = Samplers(veh_data, lr=scs[w].lr,
+                               batch_size=fleet_batches[w], seed=seeds[w])
             im_list, lab_list = [], []
             for r in range(M):
                 im, lab = clients[plans[w].veh[r]].sample_batches(

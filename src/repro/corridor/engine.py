@@ -67,7 +67,7 @@ import numpy as np
 
 from repro.channel import ChannelParams, CorridorMobility, slot_gain_table
 from repro.core import client as client_mod
-from repro.core.client import Vehicle, VehicleData
+from repro.core.client import Samplers, VehicleData
 from repro.core.jit_engine import _mesh_key, _wave_train
 from repro.core.server import DEFAULT_FEDASYNC_MIX, RoundRecord
 from repro.corridor.plan import CorridorPlan, plan_corridor
@@ -1093,8 +1093,8 @@ def _stage_run(sc, vehicles_data, p=None, *, seed, eval_every,
         # per-vehicle RNG streams in the same pop order as the serial
         # reference, so both engines train identical batches
         fleet_batch = min(batch_size, min(d.size for d in vehicles_data))
-        clients = [Vehicle(d, lr=sc.lr, batch_size=fleet_batch, seed=seed)
-                   for d in vehicles_data]
+        clients = Samplers(vehicles_data, lr=sc.lr, batch_size=fleet_batch,
+                           seed=seed)
         im_list, lab_list = [], []
         for r in range(M):
             im, lab = clients[plan.veh[r]].sample_batches(sc.l_iters)
@@ -1152,7 +1152,4 @@ def _stage_run(sc, vehicles_data, p=None, *, seed, eval_every,
                       and plan.sel.spec.policy == "eps-bandit")
         args = (w0, gains, x0, qt, qdl, qcu, qcl, imgs, labs,
                 jnp.float32(sc.lr))
-        # K samplers take milliseconds to free: inside the phase, not
-        # between phases on the way out
-        del clients
     return prog, args, plan, layout, eval_rounds, with_state, met

@@ -45,7 +45,7 @@ def run_handover_simulation(sc, vehicles_data: Sequence,
     import jax
     import numpy as np
 
-    from repro.core.client import Vehicle
+    from repro.core.client import Samplers
     from repro.core.mafl import SimResult, _Timeline, _host_report, evaluate
     from repro.core.server import RSUServer
     from repro.models.cnn import init_cnn
@@ -76,8 +76,8 @@ def run_handover_simulation(sc, vehicles_data: Sequence,
                          cl_scale=None if flt is None else flt.cl_scale)
     queue = timeline.queue
     fleet_batch = min(batch_size, min(d.size for d in vehicles_data))
-    clients = [Vehicle(d, lr=sc.lr, batch_size=fleet_batch, seed=seed)
-               for d in vehicles_data]
+    clients = Samplers(vehicles_data, lr=sc.lr, batch_size=fleet_batch,
+                       seed=seed)
 
     def schedule(vehicle: int, t_download: float):
         rsu = int(corridor.serving_rsu(vehicle, t_download))

@@ -12,7 +12,7 @@ report splits into:
   spent tracing, lowering and compiling them) and ``memory`` peaks from
   :mod:`repro.telemetry.timers`, and ``world`` counts (vehicles, shard
   rows, host bytes of the pool and its row indices, rows gathered into
-  minibatches) from ``ShardPool.world_counts``,
+  minibatches, samplers built) from ``ShardPool.world_counts``,
 - plan-derived statics: ``selection`` (the former extras entry) and
   ``waves`` fill/utilization — known before the device runs,
 - device channels (``metrics=on`` only): staleness histogram, occupancy

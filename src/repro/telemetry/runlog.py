@@ -106,7 +106,9 @@ def render(runs: list[dict]) -> str:
             out.append(f"  world: {world['vehicles']} vehicles, "
                        f"{world['shard_rows']} shard rows, "
                        f"{world['host_bytes'] / 2**20:.2f} MiB on the host, "
-                       f"{world['rows_gathered']} rows gathered")
+                       f"{world['rows_gathered']} rows gathered"
+                       + (f", {world['samplers']} samplers built"
+                          if "samplers" in world else ""))
         mem = d.get("memory") or {}
         if "peak_rss_bytes" in mem:
             out.append(f"  peak rss: {mem['peak_rss_bytes'] / 2**30:.2f} GiB")

@@ -123,12 +123,15 @@ def test_run_report_world_counts():
     sc = get_scenario("quick-k5")
     veh = build_world(sc, seed=0)[0]
     batch = min(128, min(d.size for d in veh))
-    report = run_scenario("quick-k5", engine="jit").report
+    result = run_scenario("quick-k5", engine="jit")
+    report = result.report
     assert report.world == {
         "vehicles": sc.K,
         "shard_rows": sum(d.size for d in veh),
         "host_bytes": veh[0].pool.world_counts(sc.K)["host_bytes"],
         "rows_gathered": sc.rounds * sc.l_iters * batch,
+        # a sampler for each vehicle that drew, none for the others
+        "samplers": len({r.vehicle for r in result.rounds}),
     }
     assert (f"world: {sc.K} vehicles, {report.world['shard_rows']} shard "
             "rows") in runlog.render([report.to_json()])
