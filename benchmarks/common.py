@@ -65,20 +65,11 @@ def averaged_curves(scheme: str, rounds=ROUNDS, eval_every=4, params=None,
             np.mean(losses, axis=0).tolist())
 
 
-REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-
-
 def save_result(name: str, payload: dict):
-    """Write the artifact under ``benchmarks/results/`` and, for the
-    committed (non-quick) artifacts, copy it to the repo root where the
-    perf-trajectory tracker reads ``BENCH_*.json`` — results/ alone is
-    invisible to it (ISSUE 5 satellite)."""
+    """Write the artifact under ``benchmarks/results/``, creating it."""
     os.makedirs(RESULTS_DIR, exist_ok=True)
     path = os.path.join(RESULTS_DIR, f"{name}.json")
     payload["timestamp"] = time.strftime("%Y-%m-%d %H:%M:%S")
     with open(path, "w") as f:
         json.dump(payload, f, indent=1)
-    if name.startswith("BENCH_") and not name.endswith("_quick"):
-        with open(os.path.join(REPO_ROOT, f"{name}.json"), "w") as f:
-            json.dump(payload, f, indent=1)
     return path

@@ -14,8 +14,9 @@ One chip runs, in order:
 3. each device engine against its host oracle on a short world, traces
    exactly and final parameters within ``CONFORMANCE_ATOL``.
 
-``--chips 4`` runs only ``corridor-r8-k4000`` on the pytree layout with
-two RSUs per chip, and the same world unsharded on one device.
+``--chips 4`` runs only ``corridor-r8-k4000`` with its packed cohort
+stack sharded two RSUs per chip, and the same world unsharded on one
+device.
 
 Everything runs in this one process and nothing is caught: any failed
 check is a non-zero exit.  Without a TPU the script exits before any
@@ -188,8 +189,8 @@ def conformance_phase(fleet=("fleet-k1000", 10),
 
 
 def rsu_mesh_phase(devs, n_chips: int, name: str = "corridor-r8-k4000"):
-    """The corridor's cohort stack sharded over an ``"rsu"`` mesh against
-    the same world unsharded on one device (pytree layout on both)."""
+    """The corridor's packed cohort stack sharded over an ``"rsu"`` mesh
+    against the same world unsharded on one device."""
     from jax.sharding import Mesh
 
     from repro.core.scenarios import run_scenario
@@ -199,9 +200,9 @@ def rsu_mesh_phase(devs, n_chips: int, name: str = "corridor-r8-k4000"):
                            f"found {len(devs)}")
     mesh = Mesh(np.asarray(devs[:n_chips]), ("rsu",))
     t0 = time.perf_counter()
-    sharded = run_scenario(name, engine="corridor", flat=False, mesh=mesh)
+    sharded = run_scenario(name, engine="corridor", mesh=mesh)
     t1 = time.perf_counter()
-    single = run_scenario(name, engine="corridor", flat=False)
+    single = run_scenario(name, engine="corridor")
     t2 = time.perf_counter()
     print(f"rsu-mesh {name}: sharded over {n_chips} wall_s={t1 - t0!r}, "
           f"one device wall_s={t2 - t1!r}", flush=True)

@@ -126,10 +126,10 @@ def _jit_probe(ring_dtype: str) -> list[Finding]:
         veh, scheme="mafl", rounds=6, l_iters=1, lr=0.05, params=p,
         seed=0, eval_every=3, use_kernel=False, init_params=None,
         interpretation="mixing", batch_size=32, mesh=None, selection=None,
-        flat=True, ring_dtype=ring_dtype)
+        ring_dtype=ring_dtype)
     jaxpr = jax.make_jaxpr(prog)(*args)
     return check_jaxpr(jaxpr, allow_bf16=ring_dtype == "bf16",
-                       path=f"<probe:jit-flat-{ring_dtype}>")
+                       path=f"<probe:jit-{ring_dtype}>")
 
 
 def _corridor_probe(ring_dtype: str) -> list[Finding]:
@@ -144,16 +144,16 @@ def _corridor_probe(ring_dtype: str) -> list[Finding]:
     prog, args, *_ = _stage_run(
         sc, veh, p, seed=0, eval_every=3, interpretation="mixing",
         use_kernel=False, batch_size=32, mesh=None, record_cohorts=False,
-        init_params=None, selection=None, flat=True)
+        init_params=None, selection=None)
     jaxpr = jax.make_jaxpr(prog)(*args)
     return check_jaxpr(jaxpr, allow_bf16=ring_dtype == "bf16",
-                       path=f"<probe:corridor-flat-{ring_dtype}>")
+                       path=f"<probe:corridor-{ring_dtype}>")
 
 
 def probe_dtype_flow() -> list[Finding]:
     """Stage four engine configurations and dtype-check their jaxprs:
-    jit flat f32 (must be bf16-free), jit flat bf16 and corridor flat bf16
-    (bf16 in storage roles only), corridor flat f32 (bf16-free)."""
+    jit f32 (must be bf16-free), jit bf16 and corridor bf16 (bf16 in
+    storage roles only), corridor f32 (bf16-free)."""
     findings: list[Finding] = []
     findings += _jit_probe("f32")
     findings += _jit_probe("bf16")

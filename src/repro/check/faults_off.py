@@ -52,7 +52,7 @@ def _jit_findings() -> list[Finding]:
     kw = dict(scheme="mafl", rounds=6, l_iters=1, lr=0.05, params=p,
               seed=0, eval_every=3, use_kernel=False, init_params=None,
               interpretation="mixing", batch_size=32, mesh=None,
-              selection=None, flat=True, ring_dtype="f32")
+              selection=None, ring_dtype="f32")
     base, *_ = _stage_run(veh, faults=None, **kw)
     off, *_ = _stage_run(veh, faults="off", **kw)
     on, *_ = _stage_run(veh, faults="flaky", **kw)
@@ -79,8 +79,7 @@ def _corridor_findings() -> list[Finding]:
     veh, _, _, p = build_world(sc, seed=0)
     kw = dict(seed=0, eval_every=3, interpretation="mixing",
               use_kernel=False, batch_size=32, mesh=None,
-              record_cohorts=False, init_params=None, selection=None,
-              flat=True)
+              record_cohorts=False, init_params=None, selection=None)
     base, *_ = _stage_run(sc, veh, p, faults=None, **kw)
     off, *_ = _stage_run(sc, veh, p, faults="off", **kw)
     on, *_ = _stage_run(sc, veh, p, faults="flaky", **kw)

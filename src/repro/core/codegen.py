@@ -5,10 +5,10 @@ Those digests depend on more than the (jax, numpy) versions the fixtures
 record: XLA:CPU's f32 codegen is hardware-dependent — FMA contraction and
 vectorization vary with the host CPU's feature set, so the same program on
 the same library versions can legitimately produce different low bits on a
-different machine (the flat==pytree *relationship* still holds there; only
-the absolute bits move).  Version equality alone is therefore the wrong
-gate: it passes on a host whose codegen differs from the fixture machine
-and the digest assertions fire spuriously.
+different machine (the traces and the cross-engine tolerances still
+hold there; only the absolute bits move).  Version equality alone is
+therefore the wrong gate: it passes on a host whose codegen differs from
+the fixture machine and the digest assertions fire spuriously.
 
 This module computes a compact fingerprint of the codegen environment by
 actually *running* a deterministic probe program through the same kernels
@@ -18,7 +18,7 @@ log2 chain of Eqs. 5-11 — and digesting the f32 results.  Two hosts that
 agree on the probe digest agree on the codegen of everything the fixtures
 pin; the fixtures record the fingerprint at refresh time and the tests
 compare digests only when it matches (``tests/golden/refresh.py``,
-``tests/test_golden_traces.py``, ``tests/test_flat_conformance.py``).
+``tests/test_golden_traces.py``).
 """
 from __future__ import annotations
 

@@ -39,14 +39,12 @@ the *event loop itself* into XLA:
   argmin/scalar/elementwise-aggregation ops, which lose nothing inside a
   compiled loop body.
 
-- **Packed flat fast path (default, DESIGN.md §12).**  ``flat=True``
-  replaces the pytree model states with one lane-aligned ``f32[P]``
-  buffer per state (``core/flat.py``): the model leaves the scan carry,
-  the ring materializes only checkpoint rows, aggregation is one vector
-  op per pop (or a fused ``ring_agg`` chain under ``use_kernel`` /
-  accelerator backends), and ``ring_dtype="bf16"`` halves the ring +
-  upload buffers around f32 master weights.  ``flat=False`` keeps the
-  legacy pytree program below as the benchmark baseline.
+- **Packed flat parameters (DESIGN.md §12).**  Every model state is one
+  lane-aligned ``f32[P]`` buffer (``core/flat.py``): the model leaves the
+  scan carry, the ring materializes only checkpoint rows, aggregation is
+  one vector op per pop (or a fused ``ring_agg`` chain under
+  ``use_kernel`` / accelerator backends), and ``ring_dtype="bf16"``
+  halves the ring + upload buffers around f32 master weights.
 
 Times inside the program are ``f32`` (the event semantics are unchanged;
 conformance vs the f64 host engines is to tolerance — pinned exactly on the
@@ -62,6 +60,7 @@ scheme, both of which carry host-side state between arrivals.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -267,14 +266,14 @@ def _wave_train(local_scan, mesh, n_events, shared: bool,
     scan — the trainer takes a per-event epoch-count vector as a trailing
     argument, mapped over the event axis like the minibatches.
 
-    The trained weights pass through an ``optimization_barrier``: without
-    it XLA:CPU re-fuses the SGD epilogue (``w - lr*g``) into whatever
-    consumes the wave — and FMA-contracts it differently per consumer, so
-    the *same* training would yield different low bits under the pytree
-    and flat layouts (DESIGN.md §12).  The host engines materialize
-    training outputs at their jit-call boundaries by construction; the
-    barrier gives the device programs the same property, making the flat
-    fast path bitwise against the pytree path."""
+    The trained weights pass through an ``optimization_barrier``, for
+    parity with the host engines: they materialize training outputs at
+    their jit-call boundaries by construction, and the barrier gives the
+    device programs the same boundary.  Without it XLA:CPU re-fuses the
+    SGD epilogue (``w - lr*g``) into whatever consumes the wave — and
+    FMA-contracts it differently per consumer, so the *same* training
+    would yield low bits that depend on the aggregation around it
+    (DESIGN.md §12)."""
     axes = (None if shared else 0, 0, 0, None) + ((0,) if partial else ())
     vf = jax.vmap(local_scan, in_axes=axes)
 
@@ -315,8 +314,28 @@ def _ring_interpret(use_kernel: bool):
     return resolve_interpret("weighted_agg.ring_agg_2d")
 
 
+def _replicated(fn, mesh):
+    """Wrap ``fn``, which may call a Pallas kernel, for a program over
+    ``mesh``: on several devices it runs once per device on replicated
+    operands inside a ``shard_map``, since XLA cannot partition a Mosaic
+    kernel; on one device (or with no mesh) it is ``fn`` itself."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    from jax.sharding import PartitionSpec as P
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
+
+
+def _ring_agg(use_kernel: bool, mesh):
+    """``ring_agg`` as the device programs call it: ``g, locs, coeffs``
+    in the mode of :func:`_ring_interpret`, replicated over ``mesh``."""
+    from repro.kernels.weighted_agg import ops as agg_ops
+    return _replicated(functools.partial(
+        agg_ops.ring_agg, interpret=_ring_interpret(use_kernel)), mesh)
+
+
 def _chain_segment(g, locals_buf, coeffs, snaps, s: int, e: int,
-                   needed, store, ring_interpret):
+                   needed, store, ring_agg):
     """Advance the f32 master ``g`` across scan segment ``[s, e)`` as fused
     ``ring_agg`` chains, materializing a snapshot row only at the rounds in
     ``needed`` (later-wave payloads / evals) — the global model streams
@@ -324,13 +343,12 @@ def _chain_segment(g, locals_buf, coeffs, snaps, s: int, e: int,
     arithmetic stays the bitwise sequential chain (DESIGN.md §12).
 
     ``coeffs`` are the segment's per-upload (c, d) pairs (f32[e-s, 2]);
-    ``snaps`` is the trace-level dict of stored ring rows."""
-    from repro.kernels.weighted_agg import ops as agg_ops
+    ``snaps`` is the trace-level dict of stored ring rows; ``ring_agg``
+    is :func:`_ring_agg`'s callable."""
     a = s
     for b in sorted({x for x in needed if s < x <= e} | {e}):
         if b > a:
-            g = agg_ops.ring_agg(g, locals_buf[a:b], coeffs[a - s:b - s],
-                                 interpret=ring_interpret)
+            g = ring_agg(g, locals_buf[a:b], coeffs[a - s:b - s])
         if b in needed:
             snaps[b] = store(g)
         a = b
@@ -339,19 +357,18 @@ def _chain_segment(g, locals_buf, coeffs, snaps, s: int, e: int,
 
 def _build_program(plan: FleetPlan, p: ChannelParams, *, scheme: str,
                    interpretation: str, use_kernel: bool, mesh,
-                   fedasync_mix: float, flat_layout=None,
-                   ring_dtype: str = "f32", eval_rounds: tuple = (),
-                   metrics=None, l_iters: int = 1):
+                   fedasync_mix: float, layout, ring_dtype: str = "f32",
+                   eval_rounds: tuple = (), metrics=None, l_iters: int = 1):
     """Trace-time constants live in the closure; the returned function is
     cached on the plan/world structure so repeated runs of the same world
     (determinism tests, warm benchmarks) compile exactly once.
 
-    ``flat_layout`` selects the packed flat-parameter fast path (DESIGN.md
-    §12): model states become lane-aligned ``[P]`` buffers, the model
-    leaves the event-loop scan entirely (the scan carries only queue
-    columns), and each segment's aggregation runs as a fused ``ring_agg``
-    chain.  ``ring_dtype="bf16"`` stores snapshot rows and upload buffers
-    in bf16 (f32 master weights, f32 accumulation)."""
+    ``layout`` packs each model state into a lane-aligned ``[P]`` buffer
+    (DESIGN.md §12): the model leaves the event-loop scan (off the CPU the
+    scan carries only queue columns), and each segment's aggregation runs
+    as a fused ``ring_agg`` chain.  ``ring_dtype="bf16"`` stores snapshot
+    rows and upload buffers in bf16 (f32 master weights, f32
+    accumulation)."""
     M = len(plan.veh)
     K = p.K
     d = np.asarray(plan.dl_round)
@@ -431,10 +448,8 @@ def _build_program(plan: FleetPlan, p: ChannelParams, *, scheme: str,
     def eq36_upload_delay(gains, x0, idx, t_up):
         """Eq. 3-6 re-schedule pipeline: slot gain -> position wrap ->
         distance -> SNR -> Shannon rate -> upload delay.  ``idx`` may be
-        a scalar pop or a vector of re-admissions; ONE definition serves
-        the legacy and flat scan bodies and both readmit helpers — the
-        arithmetic (and its op order) is part of the flat-vs-pytree
-        bitwise pin, so it must never fork."""
+        a scalar pop or a vector of re-admissions; one definition serves
+        the scan body and the re-admission helper."""
         slot = jnp.clip(t_up.astype(jnp.int32), 0, n_slots - 1)
         gain = gains[slot, idx]
         dx = x0[idx] + v_c * t_up                       # Eq. 3
@@ -445,21 +460,16 @@ def _build_program(plan: FleetPlan, p: ChannelParams, *, scheme: str,
         return bits / jnp.maximum(rate, 1e-12)          # Eq. 6
 
     def aggregate(g, loc, t, cu, cl, dl_t):
-        """One arrival's update — mirrors the host paths bit-for-bit in
-        formula and f32 arithmetic (aggregation.mix_update_donated /
-        literal_update_donated / weighted_agg kernel)."""
+        """One arrival's in-scan update of the f32 master ``g`` — mirrors
+        the host paths in formula and f32 arithmetic
+        (aggregation.mix_update_donated / literal_update_donated)."""
         if scheme == "mafl":
             weight = gamma ** (cu - 1.0) * zeta ** (cl - 1.0)   # Eqs. 7, 9
         else:
             weight = jnp.float32(1.0)
         if scheme == "mafl" and interpretation == "literal":
-            if use_kernel:
-                from repro.kernels.weighted_agg import ops as agg_ops
-                return agg_ops.weighted_agg_tree(g, loc, beta, weight), weight
-            new = jax.tree_util.tree_map(
-                lambda a, b: (beta * a.astype(jnp.float32) +
-                              (1.0 - beta) * weight *
-                              b.astype(jnp.float32)).astype(a.dtype), g, loc)
+            new = (beta * g.astype(jnp.float32) + (1.0 - beta) * weight *
+                   loc.astype(jnp.float32)).astype(g.dtype)
             return new, weight
         if scheme == "mafl":
             alpha = jnp.clip((1.0 - beta) * weight, 0.0, 1.0)
@@ -468,306 +478,112 @@ def _build_program(plan: FleetPlan, p: ChannelParams, *, scheme: str,
         else:                                                   # fedasync
             stale = jnp.maximum(t - dl_t, 0.0)
             alpha = f_mix * (stale + 1.0) ** (-0.5)
-        if use_kernel:
-            from repro.kernels.weighted_agg import ops as agg_ops
-            return agg_ops.weighted_agg_tree(g, loc, 1.0 - alpha,
-                                             jnp.float32(1.0)), weight
-        new = jax.tree_util.tree_map(
-            lambda a, b: ((1.0 - alpha) * a.astype(jnp.float32) +
-                          alpha * b.astype(jnp.float32)).astype(a.dtype),
-            g, loc)
+        new = ((1.0 - alpha) * g.astype(jnp.float32) +
+               alpha * loc.astype(jnp.float32)).astype(g.dtype)
         return new, weight
 
-    if flat_layout is not None:
-        from repro.core.aggregation import chain_coeffs
+    from repro.core.aggregation import chain_coeffs
 
-        layout = flat_layout
-        bf16 = ring_dtype == "bf16"
-        store_dtype = jnp.bfloat16 if bf16 else jnp.float32
-        store = ((lambda x: x.astype(jnp.bfloat16)) if bf16
-                 else (lambda x: x))
-        ring_interp = _ring_interpret(use_kernel)
-        # Fused-chain mode: aggregation leaves the scan entirely and runs
-        # as ring_agg chains between checkpoints (the multi-upload Pallas
-        # kernel on TPU/GPU, its jnp form under use_kernel on CPU).  On
-        # the CPU default the mix stays *inside* the scan instead,
-        # operating on the packed [P] buffer: XLA:CPU FMA-contracts fused
-        # elementwise loops by emission context (flags cannot disable it,
-        # DESIGN.md §12), and the in-scan form is the one that reproduces
-        # the pytree path's golden digests bit-for-bit.
-        fused_chain = use_kernel or jax.default_backend() != "cpu"
-        # rounds whose post-round model must materialize: later-wave
-        # payloads and eval rows — everything else is never read, so the
-        # chain streams straight through it
-        needed = set(int(x) for x in eval_rounds)
-        for T, _s, _e in plan.waves:
-            needed |= {int(d[t]) + 1 for t in T if d[t] >= 0}
+    bf16 = ring_dtype == "bf16"
+    store_dtype = jnp.bfloat16 if bf16 else jnp.float32
+    store = ((lambda x: x.astype(jnp.bfloat16)) if bf16
+             else (lambda x: x))
+    ring_agg = _ring_agg(use_kernel, mesh)
+    # Fused-chain mode: aggregation leaves the scan entirely and runs
+    # as ring_agg chains between checkpoints (the multi-upload Pallas
+    # kernel on TPU/GPU, its jnp form under use_kernel on CPU).  On
+    # the CPU default the mix stays *inside* the scan instead,
+    # operating on the packed [P] buffer: XLA:CPU FMA-contracts fused
+    # elementwise loops by emission context (flags cannot disable it,
+    # DESIGN.md §12), and the in-scan form is the one the golden digests
+    # were pinned on.
+    fused_chain = use_kernel or jax.default_backend() != "cpu"
+    # rounds whose post-round model must materialize: later-wave
+    # payloads and eval rows — everything else is never read, so the
+    # chain streams straight through it
+    needed = set(int(x) for x in eval_rounds)
+    for T, _s, _e in plan.waves:
+        needed |= {int(d[t]) + 1 for t in T if d[t] >= 0}
 
-        def program_flat(w0, gains, x0, qt, qdl, qcu, qcl, imgs, labs,
-                         lr):
-            local_scan = (client_mod._local_scan_partial if has_partial
-                          else client_mod._local_scan)
-            g = layout.pack(w0)                 # f32[P] master weights
-            locals_buf = jnp.zeros((M, layout.P), store_dtype)
-            mst = ring_stats = None
-            store_row = store
-            if met_on:
-                mst = tel_dev.fleet_state(metrics)
-                if metrics.ring_guard and bf16:
-                    # trace-level bf16 ring guard: every stored snapshot
-                    # row is counted for non-finite / max-|x| (DESIGN §14)
-                    ring_stats = tel_dev.RingStats()
-                    store_row = ring_stats.wrap(store)
-            snaps = {0: store_row(g)}
-            rs = rc = None
-            if with_state:
-                rs = jnp.zeros(K, jnp.float32)
-                rc = jnp.zeros(K, jnp.float32)
-            traces = []
-
-            def make_flat_body(locals_buf):
-                # fused_chain: queue bookkeeping only — the model is out
-                # of the scan carry entirely and aggregation streams
-                # per-checkpoint afterwards.  Otherwise the [P]-buffer mix
-                # rides in the scan (one fused vector op per pop instead
-                # of one op per leaf), bitwise the legacy body.  Fresh
-                # body per segment: locals_buf rebinds per wave (the
-                # lax.scan traced-body cache pitfall, DESIGN.md §9).
-                def seg_body(carry, r):
-                    if met_on:
-                        carry, mst = carry[:-1], carry[-1]
-                    if fused_chain:
-                        g = None
-                        if with_state:
-                            qt, qdl, qcu, rs, rc = carry
-                        else:
-                            qt, qdl, qcu = carry
-                    elif with_state:
-                        g, qt, qdl, qcu, rs, rc = carry
-                    else:
-                        g, qt, qdl, qcu = carry
-                    i = jnp.argmin(qt)                          # pop
-                    if met_on:
-                        # live slots at the instant of pop (incl. this one)
-                        occ = jnp.sum(jnp.isfinite(qt)).astype(jnp.int32)
-                    t, cu, cl, dl_t = qt[i], qcu[i], qcl[i], qdl[i]
-                    if fused_chain:
-                        if scheme == "mafl":
-                            weight = gamma ** (cu - 1.0) * zeta ** (cl - 1.0)
-                        else:
-                            weight = jnp.float32(1.0)
-                    else:
-                        # Eq. 10+11 on the packed buffer, one vector op;
-                        # a cap-discarded pop keeps the old master exactly
-                        # (the host skips the update outright)
-                        g_new, weight = aggregate(g, locals_buf[r], t, cu,
-                                                  cl, dl_t)
-                        g = (jnp.where(keep_tab[r], g_new, g) if has_cap
-                             else g_new)
-                    if with_state:
-                        rew = gamma ** (cu - 1.0) * zeta ** (cl - 1.0)
-                        rs = rs.at[i].add(rew)
-                        rc = rc.at[i].add(1.0)
-                    t_up = t + cl
-                    cu_new = eq36_upload_delay(gains, x0, i, t_up)
-                    t_new = t_up + cu_new
-                    if adm_active:
-                        t_new = jnp.where(adm_tab[r, i], t_new, jnp.inf)
-                    qt = qt.at[i].set(t_new)
-                    qdl = qdl.at[i].set(t)
-                    qcu = qcu.at[i].set(cu_new)
-                    if fused_chain:
-                        out = ((qt, qdl, qcu, rs, rc) if with_state
-                               else (qt, qdl, qcu))
-                    else:
-                        out = ((g, qt, qdl, qcu, rs, rc) if with_state
-                               else (g, qt, qdl, qcu))
-                    ys = (i, t, cu, cl, dl_t, weight)
-                    if met_on:
-                        mst, gap = tel_dev.fleet_pop(
-                            mst, met_edges, t=t, dl_t=dl_t,
-                            fault_row=fct_tab[r] if fct_on else None)
-                        out = out + (mst,)
-                        ys = ys + (occ, gap)
-                    return out, ys
-                return seg_body
-
-            def readmit(qt, qdl, qcu, A, t_b):
-                A = jnp.asarray(A)
-                t_up = t_b + qcl[A]
-                cu_new = eq36_upload_delay(gains, x0, A, t_up)
-                return (qt.at[A].set(t_up + cu_new), qdl.at[A].set(t_b),
-                        qcu.at[A].set(cu_new))
-
-            for T, s, e in plan.waves:
-                T = np.asarray(T, np.int32)
-                if len(T):
-                    pay_rounds = d[T] + 1
-                    shared = bool((pay_rounds == pay_rounds[0]).all())
-                    if shared:
-                        pay = layout.unpack(snaps[int(pay_rounds[0])])
-                    else:
-                        pay = layout.unpack(jnp.stack(
-                            [snaps[int(pr)] for pr in pay_rounds]))
-                    train = _wave_train(local_scan, mesh, len(T), shared,
-                                        partial=has_partial)
-                    extra = (ep_tab[jnp.asarray(T)],) if has_partial else ()
-                    with jax.named_scope(f"wave_train_{s}"):
-                        loc, _ = train(pay, imgs[T], labs[T], lr, *extra)
-                    locals_buf = locals_buf.at[jnp.asarray(T)].set(
-                        layout.pack(loc, dtype=store_dtype))
-                seg_traces = []
-                # sub-split at re-admission boundaries; the in-scan-mix
-                # mode additionally splits at checkpoints so snapshot rows
-                # store at trace level between sub-scans
-                pts = {b for b in readmit_at if s < b <= e} | {e}
-                if not fused_chain:
-                    pts |= {b for b in needed if s < b <= e}
-                a = s
-                for b in sorted(pts):
-                    if b > a:
-                        if fused_chain:
-                            carry0 = ((qt, qdl, qcu, rs, rc) if with_state
-                                      else (qt, qdl, qcu))
-                        else:
-                            carry0 = ((g, qt, qdl, qcu, rs, rc)
-                                      if with_state else (g, qt, qdl, qcu))
-                        if met_on:
-                            carry0 = carry0 + (mst,)
-                        with jax.named_scope(f"event_scan_{a}_{b}"):
-                            carry, ys = jax.lax.scan(
-                                make_flat_body(locals_buf), carry0,
-                                jnp.arange(a, b))
-                        if met_on:
-                            carry, mst = carry[:-1], carry[-1]
-                        if fused_chain:
-                            if with_state:
-                                qt, qdl, qcu, rs, rc = carry
-                            else:
-                                qt, qdl, qcu = carry
-                        elif with_state:
-                            g, qt, qdl, qcu, rs, rc = carry
-                        else:
-                            g, qt, qdl, qcu = carry
-                        traces.append(ys)
-                        seg_traces.append(ys)
-                    if not fused_chain and b in needed:
-                        snaps[b] = store_row(g)
-                    if b in readmit_at:
-                        qt, qdl, qcu = readmit(qt, qdl, qcu, readmit_at[b],
-                                               traces[-1][1][-1])
-                    a = b
-                if fused_chain:
-                    # aggregation left the scan entirely: coefficients
-                    # from the segment's own f32 trace (bitwise the legacy
-                    # per-arrival expressions), then one streaming
-                    # ring_agg chain per checkpoint interval
-                    t_c, dlt_c, w_c = (
-                        jnp.concatenate([tr[k] for tr in seg_traces])
-                        for k in (1, 4, 5))
-                    cc, dd = chain_coeffs(scheme, interpretation, p.beta,
-                                          w_c, t=t_c, dl_t=dlt_c,
-                                          fedasync_mix=fedasync_mix)
-                    if has_cap:
-                        # cap-discarded pops become exact chain no-ops
-                        keep_seg = keep_tab[s:e]
-                        cc = jnp.where(keep_seg, cc, 1.0)
-                        dd = jnp.where(keep_seg, dd, 0.0)
-                    coeffs = jnp.stack([cc, dd], axis=1)
-                    with jax.named_scope(f"ring_chain_{s}_{e}"):
-                        g = _chain_segment(g, locals_buf, coeffs, snaps,
-                                           s, e, needed, store_row,
-                                           ring_interp)
-            trace = tuple(jnp.concatenate([tr[k] for tr in traces])
-                          for k in range(6))
-            evals = jnp.stack([snaps[rr] for rr in eval_rounds])
-            if with_state:
-                ret = (layout.unpack(g), evals, trace, (rs, rc))
-            else:
-                ret = (layout.unpack(g), evals, trace)
-            if met_on:
-                met_out = {
-                    "stale_hist": mst[0],
-                    "occupancy": jnp.concatenate(
-                        [tr[6] for tr in traces]),
-                    "gap": jnp.concatenate([tr[7] for tr in traces]),
-                }
-                if fct_on:
-                    met_out["fault_counts"] = mst[2]
-                if ring_stats is not None:
-                    met_out.update(ring_stats.out())
-                ret = ret + (met_out,)
-            return ret
-
-        return jax.jit(program_flat)
-
-    def program(w0, gains, x0, qt, qdl, qcu, qcl, imgs, labs, lr):
+    def program_flat(w0, gains, x0, qt, qdl, qcu, qcl, imgs, labs, lr):
         local_scan = (client_mod._local_scan_partial if has_partial
                       else client_mod._local_scan)
-        ring = jax.tree_util.tree_map(
-            lambda x: jnp.zeros((M + 1,) + x.shape, x.dtype).at[0].set(x), w0)
-        locals_buf = jax.tree_util.tree_map(
-            lambda x: jnp.zeros((M,) + x.shape, x.dtype), w0)
-        g = w0
-        mst = tel_dev.fleet_state(metrics) if met_on else None
+        g = layout.pack(w0)                 # f32[P] master weights
+        locals_buf = jnp.zeros((M, layout.P), store_dtype)
+        mst = ring_stats = None
+        store_row = store
+        if met_on:
+            mst = tel_dev.fleet_state(metrics)
+            if metrics.ring_guard and bf16:
+                # trace-level bf16 ring guard: every stored snapshot
+                # row is counted for non-finite / max-|x| (DESIGN §14)
+                ring_stats = tel_dev.RingStats()
+                store_row = ring_stats.wrap(store)
+        snaps = {0: store_row(g)}
         rs = rc = None
         if with_state:
             rs = jnp.zeros(K, jnp.float32)
             rc = jnp.zeros(K, jnp.float32)
         traces = []
 
-        def make_seg_body(locals_buf):
-            # A *fresh* body function per scan segment: lax.scan caches the
-            # traced body jaxpr on the function's identity plus per-step
-            # avals, which are identical for every segment — reusing one
-            # closure across segments silently replays the first segment's
-            # capture of ``locals_buf`` and aggregates zeros for every
-            # later wave.
+        def make_flat_body(locals_buf):
+            # fused_chain: queue bookkeeping only — the model is out
+            # of the scan carry entirely and aggregation streams
+            # per-checkpoint afterwards.  Otherwise the [P]-buffer mix
+            # rides in the scan (one fused vector op per pop).  A
+            # fresh body per segment: lax.scan caches the traced body
+            # on the function's identity, so one closure reused across
+            # segments would replay the first wave's locals_buf
+            # (DESIGN.md §9).
             def seg_body(carry, r):
                 if met_on:
                     carry, mst = carry[:-1], carry[-1]
-                if with_state:
-                    g, ring, qt, qdl, qcu, rs, rc = carry
+                if fused_chain:
+                    g = None
+                    if with_state:
+                        qt, qdl, qcu, rs, rc = carry
+                    else:
+                        qt, qdl, qcu = carry
+                elif with_state:
+                    g, qt, qdl, qcu, rs, rc = carry
                 else:
-                    g, ring, qt, qdl, qcu = carry
-                i = jnp.argmin(qt)                              # pop
+                    g, qt, qdl, qcu = carry
+                i = jnp.argmin(qt)                          # pop
                 if met_on:
                     # live slots at the instant of pop (incl. this one)
                     occ = jnp.sum(jnp.isfinite(qt)).astype(jnp.int32)
                 t, cu, cl, dl_t = qt[i], qcu[i], qcl[i], qdl[i]
-                loc = jax.tree_util.tree_map(lambda B: B[r], locals_buf)
-                g_new, weight = aggregate(g, loc, t, cu, cl,
-                                          dl_t)             # Eq. 10+11
-                if has_cap:
-                    # cap-discarded pop: the global model stays exactly
-                    # put (the host skips the update outright)
-                    g = jax.tree_util.tree_map(
-                        lambda old, new: jnp.where(keep_tab[r], new, old),
-                        g, g_new)
+                if fused_chain:
+                    if scheme == "mafl":
+                        weight = gamma ** (cu - 1.0) * zeta ** (cl - 1.0)
+                    else:
+                        weight = jnp.float32(1.0)
                 else:
-                    g = g_new
-                ring = jax.tree_util.tree_map(
-                    lambda R, G: R.at[r + 1].set(G), ring, g)
+                    # Eq. 10+11 on the packed buffer, one vector op;
+                    # a cap-discarded pop keeps the old master exactly
+                    # (the host skips the update outright)
+                    g_new, weight = aggregate(g, locals_buf[r], t, cu,
+                                              cl, dl_t)
+                    g = (jnp.where(keep_tab[r], g_new, g) if has_cap
+                         else g_new)
                 if with_state:
-                    # the bandit reward is the paper's delay weight, folded
-                    # into the carried accumulators (Eqs. 7, 9)
                     rew = gamma ** (cu - 1.0) * zeta ** (cl - 1.0)
                     rs = rs.at[i].add(rew)
                     rc = rc.at[i].add(1.0)
-                # re-schedule vehicle i: download now, train C_l, upload C_u
                 t_up = t + cl
                 cu_new = eq36_upload_delay(gains, x0, i, t_up)
                 t_new = t_up + cu_new
                 if adm_active:
-                    # admission mask folded into the slot queue: a parked
-                    # (or dropped / blacked-out) vehicle's slot is +inf,
-                    # invisible to the argmin
                     t_new = jnp.where(adm_tab[r, i], t_new, jnp.inf)
                 qt = qt.at[i].set(t_new)
                 qdl = qdl.at[i].set(t)
                 qcu = qcu.at[i].set(cu_new)
-                out = ((g, ring, qt, qdl, qcu, rs, rc) if with_state
-                       else (g, ring, qt, qdl, qcu))
+                if fused_chain:
+                    out = ((qt, qdl, qcu, rs, rc) if with_state
+                           else (qt, qdl, qcu))
+                else:
+                    out = ((g, qt, qdl, qcu, rs, rc) if with_state
+                           else (g, qt, qdl, qcu))
                 ys = (i, t, cu, cl, dl_t, weight)
                 if met_on:
                     mst, gap = tel_dev.fleet_pop(
@@ -779,9 +595,6 @@ def _build_program(plan: FleetPlan, p: ChannelParams, *, scheme: str,
             return seg_body
 
         def readmit(qt, qdl, qcu, A, t_b):
-            """Boundary re-admission: schedule vehicles ``A`` (static) at
-            the traced boundary timestamp — the same Eq. 3-6 pipeline as
-            the in-scan re-schedule, vectorized over the newly admitted."""
             A = jnp.asarray(A)
             t_up = t_b + qcl[A]
             cu_new = eq36_upload_delay(gains, x0, A, t_up)
@@ -794,68 +607,104 @@ def _build_program(plan: FleetPlan, p: ChannelParams, *, scheme: str,
                 pay_rounds = d[T] + 1
                 shared = bool((pay_rounds == pay_rounds[0]).all())
                 if shared:
-                    pay = jax.tree_util.tree_map(
-                        lambda R: R[int(pay_rounds[0])], ring)
+                    pay = layout.unpack(snaps[int(pay_rounds[0])])
                 else:
-                    idx = jnp.asarray(pay_rounds)
-                    pay = jax.tree_util.tree_map(lambda R: R[idx], ring)
+                    pay = layout.unpack(jnp.stack(
+                        [snaps[int(pr)] for pr in pay_rounds]))
                 train = _wave_train(local_scan, mesh, len(T), shared,
                                     partial=has_partial)
                 extra = (ep_tab[jnp.asarray(T)],) if has_partial else ()
                 with jax.named_scope(f"wave_train_{s}"):
                     loc, _ = train(pay, imgs[T], labs[T], lr, *extra)
-                T_dev = jnp.asarray(T)
-                locals_buf = jax.tree_util.tree_map(
-                    lambda B, L: B.at[T_dev].set(L), locals_buf, loc)
-            # sub-split [s, e) at re-admission boundaries (static), so the
-            # boundary scheduling runs at trace level between scans
-            pts = sorted({b for b in readmit_at if s < b <= e} | {e})
+                locals_buf = locals_buf.at[jnp.asarray(T)].set(
+                    layout.pack(loc, dtype=store_dtype))
+            seg_traces = []
+            # sub-split at re-admission boundaries; the in-scan-mix
+            # mode additionally splits at checkpoints so snapshot rows
+            # store at trace level between sub-scans
+            pts = {b for b in readmit_at if s < b <= e} | {e}
+            if not fused_chain:
+                pts |= {b for b in needed if s < b <= e}
             a = s
-            for b in pts:
+            for b in sorted(pts):
                 if b > a:
-                    carry0 = ((g, ring, qt, qdl, qcu, rs, rc) if with_state
-                              else (g, ring, qt, qdl, qcu))
+                    if fused_chain:
+                        carry0 = ((qt, qdl, qcu, rs, rc) if with_state
+                                  else (qt, qdl, qcu))
+                    else:
+                        carry0 = ((g, qt, qdl, qcu, rs, rc)
+                                  if with_state else (g, qt, qdl, qcu))
                     if met_on:
                         carry0 = carry0 + (mst,)
                     with jax.named_scope(f"event_scan_{a}_{b}"):
                         carry, ys = jax.lax.scan(
-                            make_seg_body(locals_buf), carry0,
+                            make_flat_body(locals_buf), carry0,
                             jnp.arange(a, b))
                     if met_on:
                         carry, mst = carry[:-1], carry[-1]
-                    if with_state:
-                        g, ring, qt, qdl, qcu, rs, rc = carry
+                    if fused_chain:
+                        if with_state:
+                            qt, qdl, qcu, rs, rc = carry
+                        else:
+                            qt, qdl, qcu = carry
+                    elif with_state:
+                        g, qt, qdl, qcu, rs, rc = carry
                     else:
-                        g, ring, qt, qdl, qcu = carry
+                        g, qt, qdl, qcu = carry
                     traces.append(ys)
+                    seg_traces.append(ys)
+                if not fused_chain and b in needed:
+                    snaps[b] = store_row(g)
                 if b in readmit_at:
-                    # t_b = the boundary pop's timestamp (last of the
-                    # sub-segment that just ran)
                     qt, qdl, qcu = readmit(qt, qdl, qcu, readmit_at[b],
                                            traces[-1][1][-1])
                 a = b
+            if fused_chain:
+                # aggregation left the scan entirely: coefficients
+                # from the segment's own f32 trace (bitwise the
+                # in-scan per-arrival expressions), then one streaming
+                # ring_agg chain per checkpoint interval
+                t_c, dlt_c, w_c = (
+                    jnp.concatenate([tr[k] for tr in seg_traces])
+                    for k in (1, 4, 5))
+                cc, dd = chain_coeffs(scheme, interpretation, p.beta,
+                                      w_c, t=t_c, dl_t=dlt_c,
+                                      fedasync_mix=fedasync_mix)
+                if has_cap:
+                    # cap-discarded pops become exact chain no-ops
+                    keep_seg = keep_tab[s:e]
+                    cc = jnp.where(keep_seg, cc, 1.0)
+                    dd = jnp.where(keep_seg, dd, 0.0)
+                coeffs = jnp.stack([cc, dd], axis=1)
+                with jax.named_scope(f"ring_chain_{s}_{e}"):
+                    g = _chain_segment(g, locals_buf, coeffs, snaps,
+                                       s, e, needed, store_row, ring_agg)
         trace = tuple(jnp.concatenate([tr[k] for tr in traces])
                       for k in range(6))
+        evals = jnp.stack([snaps[rr] for rr in eval_rounds])
         if with_state:
-            ret = (g, ring, trace, (rs, rc))
+            ret = (layout.unpack(g), evals, trace, (rs, rc))
         else:
-            ret = (g, ring, trace)
+            ret = (layout.unpack(g), evals, trace)
         if met_on:
             met_out = {
                 "stale_hist": mst[0],
-                "occupancy": jnp.concatenate([tr[6] for tr in traces]),
+                "occupancy": jnp.concatenate(
+                    [tr[6] for tr in traces]),
                 "gap": jnp.concatenate([tr[7] for tr in traces]),
             }
             if fct_on:
                 met_out["fault_counts"] = mst[2]
+            if ring_stats is not None:
+                met_out.update(ring_stats.out())
             ret = ret + (met_out,)
         return ret
 
-    return jax.jit(program)
+    return jax.jit(program_flat)
 
 
 def _get_program(plan: FleetPlan, p: ChannelParams, *, scheme, interpretation,
-                 use_kernel, mesh, fedasync_mix, shapes, flat_layout=None,
+                 use_kernel, mesh, fedasync_mix, shapes, layout,
                  ring_dtype="f32", eval_rounds=(), metrics=None,
                  l_iters=1):
     # the trainer function rides in the key as the object itself, not its
@@ -869,8 +718,7 @@ def _get_program(plan: FleetPlan, p: ChannelParams, *, scheme, interpretation,
            _mesh_key(mesh), shapes,
            None if plan.sel is None else plan.sel.signature(),
            client_mod._local_scan,
-           None if flat_layout is None else flat_layout.signature(),
-           ring_dtype, eval_rounds if flat_layout is not None else (),
+           layout.signature(), ring_dtype, eval_rounds,
            None if metrics is None else metrics.signature(),
            None if plan.flt is None else (plan.flt.signature(), l_iters,
                                           client_mod._local_scan_partial))
@@ -880,7 +728,7 @@ def _get_program(plan: FleetPlan, p: ChannelParams, *, scheme, interpretation,
                               interpretation=interpretation,
                               use_kernel=use_kernel, mesh=mesh,
                               fedasync_mix=fedasync_mix,
-                              flat_layout=flat_layout, ring_dtype=ring_dtype,
+                              layout=layout, ring_dtype=ring_dtype,
                               eval_rounds=eval_rounds, metrics=metrics,
                               l_iters=l_iters)
         _PROGRAM_CACHE[key] = prog
@@ -893,7 +741,7 @@ def _get_program(plan: FleetPlan, p: ChannelParams, *, scheme, interpretation,
 
 def _stage_run(vehicles_data, *, scheme, rounds, l_iters, lr, params, seed,
                eval_every, use_kernel, init_params, interpretation,
-               batch_size, mesh, selection, flat, ring_dtype,
+               batch_size, mesh, selection, ring_dtype,
                metrics=None, faults=None, timers=None):
     """Validate, plan, and stage one fleet run — everything up to (but not
     including) executing the compiled program.  Split out of
@@ -916,10 +764,6 @@ def _stage_run(vehicles_data, *, scheme, rounds, l_iters, lr, params, seed,
     if ring_dtype not in ("f32", "bf16"):
         raise ValueError(f"unknown ring_dtype {ring_dtype!r}; "
                          "expected 'f32' or 'bf16'")
-    if ring_dtype == "bf16" and not flat:
-        raise ValueError("ring_dtype='bf16' requires the flat fast path "
-                         "(flat=True): only the packed ring stores bf16 "
-                         "snapshots around f32 master weights")
     p = params or ChannelParams()
     assert len(vehicles_data) == p.K, (len(vehicles_data), p.K)
     if rounds < 1:
@@ -966,14 +810,14 @@ def _stage_run(vehicles_data, *, scheme, rounds, l_iters, lr, params, seed,
         shapes = (imgs.shape, tuple(
             (str(path), v.shape, str(v.dtype))
             for path, v in jax.tree_util.tree_leaves_with_path(w0)))
-        layout = ParamLayout.from_tree(w0) if flat else None
+        layout = ParamLayout.from_tree(w0)
         eval_rounds = tuple(rr for rr in range(1, M + 1)
                             if rr % eval_every == 0 or rr == rounds)
         prog = _get_program(plan, p, scheme=scheme,
                             interpretation=interpretation,
                             use_kernel=use_kernel, mesh=mesh,
                             fedasync_mix=DEFAULT_FEDASYNC_MIX, shapes=shapes,
-                            flat_layout=layout, ring_dtype=ring_dtype,
+                            layout=layout, ring_dtype=ring_dtype,
                             eval_rounds=eval_rounds, metrics=met,
                             l_iters=l_iters)
         with_state = (plan.sel is not None and not plan.sel.is_noop
@@ -1005,7 +849,6 @@ def run_simulation_jit(
     batch_size: int = 128,
     mesh=None,
     selection=None,
-    flat: bool = True,
     ring_dtype: str = "f32",
     metrics=None,
     faults=None,
@@ -1013,14 +856,10 @@ def run_simulation_jit(
     """Run M rounds entirely on device; returns the same ``SimResult`` the
     host engines produce (same record fields, same eval cadence).
 
-    ``flat=True`` (the native layout, DESIGN.md §12) runs the packed
-    flat-parameter fast path: one ``[P]`` buffer per model state, queue
-    bookkeeping alone in the scan, fused ``ring_agg`` chains for the
-    aggregation — bitwise-identical outputs in f32 (golden-pinned);
-    ``flat=False`` keeps the legacy pytree program (the benchmark
-    baseline).  ``ring_dtype="bf16"`` (flat only) stores snapshot-ring
-    rows and upload buffers in bf16 around f32 master weights/accumulation
-    — halves ring memory at a documented sub-1e-2 parameter rounding
+    The program keeps every model state as one packed ``[P]`` buffer
+    (DESIGN.md §12).  ``ring_dtype="bf16"`` stores snapshot-ring rows and
+    upload buffers in bf16 around f32 master weights/accumulation —
+    halves ring memory at a documented sub-1e-2 parameter rounding
     (EXPERIMENTS.md §Flat); it must be requested explicitly.
 
     One behavioral difference from the host engines: the whole round loop
@@ -1053,7 +892,7 @@ def run_simulation_jit(
         lr=lr, params=params, seed=seed, eval_every=eval_every,
         use_kernel=use_kernel, init_params=init_params,
         interpretation=interpretation, batch_size=batch_size, mesh=mesh,
-        selection=selection, flat=flat, ring_dtype=ring_dtype,
+        selection=selection, ring_dtype=ring_dtype,
         metrics=metrics, faults=faults, timers=timers)
     M = rounds
     with timers.phase("run"):
@@ -1102,7 +941,7 @@ def run_simulation_jit(
                     "jit engine: device bandit reward accumulators "
                     "diverged from the host selection replay")
 
-        if flat and ring_dtype == "bf16":
+        if ring_dtype == "bf16":
             # bf16 divergence guard (DESIGN.md §12): the timeline guards
             # above stay exact (times never depend on params); the
             # parameters may only diverge by bf16 rounding — a non-finite
@@ -1126,12 +965,8 @@ def run_simulation_jit(
                               weight=float(t_w[r]))
             rr = r + 1
             if rr % eval_every == 0 or rr == rounds:
-                if flat:
-                    params_r = layout.unpack(ring[eval_idx[rr]])
-                else:
-                    params_r = jax.tree_util.tree_map(
-                        lambda R: R[rr], ring)
-                acc, loss = evaluate(params_r, test_images, test_labels)
+                acc, loss = evaluate(layout.unpack(ring[eval_idx[rr]]),
+                                     test_images, test_labels)
                 rec.accuracy, rec.loss = acc, loss
                 result.acc_history.append((rr, acc))
                 result.loss_history.append((rr, loss))

@@ -49,9 +49,8 @@ from repro.models.cnn import cnn_forward, init_cnn
 from repro.selection import make_selection_state
 
 
-# accepted run_simulation/run_scenario engine names ('unbatched' is a
-# legacy alias for 'serial')
-ENGINES = ("batched", "serial", "unbatched", "jit")
+# accepted run_simulation/run_scenario engine names
+ENGINES = ("batched", "serial", "jit")
 
 
 @dataclass
@@ -133,7 +132,6 @@ def run_simulation(
     wave_chunk: int = 16,
     batch_size: int = 128,
     selection=None,
-    flat: bool = True,
     ring_dtype: str = "f32",
     metrics=None,
     faults=None,
@@ -178,12 +176,11 @@ def run_simulation(
             eval_every=eval_every, use_kernel=use_kernel,
             init_params=init_params, interpretation=interpretation,
             progress=progress, batch_size=batch_size, selection=selection,
-            flat=flat, ring_dtype=ring_dtype, metrics=metrics,
-            faults=faults)
+            ring_dtype=ring_dtype, metrics=metrics, faults=faults)
     if ring_dtype != "f32":
-        # the bf16 snapshot ring exists only on the packed flat layout of
-        # the device engines (DESIGN.md §12) — an explicit gate, never a
-        # silent precision change on the host paths
+        # the bf16 snapshot ring exists only on the device engines
+        # (DESIGN.md §12) — an explicit gate, never a silent precision
+        # change on the host paths
         raise ValueError(
             f"ring_dtype={ring_dtype!r} requires engine='jit' (or the "
             "corridor engine); the host engines keep full-precision "
@@ -273,7 +270,7 @@ def run_simulation(
                      schedule=lambda v: schedule(v, ev.time))
         timeline.prune()
 
-    if engine in ("serial", "unbatched"):
+    if engine == "serial":
         with timers.phase("run"):
             while server.round < rounds and len(queue):
                 ev = queue.pop()

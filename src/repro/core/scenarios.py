@@ -82,7 +82,7 @@ class Scenario:
     selection_budget: Optional[float] = None
     selection_eps: float = 0.1
     resel_every: Optional[int] = None
-    # snapshot-ring dtype on the device engines' flat fast path (DESIGN.md
+    # snapshot-ring dtype on the device engines' packed layout (DESIGN.md
     # §12): "f32" = bitwise-exact (golden-pinned); "bf16" = half-memory
     # ring + upload buffers around f32 master weights — an explicit
     # opt-in, never a default precision change
@@ -174,10 +174,9 @@ register(Scenario(
 register(Scenario(
     name="fleet-k10000",
     description="Giga-fleet: 10000 vehicles under one RSU — the regime "
-                "the DRL-selection literature studies (PAPERS.md) and the "
-                "flat fast path unlocks: the bf16 snapshot ring + packed "
-                "upload buffers halve the ring memory that caps the f32 "
-                "pytree layout (DESIGN.md §12), and aggregation streams "
+                "the DRL-selection literature studies (PAPERS.md): the "
+                "bf16 snapshot ring + packed upload buffers halve the "
+                "ring memory (DESIGN.md §12), and aggregation streams "
                 "as fused ring_agg chains.",
     K=10000, rounds=60, l_iters=1, scale=0.0008, max_per_vehicle=64,
     n_train=4000, n_test=400, ring_dtype="bf16",
@@ -313,8 +312,8 @@ def build_world(sc: Scenario, seed: int = 0):
 def run_scenario(scenario: str | Scenario, *, seed: int = 0,
                  engine: Optional[str] = None, eval_every: int = 10,
                  progress=None, use_kernel: bool = False, mesh=None,
-                 record_cohorts: bool = False, flat: Optional[bool] = None,
-                 metrics=None, **overrides) -> SimResult:
+                 record_cohorts: bool = False, metrics=None,
+                 **overrides) -> SimResult:
     """Build the named world and run it; ``overrides`` replace Scenario
     fields (e.g. ``rounds=20`` for a shortened run, or
     ``ring_dtype="bf16"`` for the explicit half-memory ring opt-in).
@@ -324,12 +323,10 @@ def run_scenario(scenario: str | Scenario, *, seed: int = 0,
     for multi-RSU ones.  An explicit engine that cannot run the scenario's
     topology raises — the old behavior of silently substituting the serial
     handover loop for whatever was requested is gone.  ``mesh`` /
-    ``record_cohorts`` reach the corridor engine only.  ``flat`` selects
-    the device engines' packed-buffer fast path (DESIGN.md §12); ``None``
-    means the engine default (flat on).  ``metrics="on"`` enables the
-    telemetry channels (DESIGN.md §14) on every engine; the returned
-    ``result.report`` is stamped with the scenario name.  A scenario with
-    a ``faults`` profile (DESIGN.md §16) threads the resolved
+    ``record_cohorts`` reach the corridor engine only.  ``metrics="on"``
+    enables the telemetry channels (DESIGN.md §14) on every engine; the
+    returned ``result.report`` is stamped with the scenario name.  A
+    scenario with a ``faults`` profile (DESIGN.md §16) threads the resolved
     :class:`~repro.faults.spec.FaultSpec` into every engine
     (``engine='vmap'`` rejects fault worlds — the sweep tier has no
     per-world program structure)."""
@@ -337,13 +334,12 @@ def run_scenario(scenario: str | Scenario, *, seed: int = 0,
     if overrides:
         sc = dataclasses.replace(sc, **overrides)
     flt = scenario_faults(sc)
-    if sc.ring_dtype != "f32" and (engine not in (None, "jit", "corridor",
-                                                  "vmap")
-                                   or flat is False):
+    if sc.ring_dtype != "f32" and engine not in (None, "jit", "corridor",
+                                                 "vmap"):
         raise ValueError(
-            f"ring_dtype={sc.ring_dtype!r} needs the flat fast path of a "
-            "device engine (engine='jit' or the corridor engine); the "
-            "host engines and the pytree layout keep full precision")
+            f"ring_dtype={sc.ring_dtype!r} needs a device engine "
+            "(engine='jit' or the corridor engine); the host engines keep "
+            "full precision")
     if sc.n_rsus > 1:
         eng = engine or "corridor"
         if eng not in CORRIDOR_ENGINES:
@@ -352,8 +348,8 @@ def run_scenario(scenario: str | Scenario, *, seed: int = 0,
                 f"{sc.name!r} (n_rsus={sc.n_rsus}); corridor scenarios "
                 f"accept {CORRIDOR_ENGINES}")
     else:
-        # a non-f32 ring only exists on the jit engine's flat path, so it
-        # flips the single-RSU auto-selection from "batched" to "jit"
+        # a non-f32 ring only exists on the jit engine, so it flips the
+        # single-RSU auto-selection from "batched" to "jit"
         eng = engine or ("jit" if sc.ring_dtype != "f32" else "batched")
         if eng in CORRIDOR_ENGINES and eng not in ENGINES:
             raise ValueError(
@@ -372,10 +368,6 @@ def run_scenario(scenario: str | Scenario, *, seed: int = 0,
                 "engine='vmap' has no use_kernel/mesh/record_cohorts: "
                 "the sweep tier compiles the flat in-scan program only "
                 "(DESIGN.md §15) — run the world solo with engine='jit'")
-        if flat is False:
-            raise ValueError(
-                "engine='vmap' is flat-only: the world axis lives on the "
-                "packed [W, P] buffer (DESIGN.md §15)")
         from repro.core.sweep import run_simulation_vmap
         cb = None if progress is None else (
             lambda _w, rr, acc: progress(rr, acc))
@@ -404,17 +396,15 @@ def run_scenario(scenario: str | Scenario, *, seed: int = 0,
                 sc, veh, te_i, te_l, p, seed=seed, eval_every=eval_every,
                 use_kernel=use_kernel, mesh=mesh,
                 record_cohorts=record_cohorts, progress=progress,
-                flat=flat, metrics=metrics, faults=flt)
+                metrics=metrics, faults=flt)
     else:
-        kw = {} if flat is None else {"flat": flat}
         result = run_simulation(
             veh, te_i, te_l, scheme=sc.scheme,
             rounds=sc.rounds, l_iters=sc.l_iters, lr=sc.lr,
             params=p, seed=seed, eval_every=eval_every,
             use_kernel=use_kernel, engine=eng,
             progress=progress, selection=sc.selection_spec(),
-            ring_dtype=sc.ring_dtype, metrics=metrics, faults=flt,
-            **kw)
+            ring_dtype=sc.ring_dtype, metrics=metrics, faults=flt)
     with world.phase("world"):
         counts = veh[0].pool.world_counts(len(veh))
         # freeing K vehicles' shards takes milliseconds: inside the phase,

@@ -41,8 +41,8 @@ This engine batches W worlds through ONE compiled flat-path program:
   ``x**-0.5 -> rsqrt``) — tracing it would change every world's codegen.
   The engine therefore requires ``alpha`` uniform across the batch.
 
-Always the flat layout and the in-scan mix (the CPU-default form that
-reproduces the golden digests); no ``use_kernel``/``mesh``/``metrics`` —
+Always the in-scan mix (the CPU-default form that reproduces the golden
+digests); no ``use_kernel``/``mesh``/``metrics`` —
 those stay solo-tier features and are rejected loudly, never silently
 dropped.  The entry points are :class:`repro.core.scenarios.SweepSpec` /
 ``run_sweep`` (grids over a base scenario) and ``run_scenario(...,

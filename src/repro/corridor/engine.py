@@ -17,9 +17,10 @@ engine extends the mega-fleet layout (DESIGN.md §9) with an RSU axis:
   vehicle's download-time/staleness column and in-flight payload pointer)
   between RSU shards whenever the trajectory crosses a coverage boundary.
 
-- **Cohort stack, ``[R, ...]``.**  The R cohort models are one stacked
-  pytree; an arrival updates exactly one row (dynamic one-row scatter, or a
-  masked local-row update under the ``"rsu"``-sharded mesh path).
+- **Cohort stack, ``f32[R, P]``.**  The R cohort models are one packed
+  buffer (``core/flat.py``, DESIGN.md §12); an arrival updates exactly one
+  row, in the scan (the CPU default) or as per-RSU fused ``ring_agg``
+  chains between scans (``use_kernel`` / accelerator backends).
 
 - **Snapshot ring: one model per round, exactly.**  Each round re-schedules
   exactly one vehicle, whose next download reads exactly one cohort — the
@@ -40,15 +41,16 @@ engine extends the mega-fleet layout (DESIGN.md §9) with an RSU axis:
   reference schedules after reconciling), the boundary's ring row is
   overwritten with the reconciled row.
 
-- **Optional ``shard_map`` over the RSU axis.**  With a mesh that has an
-  ``"rsu"`` axis (R divisible by its size), the cohort stack is sharded
-  over it for the whole scan segment: the queue columns are replicated
-  (scalar bookkeeping, computed redundantly per device — zero traffic),
-  each arrival updates a cohort row on the owning shard only, and ring
-  rows leave the shards as one psum per segment.  Between reconciliations
-  the cohorts exchange exactly nothing; the reconcile itself is one pmean
-  per leaf — the corridor-scale instance of
-  ``hierarchical.cross_pod_reconcile``.
+- **Optional sharding over the RSU axis.**  With a mesh that has an
+  ``"rsu"`` axis (R divisible by its size), the cohort stack is placed
+  sharded over it along its row axis, at creation and after every
+  reconcile; the compiler partitions the rest of the program around that
+  placement.  The mesh is rebuilt over the same devices with Auto axes,
+  so ``jax.make_mesh`` meshes (Explicit axes) and ``Mesh(devices,
+  ("rsu",))`` meshes run the same program.  XLA cannot partition a
+  Mosaic kernel, so on several devices the Pallas calls (the ``ring_agg``
+  chains, the EMA reconcile under ``use_kernel``) run replicated inside a
+  ``shard_map``.
 
 Local training is wave-hoisted exactly as in the jit engine (same wave
 rule, same shared-payload broadcast fast path, optional ``"data"``-axis
@@ -68,7 +70,8 @@ import numpy as np
 from repro.channel import ChannelParams, CorridorMobility, slot_gain_table
 from repro.core import client as client_mod
 from repro.core.client import Samplers, VehicleData
-from repro.core.jit_engine import _mesh_key, _wave_train
+from repro.core.jit_engine import (_mesh_key, _replicated, _ring_agg,
+                                   _wave_train)
 from repro.core.server import DEFAULT_FEDASYNC_MIX, RoundRecord
 from repro.corridor.plan import CorridorPlan, plan_corridor
 from repro.models.cnn import init_cnn
@@ -99,19 +102,17 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
                    interpretation: str, use_kernel: bool, mesh,
                    reconcile_every: int, reconcile_mode: str,
                    reconcile_tau: float, eval_rounds: tuple,
-                   fedasync_mix: float, record_cohorts: bool,
-                   flat_layout=None, ring_dtype: str = "f32",
-                   metrics=None, l_iters: int = 1):
+                   fedasync_mix: float, record_cohorts: bool, layout,
+                   ring_dtype: str = "f32", metrics=None, l_iters: int = 1):
     """Trace-time constants live in the closure; cached per world structure
     like the jit engine's program.
 
-    ``flat_layout`` selects the packed flat-parameter fast path (DESIGN.md
-    §12): the cohort stack becomes one ``f32[R, P]`` buffer, ring rows are
-    single ``[P]`` vectors, and aggregation is either the in-scan
-    one-vector-op mix (CPU default — bitwise the pytree path on the golden
-    worlds) or fused per-RSU ``ring_agg`` chains (``use_kernel`` /
-    accelerator backends).  Unsharded only — the ``"rsu"``-mesh path keeps
-    the pytree layout."""
+    ``layout`` packs the cohort stack into one ``f32[R, P]`` buffer and
+    each ring row into one ``[P]`` vector (DESIGN.md §12); aggregation is
+    either the in-scan one-vector-op mix (CPU default) or fused per-RSU
+    ``ring_agg`` chains (``use_kernel`` / accelerator backends).  A mesh
+    with an ``"rsu"`` axis of size > 1 (Auto axes, see ``_stage_run``)
+    places the cohort stack sharded over it."""
     M = len(plan.veh)
     K = p.K
     R = plan.n_rsus
@@ -134,8 +135,16 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
     bw = jnp.float32(p.B)
     bits = jnp.float32(p.model_bits)
     n_slots = plan.n_slots
-    n_shards = _rsu_shards(mesh, R)
-    Rl = R // n_shards
+    if _rsu_shards(mesh, R) > 1:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        rows = NamedSharding(mesh, PartitionSpec(_RSU_AXIS))
+
+        def place(G):
+            return jax.lax.with_sharding_constraint(G, rows)
+    else:
+        def place(G):
+            return G
 
     # selection (DESIGN.md §11): same fold as the jit engine — a [M, K]
     # static mask table gates every re-schedule (parked slots are +inf in
@@ -191,24 +200,16 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
     if fct_on:
         fct_tab = jnp.asarray(flt_plan.counts_table(l_iters))
 
-    if n_shards > 1:
-        from jax.sharding import PartitionSpec as P
-
     def aggregate(g, loc, t, cu, cl, dl_t):
-        """One arrival's cohort update — identical math and f32 arithmetic
-        to the jit engine / host aggregation paths."""
+        """One arrival's in-scan cohort-row update — identical math and
+        f32 arithmetic to the jit engine / host aggregation paths."""
         if scheme == "mafl":
             weight = gamma ** (cu - 1.0) * zeta ** (cl - 1.0)   # Eqs. 7, 9
         else:
             weight = jnp.float32(1.0)
         if scheme == "mafl" and interpretation == "literal":
-            if use_kernel:
-                from repro.kernels.weighted_agg import ops as agg_ops
-                return agg_ops.weighted_agg_tree(g, loc, beta, weight), weight
-            new = jax.tree_util.tree_map(
-                lambda a, b: (beta * a.astype(jnp.float32) +
-                              (1.0 - beta) * weight *
-                              b.astype(jnp.float32)).astype(a.dtype), g, loc)
+            new = (beta * g.astype(jnp.float32) + (1.0 - beta) * weight *
+                   loc.astype(jnp.float32)).astype(g.dtype)
             return new, weight
         if scheme == "mafl":
             alpha = jnp.clip((1.0 - beta) * weight, 0.0, 1.0)
@@ -217,34 +218,25 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
         else:                                                   # fedasync
             stale = jnp.maximum(t - dl_t, 0.0)
             alpha = f_mix * (stale + 1.0) ** (-0.5)
-        if use_kernel:
-            from repro.kernels.weighted_agg import ops as agg_ops
-            return agg_ops.weighted_agg_tree(g, loc, 1.0 - alpha,
-                                             jnp.float32(1.0)), weight
-        new = jax.tree_util.tree_map(
-            lambda a, b: ((1.0 - alpha) * a.astype(jnp.float32) +
-                          alpha * b.astype(jnp.float32)).astype(a.dtype),
-            g, loc)
+        new = ((1.0 - alpha) * g.astype(jnp.float32) +
+               alpha * loc.astype(jnp.float32)).astype(g.dtype)
         return new, weight
 
     def stack_mean(G):
-        """Mean over the (local) cohort rows, f32 accumulate."""
-        return jax.tree_util.tree_map(
-            lambda x: jnp.mean(x.astype(jnp.float32), axis=0), G)
+        """Mean over the cohort rows, f32 accumulate."""
+        return jnp.mean(G.astype(jnp.float32), axis=0)
 
     def mix_rows(G, cons):
         """EMA of every row toward ``cons`` (tau=1 → adopt outright);
         ``cons`` arrives in f32 and is cast back to the row dtype."""
         if use_kernel and float(tau) != 1.0:
             from repro.kernels.weighted_agg import ops as agg_ops
-            return agg_ops.weighted_agg_tree(
-                G, jax.tree_util.tree_map(
-                    lambda x, c: jnp.broadcast_to(c.astype(x.dtype),
-                                                  x.shape), G, cons),
-                1.0 - tau, jnp.float32(1.0))
-        return jax.tree_util.tree_map(
-            lambda x, c: ((1.0 - tau) * x.astype(jnp.float32) +
-                          tau * c[None]).astype(x.dtype), G, cons)
+            return _replicated(
+                lambda G, C: agg_ops.weighted_agg_tree(
+                    G, C, 1.0 - tau, jnp.float32(1.0)), mesh)(
+                G, jnp.broadcast_to(cons.astype(G.dtype), G.shape))
+        return ((1.0 - tau) * G.astype(jnp.float32) +
+                tau * cons[None]).astype(G.dtype)
 
     def serving(x):
         j = jnp.floor((x + span / 2.0) / cell).astype(jnp.int32)
@@ -254,9 +246,7 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
         """Eq. 3-6 with the corridor geometry: slot gain -> span wrap ->
         serving-cell distance -> SNR -> Shannon rate -> upload delay.
         ``idx`` is a scalar pop or a vector of re-admissions; one
-        definition serves the pytree and flat bodies and both readmit
-        helpers — its op order is part of the flat-vs-pytree bitwise
-        pin, so it must never fork."""
+        definition serves the scan body and the re-admission helper."""
         slot = jnp.clip(t_up.astype(jnp.int32), 0, n_slots - 1)
         gain = gains[slot, idx]
         dx = x0[idx] + v_c * t_up                       # Eq. 3
@@ -267,455 +257,123 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
         rate = bw * jnp.log2(1.0 + snr)                 # Eq. 5
         return bits / jnp.maximum(rate, 1e-12)          # Eq. 6
 
-    def make_seg_body(locals_buf, gains, x0, qcl, off):
-        def wrap_x(i, t):
-            dx = x0[i] + v_c * t                                # Eq. 3
-            return jnp.mod(dx + span / 2.0, span) - span / 2.0
-
-        # fresh body per scan segment (the lax.scan traced-body cache
-        # pitfall, DESIGN.md §9) — and ``off`` is this shard's first RSU
-        # row (0 when unsharded)
-        def body(carry, r):
-            if met_on:
-                carry, mst = carry[:-1], carry[-1]
-            if with_state:
-                G, qt, qdl, qcu, rs, rc = carry
-            else:
-                G, qt, qdl, qcu = carry
-            flat = jnp.argmin(qt)                               # pop
-            j = flat // K
-            i = flat % K
-            t = qt[j, i]
-            cu, cl, dl_t = qcu[i], qcl[i], qdl[i]
-            if met_on:
-                # per-RSU live slots at pop time, before the slot
-                # migration writes (matches the f64 replay's pre-pop
-                # pending count)
-                occ = jnp.sum(jnp.isfinite(qt), axis=1).astype(jnp.int32)
-            loc = jax.tree_util.tree_map(lambda B: B[r], locals_buf)
-            owned = (j >= off) & (j < off + Rl)
-            row = jnp.where(owned, j - off, 0)
-            grow = jax.tree_util.tree_map(lambda Gl: Gl[row], G)
-            new_row, weight = aggregate(grow, loc, t, cu, cl, dl_t)
-            if has_cap:
-                # a cap-discarded pop keeps the cohort row exactly (the
-                # host skips the update outright); the ring contribution
-                # below inherits the unchanged row
-                new_row = jax.tree_util.tree_map(
-                    lambda old, new: jnp.where(keep_tab[r], new, old),
-                    grow, new_row)
-            G = jax.tree_util.tree_map(
-                lambda Gl, nr: Gl.at[row].set(
-                    jnp.where(owned, nr, Gl[row])), G, new_row)
-            # this shard's contribution to ring[r+1] (exactly one shard
-            # owns the row; psum'd once per segment under the mesh path)
-            contrib = jax.tree_util.tree_map(
-                lambda nr: jnp.where(owned, nr, jnp.zeros_like(nr)),
-                new_row)
-            if with_state:
-                # bandit reward = the paper's delay weight (Eqs. 7, 9)
-                rew = gamma ** (cu - 1.0) * zeta ** (cl - 1.0)
-                rs = rs.at[i].add(rew)
-                rc = rc.at[i].add(1.0)
-            # re-schedule vehicle i: download now, train C_l, upload C_u
-            t_up = t + cl
-            cu_new = eq36_upload_delay(gains, x0, i, t_up)
-            t_new = t_up + cu_new
-            j_new = serving(wrap_x(i, t_new))    # handover target
-            if adm_active:
-                # admission folded into the slot queue: a parked (or
-                # dropped / blacked-out) vehicle is +inf in every RSU
-                # row, invisible to the argmin
-                t_new = jnp.where(adm_tab[r, i], t_new, jnp.inf)
-            # slot migration: leave row j, land in row j_new
-            qt = qt.at[j, i].set(jnp.inf)
-            qt = qt.at[j_new, i].set(t_new)
-            qdl = qdl.at[i].set(t)
-            qcu = qcu.at[i].set(cu_new)
-            out = ((G, qt, qdl, qcu, rs, rc) if with_state
-                   else (G, qt, qdl, qcu))
-            ys = (i, j, t, cu, cl, dl_t, weight, contrib)
-            if met_on:
-                # handover = the admitted re-schedule lands on a new RSU
-                # (parked vehicles never migrate; readmits are counted by
-                # neither the device nor the f64 replay)
-                ho = (j_new != j)
-                if adm_active:
-                    ho = ho & adm_tab[r, i]
-                mst, gap = tel_dev.corridor_pop(
-                    mst, met_edges, t=t, dl_t=dl_t, j=j, handover=ho,
-                    fault_row=fct_tab[r] if fct_on else None)
-                out = out + (mst,)
-                ys = ys + (occ, gap, ho)
-            return out, ys
-        return body
-
-    def run_segment(st, locals_buf, gains, x0, qcl, a, b):
-        """Consume pops ``a..b-1``; ``st`` is the carried queue/cohort
-        state tuple; returns the updated tuple, the stacked ring rows for
-        those rounds, and the scalar trace columns."""
-        if n_shards == 1:
-            body = make_seg_body(locals_buf, gains, x0, qcl, 0)
-            with jax.named_scope(f"event_scan_{a}_{b}"):
-                carry, ys = jax.lax.scan(body, st, jnp.arange(a, b))
-            return carry, ys[7], ys[:7] + ys[8:]
-
-        def seg_fn(st, locals_buf, gains, x0, qcl):
-            off = jax.lax.axis_index(_RSU_AXIS) * Rl
-            body = make_seg_body(locals_buf, gains, x0, qcl, off)
-            with jax.named_scope(f"event_scan_{a}_{b}"):
-                carry, ys = jax.lax.scan(body, st, jnp.arange(a, b))
-            rows = jax.tree_util.tree_map(
-                lambda x: jax.lax.psum(x, _RSU_AXIS), ys[7])
-            return carry, rows, ys[:7] + ys[8:]
-
-        # cohort stack sharded over the RSU axis; queue columns (and the
-        # bandit accumulators, when carried) replicated
-        st_spec = (P(_RSU_AXIS),) + (P(),) * (len(st) - 1)
-        fn = jax.shard_map(
-            seg_fn, mesh=mesh,
-            in_specs=(st_spec, P(), P(), P(), P()),
-            out_specs=(st_spec, P(), P()),
-            check_vma=False)
-        return fn(st, locals_buf, gains, x0, qcl)
-
-    def reconcile(G):
-        """The cloud tier: FedAvg/EMA of the R cohorts; the only step that
-        touches more than one cohort (one pmean per leaf when sharded)."""
-        if n_shards == 1:
-            return mix_rows(G, stack_mean(G))
-
-        def rec_fn(G):
-            cons = jax.tree_util.tree_map(
-                lambda x: jax.lax.pmean(x, _RSU_AXIS), stack_mean(G))
-            return mix_rows(G, cons)
-
-        return jax.shard_map(rec_fn, mesh=mesh, in_specs=(P(_RSU_AXIS),),
-                             out_specs=P(_RSU_AXIS), check_vma=False)(G)
-
-    def consensus(G):
-        """Corridor-wide model (mean of cohorts) for eval/final params."""
-        if n_shards == 1:
-            return jax.tree_util.tree_map(
-                lambda x, g: x.astype(g.dtype), stack_mean(G),
-                jax.tree_util.tree_map(lambda g: g[0], G))
-
-        def cons_fn(G):
-            return jax.tree_util.tree_map(
-                lambda x: jax.lax.pmean(x, _RSU_AXIS), stack_mean(G))
-
-        cons = jax.shard_map(cons_fn, mesh=mesh, in_specs=(P(_RSU_AXIS),),
-                             out_specs=P(), check_vma=False)(G)
-        return jax.tree_util.tree_map(
-            lambda x, g: x.astype(g.dtype), cons,
-            jax.tree_util.tree_map(lambda g: g[0], G))
-
-    def cohort_row(G, j: int):
-        """Row ``j`` of the (possibly sharded) cohort stack, replicated."""
-        if n_shards == 1:
-            return jax.tree_util.tree_map(lambda x: x[j], G)
-
-        def pick(G):
-            mine = jax.lax.axis_index(_RSU_AXIS) == j // Rl
-            return jax.tree_util.tree_map(
-                lambda x: jax.lax.psum(
-                    jnp.where(mine, x[j % Rl], jnp.zeros_like(x[j % Rl])),
-                    _RSU_AXIS), G)
-
-        return jax.shard_map(pick, mesh=mesh, in_specs=(P(_RSU_AXIS),),
-                             out_specs=P(), check_vma=False)(G)
-
-    def gather_cohorts(G):
-        """Full [R, ...] stack on every device (cohort snapshots only)."""
-        if n_shards == 1:
-            return G
-
-        def allg(G):
-            return jax.tree_util.tree_map(
-                lambda x: jax.lax.all_gather(x, _RSU_AXIS, tiled=True), G)
-
-        return jax.shard_map(allg, mesh=mesh, in_specs=(P(_RSU_AXIS),),
-                             out_specs=P(), check_vma=False)(G)
-
     eval_set = set(eval_rounds)
     reconcile_set = {b for b in range(reconcile_every, M + 1,
                                       reconcile_every)}
 
-    if flat_layout is not None:
-        from repro.core.aggregation import chain_coeffs
-        from repro.core.jit_engine import _ring_interpret
-        from repro.corridor.plan import rsu_chain_groups
-        from repro.kernels.weighted_agg import ops as agg_ops
+    from repro.core.aggregation import chain_coeffs
+    from repro.corridor.plan import rsu_chain_groups
 
-        assert n_shards == 1, \
-            "flat fast path is unsharded (mesh 'rsu' axis keeps pytrees)"
-        layout = flat_layout
-        bf16 = ring_dtype == "bf16"
-        store_dtype = jnp.bfloat16 if bf16 else jnp.float32
-        store = ((lambda x: x.astype(jnp.bfloat16)) if bf16
-                 else (lambda x: x))
-        ring_interp = _ring_interpret(use_kernel)
-        fused_chain = use_kernel or jax.default_backend() != "cpu"
-        # ring rows later waves read (payload rounds); evals read the
-        # consensus, never the ring
-        needed = set()
-        for T, _s, _e in plan.waves:
-            needed |= {int(d[t]) + 1 for t in T if d[t] >= 0}
+    bf16 = ring_dtype == "bf16"
+    store_dtype = jnp.bfloat16 if bf16 else jnp.float32
+    store = ((lambda x: x.astype(jnp.bfloat16)) if bf16
+             else (lambda x: x))
+    ring_agg = _ring_agg(use_kernel, mesh)
+    fused_chain = use_kernel or jax.default_backend() != "cpu"
+    # ring rows later waves read (payload rounds); evals read the
+    # consensus, never the ring
+    needed = set()
+    for T, _s, _e in plan.waves:
+        needed |= {int(d[t]) + 1 for t in T if d[t] >= 0}
 
-        def program_flat(w0, gains, x0, qt, qdl, qcu, qcl, imgs, labs, lr):
-            local_scan = (client_mod._local_scan_partial if has_partial
-                          else client_mod._local_scan)
-            G = jnp.broadcast_to(layout.pack(w0)[None],
-                                 (R, layout.P)).astype(jnp.float32)
-            locals_buf = jnp.zeros((M, layout.P), store_dtype)
-            mst = ring_stats = None
-            store_row = store
-            if met_on:
-                mst = tel_dev.corridor_state(metrics)
-                if metrics.ring_guard and bf16:
-                    ring_stats = tel_dev.RingStats()
-                    store_row = ring_stats.wrap(store)
-            ring = [store_row(layout.pack(w0))] + [None] * M
-            cons_snaps, cohort_snaps, traces, met_traces = [], [], [], []
-            rs = rc = None
-            if with_state:
-                rs = jnp.zeros(K, jnp.float32)
-                rc = jnp.zeros(K, jnp.float32)
-
-            def make_flat_body(locals_buf):
-                # same pop / slot-migration / re-schedule arithmetic as
-                # the pytree body; in fused mode the cohort stack leaves
-                # the carry and aggregation streams per-RSU afterwards
-                # (fresh body per segment — locals_buf rebinds per wave)
-                def body(carry, r):
-                    if met_on:
-                        carry, mst = carry[:-1], carry[-1]
-                    if fused_chain:
-                        G = None
-                        if with_state:
-                            qt, qdl, qcu, rs, rc = carry
-                        else:
-                            qt, qdl, qcu = carry
-                    elif with_state:
-                        G, qt, qdl, qcu, rs, rc = carry
-                    else:
-                        G, qt, qdl, qcu = carry
-                    flat = jnp.argmin(qt)                       # pop
-                    j = flat // K
-                    i = flat % K
-                    t = qt[j, i]
-                    cu, cl, dl_t = qcu[i], qcl[i], qdl[i]
-                    if met_on:
-                        occ = jnp.sum(jnp.isfinite(qt),
-                                      axis=1).astype(jnp.int32)
-                    if fused_chain:
-                        if scheme == "mafl":
-                            weight = gamma ** (cu - 1.0) * zeta ** (cl - 1.0)
-                        else:
-                            weight = jnp.float32(1.0)
-                        new_row = None
-                    else:
-                        grow = G[j]
-                        new_row, weight = aggregate(grow, locals_buf[r], t,
-                                                    cu, cl, dl_t)
-                        if has_cap:
-                            # cap-discarded pop: the cohort row (and the
-                            # ring row reading it) stays exactly put
-                            new_row = jnp.where(keep_tab[r], new_row, grow)
-                        G = G.at[j].set(new_row)
-                    if with_state:
-                        rew = gamma ** (cu - 1.0) * zeta ** (cl - 1.0)
-                        rs = rs.at[i].add(rew)
-                        rc = rc.at[i].add(1.0)
-                    t_up = t + cl
-                    cu_new = eq36_upload_delay(gains, x0, i, t_up)
-                    t_new = t_up + cu_new
-                    x_new = jnp.mod(x0[i] + v_c * t_new + span / 2.0,
-                                    span) - span / 2.0
-                    j_new = serving(x_new)              # handover target
-                    if adm_active:
-                        t_new = jnp.where(adm_tab[r, i], t_new, jnp.inf)
-                    qt = qt.at[j, i].set(jnp.inf)
-                    qt = qt.at[j_new, i].set(t_new)
-                    qdl = qdl.at[i].set(t)
-                    qcu = qcu.at[i].set(cu_new)
-                    if fused_chain:
-                        out = ((qt, qdl, qcu, rs, rc) if with_state
-                               else (qt, qdl, qcu))
-                        ys = (i, j, t, cu, cl, dl_t, weight)
-                    else:
-                        out = ((G, qt, qdl, qcu, rs, rc) if with_state
-                               else (G, qt, qdl, qcu))
-                        ys = (i, j, t, cu, cl, dl_t, weight, new_row)
-                    if met_on:
-                        ho = (j_new != j)
-                        if adm_active:
-                            ho = ho & adm_tab[r, i]
-                        mst, gap = tel_dev.corridor_pop(
-                            mst, met_edges, t=t, dl_t=dl_t, j=j, handover=ho,
-                            fault_row=fct_tab[r] if fct_on else None)
-                        out = out + (mst,)
-                        ys = ys + (occ, gap, ho)
-                    return out, ys
-                return body
-
-            def readmit(qt, qdl, qcu, A, t_b):
-                A = jnp.asarray(A)
-                t_up = t_b + qcl[A]
-                cu_new = eq36_upload_delay(gains, x0, A, t_up)
-                t_new = t_up + cu_new
-                x_new = jnp.mod(x0[A] + v_c * t_new + span / 2.0,
-                                span) - span / 2.0
-                j_new = serving(x_new)
-                return (qt.at[j_new, A].set(t_new), qdl.at[A].set(t_b),
-                        qcu.at[A].set(cu_new))
-
-            for T, s, e in plan.waves:
-                T = np.asarray(T, np.int32)
-                if len(T):
-                    pay_rounds = [int(x) for x in d[T] + 1]
-                    shared = all(pr == pay_rounds[0] for pr in pay_rounds)
-                    if shared:
-                        pay = layout.unpack(ring[pay_rounds[0]])
-                    else:
-                        pay = layout.unpack(jnp.stack(
-                            [ring[pr] for pr in pay_rounds]))
-                    train = _wave_train(local_scan, mesh, len(T), shared,
-                                        partial=has_partial)
-                    extra = (ep_tab[jnp.asarray(T)],) if has_partial else ()
-                    with jax.named_scope(f"wave_train_{s}"):
-                        loc, _ = train(pay, imgs[T], labs[T], lr, *extra)
-                    locals_buf = locals_buf.at[jnp.asarray(T)].set(
-                        layout.pack(loc, dtype=store_dtype))
-                points = sorted({b for b in range(s + 1, e + 1)
-                                 if b in eval_set or b in reconcile_set
-                                 or b in readmit_at}
-                                | {e})
-                a = s
-                for b in points:
-                    if b > a:
-                        if fused_chain:
-                            st = ((qt, qdl, qcu, rs, rc) if with_state
-                                  else (qt, qdl, qcu))
-                        else:
-                            st = ((G, qt, qdl, qcu, rs, rc) if with_state
-                                  else (G, qt, qdl, qcu))
-                        if met_on:
-                            st = st + (mst,)
-                        with jax.named_scope(f"event_scan_{a}_{b}"):
-                            st, ys = jax.lax.scan(
-                                make_flat_body(locals_buf),
-                                st, jnp.arange(a, b))
-                        if met_on:
-                            st, mst = st[:-1], st[-1]
-                            met_traces.append(ys[-3:])
-                        if fused_chain:
-                            if with_state:
-                                qt, qdl, qcu, rs, rc = st
-                            else:
-                                qt, qdl, qcu = st
-                        elif with_state:
-                            G, qt, qdl, qcu, rs, rc = st
-                        else:
-                            G, qt, qdl, qcu = st
-                        traces.append(ys[:7])
-                        if fused_chain:
-                            # per-RSU streaming chains (DESIGN.md §12):
-                            # coefficients from the segment's own f32
-                            # trace, one ring_agg per checkpoint chunk
-                            cc, dd = chain_coeffs(
-                                scheme, interpretation, p.beta, ys[6],
-                                t=ys[2], dl_t=ys[5],
-                                fedasync_mix=fedasync_mix)
-                            if has_cap:
-                                # cap-discarded pops become exact no-ops
-                                keep_seg = keep_tab[a:b]
-                                cc = jnp.where(keep_seg, cc, 1.0)
-                                dd = jnp.where(keep_seg, dd, 0.0)
-                            coeffs = jnp.stack([cc, dd], axis=1)
-                            # beside the segment's event_scan scope, not
-                            # inside it: a trace keys an op by its first
-                            # scope
-                            with jax.named_scope(f"ring_chain_{a}_{b}"):
-                                for jr, chunks in rsu_chain_groups(
-                                        plan, a, b, needed):
-                                    g_j = G[jr]
-                                    for chunk in chunks:
-                                        idx = np.asarray(chunk)
-                                        g_j = agg_ops.ring_agg(
-                                            g_j,
-                                            locals_buf[jnp.asarray(idx)],
-                                            coeffs[jnp.asarray(idx - a)],
-                                            interpret=ring_interp)
-                                        last = chunk[-1] + 1
-                                        if last in needed:
-                                            ring[last] = store_row(g_j)
-                                    G = G.at[jr].set(g_j)
-                        else:
-                            rows = ys[7]
-                            for r in range(a, b):
-                                ring[r + 1] = store_row(rows[r - a])
-                    if b in reconcile_set:
-                        G = mix_rows(G, stack_mean(G))
-                        ring[b] = store_row(G[int(up_rsu[b - 1])])
-                    if b in readmit_at:
-                        qt, qdl, qcu = readmit(qt, qdl, qcu, readmit_at[b],
-                                               traces[-1][2][-1])
-                    if b in eval_set:
-                        cons_snaps.append(layout.unpack(
-                            jnp.mean(G, axis=0)))
-                        if record_cohorts:
-                            cohort_snaps.append(layout.unpack(G))
-                    a = b
-
-            trace = tuple(jnp.concatenate([tr[k] for tr in traces])
-                          for k in range(7))
-            ret = (layout.unpack(G), cons_snaps, cohort_snaps, trace)
-            if with_state:
-                ret = ret + ((rs, rc),)
-            if met_on:
-                met_out = {
-                    "stale_hist": mst[0],
-                    "handover_count": mst[2],
-                    "occupancy": jnp.concatenate(
-                        [m[0] for m in met_traces]),
-                    "gap": jnp.concatenate([m[1] for m in met_traces]),
-                    "handover": jnp.concatenate(
-                        [m[2] for m in met_traces]),
-                }
-                if fct_on:
-                    met_out["fault_counts"] = mst[3]
-                if ring_stats is not None:
-                    met_out.update(ring_stats.out())
-                ret = ret + (met_out,)
-            return ret
-
-        return jax.jit(program_flat)
-
-    def program(w0, gains, x0, qt, qdl, qcu, qcl, imgs, labs, lr):
+    def program_flat(w0, gains, x0, qt, qdl, qcu, qcl, imgs, labs, lr):
         local_scan = (client_mod._local_scan_partial if has_partial
                       else client_mod._local_scan)
-        G = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x[None], (R,) + x.shape), w0)
-        if n_shards > 1:
-            G = jax.lax.with_sharding_constraint(
-                G, jax.sharding.NamedSharding(mesh, P(_RSU_AXIS)))
-        locals_buf = jax.tree_util.tree_map(
-            lambda x: jnp.zeros((M,) + x.shape, x.dtype), w0)
-        ring = [w0] + [None] * M       # one model per round (see header)
-        cons_snaps, cohort_snaps, traces = [], [], []
-        mst = tel_dev.corridor_state(metrics) if met_on else None
+        G = place(jnp.broadcast_to(layout.pack(w0)[None],
+                                   (R, layout.P)).astype(jnp.float32))
+        locals_buf = jnp.zeros((M, layout.P), store_dtype)
+        mst = ring_stats = None
+        store_row = store
+        if met_on:
+            mst = tel_dev.corridor_state(metrics)
+            if metrics.ring_guard and bf16:
+                ring_stats = tel_dev.RingStats()
+                store_row = ring_stats.wrap(store)
+        ring = [store_row(layout.pack(w0))] + [None] * M
+        cons_snaps, cohort_snaps, traces, met_traces = [], [], [], []
         rs = rc = None
         if with_state:
             rs = jnp.zeros(K, jnp.float32)
             rc = jnp.zeros(K, jnp.float32)
 
+        def make_flat_body(locals_buf):
+            # pop, slot migration and re-schedule; in fused mode the
+            # cohort stack leaves the carry and aggregation streams
+            # per-RSU afterwards (fresh body per segment — locals_buf
+            # rebinds per wave, DESIGN.md §9)
+            def body(carry, r):
+                if met_on:
+                    carry, mst = carry[:-1], carry[-1]
+                if fused_chain:
+                    G = None
+                    if with_state:
+                        qt, qdl, qcu, rs, rc = carry
+                    else:
+                        qt, qdl, qcu = carry
+                elif with_state:
+                    G, qt, qdl, qcu, rs, rc = carry
+                else:
+                    G, qt, qdl, qcu = carry
+                ji = jnp.argmin(qt)                         # pop
+                j = ji // K
+                i = ji % K
+                t = qt[j, i]
+                cu, cl, dl_t = qcu[i], qcl[i], qdl[i]
+                if met_on:
+                    occ = jnp.sum(jnp.isfinite(qt),
+                                  axis=1).astype(jnp.int32)
+                if fused_chain:
+                    if scheme == "mafl":
+                        weight = gamma ** (cu - 1.0) * zeta ** (cl - 1.0)
+                    else:
+                        weight = jnp.float32(1.0)
+                    new_row = None
+                else:
+                    grow = G[j]
+                    new_row, weight = aggregate(grow, locals_buf[r], t,
+                                                cu, cl, dl_t)
+                    if has_cap:
+                        # cap-discarded pop: the cohort row (and the
+                        # ring row reading it) stays exactly put
+                        new_row = jnp.where(keep_tab[r], new_row, grow)
+                    G = G.at[j].set(new_row)
+                if with_state:
+                    rew = gamma ** (cu - 1.0) * zeta ** (cl - 1.0)
+                    rs = rs.at[i].add(rew)
+                    rc = rc.at[i].add(1.0)
+                t_up = t + cl
+                cu_new = eq36_upload_delay(gains, x0, i, t_up)
+                t_new = t_up + cu_new
+                x_new = jnp.mod(x0[i] + v_c * t_new + span / 2.0,
+                                span) - span / 2.0
+                j_new = serving(x_new)              # handover target
+                if adm_active:
+                    t_new = jnp.where(adm_tab[r, i], t_new, jnp.inf)
+                qt = qt.at[j, i].set(jnp.inf)
+                qt = qt.at[j_new, i].set(t_new)
+                qdl = qdl.at[i].set(t)
+                qcu = qcu.at[i].set(cu_new)
+                if fused_chain:
+                    out = ((qt, qdl, qcu, rs, rc) if with_state
+                           else (qt, qdl, qcu))
+                    ys = (i, j, t, cu, cl, dl_t, weight)
+                else:
+                    out = ((G, qt, qdl, qcu, rs, rc) if with_state
+                           else (G, qt, qdl, qcu))
+                    ys = (i, j, t, cu, cl, dl_t, weight, new_row)
+                if met_on:
+                    ho = (j_new != j)
+                    if adm_active:
+                        ho = ho & adm_tab[r, i]
+                    mst, gap = tel_dev.corridor_pop(
+                        mst, met_edges, t=t, dl_t=dl_t, j=j, handover=ho,
+                        fault_row=fct_tab[r] if fct_on else None)
+                    out = out + (mst,)
+                    ys = ys + (occ, gap, ho)
+                return out, ys
+            return body
+
         def readmit(qt, qdl, qcu, A, t_b):
-            """Boundary re-admission (post-reconcile): schedule vehicles
-            ``A`` (static) at the traced boundary timestamp — the same
-            Eq. 3-6 pipeline as the in-scan re-schedule, with the slot
-            landing in the row of the RSU serving each vehicle at its new
-            arrival time."""
             A = jnp.asarray(A)
             t_up = t_b + qcl[A]
             cu_new = eq36_upload_delay(gains, x0, A, t_up)
@@ -732,22 +390,17 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
                 pay_rounds = [int(x) for x in d[T] + 1]
                 shared = all(pr == pay_rounds[0] for pr in pay_rounds)
                 if shared:
-                    pay = ring[pay_rounds[0]]
+                    pay = layout.unpack(ring[pay_rounds[0]])
                 else:
-                    pay = jax.tree_util.tree_map(
-                        lambda *xs: jnp.stack(xs),
-                        *[ring[pr] for pr in pay_rounds])
+                    pay = layout.unpack(jnp.stack(
+                        [ring[pr] for pr in pay_rounds]))
                 train = _wave_train(local_scan, mesh, len(T), shared,
                                     partial=has_partial)
                 extra = (ep_tab[jnp.asarray(T)],) if has_partial else ()
                 with jax.named_scope(f"wave_train_{s}"):
                     loc, _ = train(pay, imgs[T], labs[T], lr, *extra)
-                T_dev = jnp.asarray(T)
-                locals_buf = jax.tree_util.tree_map(
-                    lambda B, L: B.at[T_dev].set(L), locals_buf, loc)
-            # sub-split [s, e) at reconcile/eval boundaries, which are
-            # static — the reconcile and the consensus snapshot run at
-            # trace level *between* scans (no collective under lax.cond)
+                locals_buf = locals_buf.at[jnp.asarray(T)].set(
+                    layout.pack(loc, dtype=store_dtype))
             points = sorted({b for b in range(s + 1, e + 1)
                              if b in eval_set or b in reconcile_set
                              or b in readmit_at}
@@ -755,60 +408,106 @@ def _build_program(plan: CorridorPlan, p: ChannelParams, *, scheme: str,
             a = s
             for b in points:
                 if b > a:
-                    st = ((G, qt, qdl, qcu, rs, rc) if with_state
-                          else (G, qt, qdl, qcu))
+                    if fused_chain:
+                        st = ((qt, qdl, qcu, rs, rc) if with_state
+                              else (qt, qdl, qcu))
+                    else:
+                        st = ((G, qt, qdl, qcu, rs, rc) if with_state
+                              else (G, qt, qdl, qcu))
                     if met_on:
                         st = st + (mst,)
-                    st, rows, ys = run_segment(
-                        st, locals_buf, gains, x0, qcl, a, b)
+                    with jax.named_scope(f"event_scan_{a}_{b}"):
+                        st, ys = jax.lax.scan(
+                            make_flat_body(locals_buf),
+                            st, jnp.arange(a, b))
                     if met_on:
                         st, mst = st[:-1], st[-1]
-                    if with_state:
+                        met_traces.append(ys[-3:])
+                    if fused_chain:
+                        if with_state:
+                            qt, qdl, qcu, rs, rc = st
+                        else:
+                            qt, qdl, qcu = st
+                    elif with_state:
                         G, qt, qdl, qcu, rs, rc = st
                     else:
                         G, qt, qdl, qcu = st
-                    traces.append(ys)
-                    for r in range(a, b):
-                        ring[r + 1] = jax.tree_util.tree_map(
-                            lambda x, i=r - a: x[i], rows)
+                    traces.append(ys[:7])
+                    if fused_chain:
+                        # per-RSU streaming chains (DESIGN.md §12):
+                        # coefficients from the segment's own f32
+                        # trace, one ring_agg per checkpoint chunk
+                        cc, dd = chain_coeffs(
+                            scheme, interpretation, p.beta, ys[6],
+                            t=ys[2], dl_t=ys[5],
+                            fedasync_mix=fedasync_mix)
+                        if has_cap:
+                            # cap-discarded pops become exact no-ops
+                            keep_seg = keep_tab[a:b]
+                            cc = jnp.where(keep_seg, cc, 1.0)
+                            dd = jnp.where(keep_seg, dd, 0.0)
+                        coeffs = jnp.stack([cc, dd], axis=1)
+                        # beside the segment's event_scan scope, not
+                        # inside it: a trace keys an op by its first
+                        # scope
+                        with jax.named_scope(f"ring_chain_{a}_{b}"):
+                            for jr, chunks in rsu_chain_groups(
+                                    plan, a, b, needed):
+                                g_j = G[jr]
+                                for chunk in chunks:
+                                    idx = np.asarray(chunk)
+                                    g_j = ring_agg(
+                                        g_j,
+                                        locals_buf[jnp.asarray(idx)],
+                                        coeffs[jnp.asarray(idx - a)])
+                                    last = chunk[-1] + 1
+                                    if last in needed:
+                                        ring[last] = store_row(g_j)
+                                G = G.at[jr].set(g_j)
+                    else:
+                        rows = ys[7]
+                        for r in range(a, b):
+                            ring[r + 1] = store_row(rows[r - a])
                 if b in reconcile_set:
-                    G = reconcile(G)
-                    # the boundary round's re-download happens *after* the
-                    # reconcile (serial reference order) — its ring row is
-                    # the reconciled cohort the upload landed on
-                    ring[b] = cohort_row(G, int(up_rsu[b - 1]))
+                    G = place(mix_rows(G, stack_mean(G)))
+                    # the boundary round's re-download happens *after*
+                    # the reconcile (serial reference order): its ring
+                    # row is the reconciled cohort the upload landed on
+                    ring[b] = store_row(G[int(up_rsu[b - 1])])
                 if b in readmit_at:
-                    # the boundary re-scored the fleet (fedavg-only, so
-                    # every re-admitted download reads the reconciled
-                    # ring[b] regardless of serving RSU); t_b = the
-                    # boundary pop's timestamp
+                    # t_b = the boundary pop's timestamp
                     qt, qdl, qcu = readmit(qt, qdl, qcu, readmit_at[b],
                                            traces[-1][2][-1])
                 if b in eval_set:
-                    cons_snaps.append(consensus(G))
+                    cons_snaps.append(layout.unpack(
+                        jnp.mean(G, axis=0)))
                     if record_cohorts:
-                        cohort_snaps.append(gather_cohorts(G))
+                        cohort_snaps.append(layout.unpack(G))
                 a = b
 
         trace = tuple(jnp.concatenate([tr[k] for tr in traces])
                       for k in range(7))
-        ret = (gather_cohorts(G), cons_snaps, cohort_snaps, trace)
+        ret = (layout.unpack(G), cons_snaps, cohort_snaps, trace)
         if with_state:
             ret = ret + ((rs, rc),)
         if met_on:
             met_out = {
                 "stale_hist": mst[0],
                 "handover_count": mst[2],
-                "occupancy": jnp.concatenate([tr[7] for tr in traces]),
-                "gap": jnp.concatenate([tr[8] for tr in traces]),
-                "handover": jnp.concatenate([tr[9] for tr in traces]),
+                "occupancy": jnp.concatenate(
+                    [m[0] for m in met_traces]),
+                "gap": jnp.concatenate([m[1] for m in met_traces]),
+                "handover": jnp.concatenate(
+                    [m[2] for m in met_traces]),
             }
             if fct_on:
                 met_out["fault_counts"] = mst[3]
+            if ring_stats is not None:
+                met_out.update(ring_stats.out())
             ret = ret + (met_out,)
         return ret
 
-    return jax.jit(program)
+    return jax.jit(program_flat)
 
 
 # ---------------------------------------------------------------------------
@@ -831,7 +530,6 @@ def run_corridor_simulation(
     record_cohorts: bool = False,
     init_params=None,
     selection=None,
-    flat: Optional[bool] = None,
     metrics=None,
     faults=None,
 ):
@@ -839,11 +537,10 @@ def run_corridor_simulation(
     same ``SimResult`` the serial reference produces (same record fields,
     same eval cadence, per-RSU round numbering, ``rec.rsu`` set).
 
-    ``flat=None`` auto-selects the packed flat-parameter fast path
-    (DESIGN.md §12) whenever the run is unsharded; an ``"rsu"``-sharded
-    mesh keeps the pytree layout (explicitly requesting both raises).
-    ``sc.ring_dtype="bf16"`` (flat only) stores ring rows and upload
-    buffers in bf16 around the f32 cohort stack.
+    ``mesh`` with an ``"rsu"`` axis shards the packed cohort stack over
+    it (DESIGN.md §10); a ``"data"`` axis shards the training waves.
+    ``sc.ring_dtype="bf16"`` stores ring rows and upload buffers in bf16
+    around the f32 cohort stack.
 
     ``result.extras`` carries the corridor-specific outputs: the per-round
     serving-RSU trace, the final cohort stack, and (``record_cohorts=True``)
@@ -874,14 +571,13 @@ def run_corridor_simulation(
         sc, vehicles_data, p, seed=seed, eval_every=eval_every,
         interpretation=interpretation, use_kernel=use_kernel,
         batch_size=batch_size, mesh=mesh, record_cohorts=record_cohorts,
-        init_params=init_params, selection=selection, flat=flat,
-        metrics=metrics, faults=faults, timers=timers)
+        init_params=init_params, selection=selection, metrics=metrics,
+        faults=faults, timers=timers)
     p = p if p is not None else sc.channel()
     scheme = sc.scheme
     R = sc.n_rsus
     M = sc.rounds
     ring_dtype = getattr(sc, "ring_dtype", "f32")
-    flat = layout is not None
     with timers.phase("run"):
         out = jax.block_until_ready(prog(*args))
     with timers.phase("guard"):
@@ -933,7 +629,7 @@ def run_corridor_simulation(
                     "corridor engine: device bandit reward accumulators "
                     "diverged from the host selection replay")
 
-        if flat and ring_dtype == "bf16":
+        if ring_dtype == "bf16":
             # bf16 divergence guard (DESIGN.md §12): the trace guards above
             # keep the timeline exact; a non-finite cohort stack means the
             # quantized ring diverged — fail loudly
@@ -1018,7 +714,7 @@ def run_corridor_simulation(
 
 def _stage_run(sc, vehicles_data, p=None, *, seed, eval_every,
                interpretation, use_kernel, batch_size, mesh, record_cohorts,
-               init_params, selection, flat, metrics=None, faults=None,
+               init_params, selection, metrics=None, faults=None,
                timers=None):
     """Validate, plan, and stage one corridor run — everything up to (but
     not including) executing the compiled program.  Split out of
@@ -1058,18 +754,13 @@ def _stage_run(sc, vehicles_data, p=None, *, seed, eval_every,
     if ring_dtype not in ("f32", "bf16"):
         raise ValueError(f"unknown ring_dtype {ring_dtype!r}; "
                          "expected 'f32' or 'bf16'")
-    sharded = _rsu_shards(mesh, R) > 1
-    if flat is None:
-        flat = not sharded
-    elif flat and sharded:
-        raise ValueError(
-            "flat fast path does not run under an 'rsu'-sharded mesh — "
-            "the sharded cohort stack keeps the pytree layout (pass "
-            "flat=False or drop the mesh)")
-    if ring_dtype == "bf16" and not flat:
-        raise ValueError("ring_dtype='bf16' requires the flat fast path "
-                         "(unsharded corridor): only the packed ring "
-                         "stores bf16 snapshots around the f32 stack")
+    if _rsu_shards(mesh, R) > 1:
+        # the cohort stack's placement is a constraint the compiler
+        # propagates, which needs Auto axes; jax.make_mesh builds
+        # Explicit ones
+        from jax.sharding import AxisType, Mesh
+        mesh = Mesh(mesh.devices, mesh.axis_names,
+                    axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
     with timers.phase("plan"):
         plan = plan_corridor(p, R, seed, rounds, entry=entry,
@@ -1114,7 +805,7 @@ def _stage_run(sc, vehicles_data, p=None, *, seed, eval_every,
         qcl = jnp.asarray(plan.q0["train_delay"], jnp.float32)
 
         from repro.core.flat import ParamLayout
-        layout = ParamLayout.from_tree(w0) if flat else None
+        layout = ParamLayout.from_tree(w0)
         shapes = (imgs.shape, tuple(
             (str(path), v.shape, str(v.dtype))
             for path, v in jax.tree_util.tree_leaves_with_path(w0)))
@@ -1126,8 +817,7 @@ def _stage_run(sc, vehicles_data, p=None, *, seed, eval_every,
                      _mesh_key(mesh), shapes,
                      None if plan.sel is None else plan.sel.signature(),
                      client_mod._local_scan,
-                     None if layout is None else layout.signature(),
-                     ring_dtype,
+                     layout.signature(), ring_dtype,
                      None if met is None else met.signature(),
                      None if plan.flt is None else
                      (plan.flt.signature(), sc.l_iters,
@@ -1140,7 +830,7 @@ def _stage_run(sc, vehicles_data, p=None, *, seed, eval_every,
                 reconcile_every=sc.reconcile_every, reconcile_mode=mode,
                 reconcile_tau=float(getattr(sc, "reconcile_tau", 0.5)),
                 eval_rounds=eval_rounds, fedasync_mix=DEFAULT_FEDASYNC_MIX,
-                record_cohorts=record_cohorts, flat_layout=layout,
+                record_cohorts=record_cohorts, layout=layout,
                 ring_dtype=ring_dtype, metrics=met, l_iters=sc.l_iters)
             _PROGRAM_CACHE[cache_key] = prog
             while len(_PROGRAM_CACHE) > _PROGRAM_CACHE_SIZE:

@@ -208,7 +208,7 @@ def plan_corridor(p: ChannelParams, n_rsus: int, seed: int, rounds: int,
 def rsu_chain_groups(plan: CorridorPlan, s: int, e: int,
                      needed) -> list:
     """Static per-RSU upload chains for scan segment ``[s, e)`` — the flat
-    fast path's fused-aggregation plan (DESIGN.md §12).
+    program's fused-aggregation plan (DESIGN.md §12).
 
     Within a segment the uploads landing on RSU ``j`` form one sequential
     mix chain on cohort row ``j`` (uploads to other RSUs never touch it),

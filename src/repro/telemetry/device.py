@@ -76,7 +76,7 @@ def corridor_pop(mst, edges, *, t, dl_t, j, handover, fault_row=None):
 class RingStats:
     """Trace-level bf16 snapshot-ring guard counters (DESIGN.md §12/§14).
 
-    Wraps the flat fast path's ``store`` closure: every checkpoint row
+    Wraps the device program's ``store`` closure: every checkpoint row
     stored to the ring is scanned for non-finite values (bf16 overflow
     saturates to inf) and folded into running counters.  All ``store``
     call sites execute at trace level (between scan segments), so plain

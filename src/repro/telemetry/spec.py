@@ -35,7 +35,7 @@ class MetricsSpec:
     so the device and the f64 replay bucket against bit-identical
     constants.  ``n_rsus`` sizes the per-RSU axes of the corridor
     channels; ``ring_guard`` arms the bf16 snapshot-ring finiteness /
-    overflow counters on the flat fast path."""
+    overflow counters."""
     enabled: bool = True
     edges: tuple = ()
     n_rsus: int = 1

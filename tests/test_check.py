@@ -120,9 +120,11 @@ def test_seeded_injection_into_real_engine_is_caught():
     src = (REPO / "src/repro/core/jit_engine.py").read_text()
     anchor = "i = jnp.argmin(qt)                          # pop"
     assert anchor in src
+    line = next(l for l in src.splitlines() if anchor in l)
+    indent = line[:len(line) - len(line.lstrip())]
     inject = (anchor
-              + "\n                    bad64 = qt.astype(jnp.float64)"
-              + "\n                    badhost = qt[0].item()")
+              + f"\n{indent}bad64 = qt.astype(jnp.float64)"
+              + f"\n{indent}badhost = qt[0].item()")
     findings = check_source("src/repro/core/jit_engine.py",
                             src.replace(anchor, inject, 1))
     rules = {f.rule for f in findings if not f.waived}

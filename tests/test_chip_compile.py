@@ -101,7 +101,7 @@ def test_fleet_k10000_program_compiles_for_v5e(one_chip, no_compile_cache,
         veh, scheme=sc.scheme, rounds=sc.rounds, l_iters=sc.l_iters,
         lr=sc.lr, params=p, seed=0, eval_every=10, use_kernel=False,
         init_params=None, interpretation="mixing", batch_size=128,
-        mesh=None, selection=None, flat=True, ring_dtype=sc.ring_dtype)
+        mesh=None, selection=None, ring_dtype=sc.ring_dtype)
     assert layout.P == P_CNN
     _assert_compiles_for_one_chip(prog, args, one_chip)
 
@@ -120,6 +120,54 @@ def test_corridor_r8_k4000_program_compiles_for_v5e(one_chip,
     prog, args, _, layout, *_ = corridor_engine._stage_run(
         sc, veh, p, seed=0, eval_every=10, interpretation="mixing",
         use_kernel=False, batch_size=128, mesh=None, record_cohorts=False,
-        init_params=None, selection=None, flat=True)
+        init_params=None, selection=None)
     assert layout.P == P_CNN
     _assert_compiles_for_one_chip(prog, args, one_chip)
+
+
+@pytest.mark.parametrize("engine,axis", [("jit", "data"),
+                                         ("corridor", "rsu")])
+def test_program_over_four_chips_compiles_for_v5e(topo, no_compile_cache,
+                                                  monkeypatch, engine, axis):
+    """The main path over a four-chip mesh: the fleet's training waves over
+    ``"data"``, the corridor's cohort stack over ``"rsu"``.  XLA cannot
+    partition a Mosaic kernel, so under a mesh the ``ring_agg`` chains
+    must run inside a ``shard_map``; interpret mode on the CPU cannot show
+    that, a compile for the TPU does."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    import repro.core.jit_engine as jit_engine
+    import repro.corridor.engine as corridor_engine
+    from repro.core.scenarios import build_world, get_scenario
+
+    mesh = Mesh(np.asarray(topo.devices[:4]), (axis,))
+    if engine == "jit":
+        _as_tpu_program(jit_engine, monkeypatch)
+        sc = get_scenario("fleet-k10000")
+        veh, _, _, p = build_world(sc)
+        prog, args, *_ = jit_engine._stage_run(
+            veh, scheme=sc.scheme, rounds=sc.rounds, l_iters=sc.l_iters,
+            lr=sc.lr, params=p, seed=0, eval_every=10, use_kernel=False,
+            init_params=None, interpretation="mixing", batch_size=128,
+            mesh=mesh, selection=None, ring_dtype=sc.ring_dtype)
+    else:
+        _as_tpu_program(corridor_engine, monkeypatch)
+        sc = get_scenario("corridor-r8-k4000")
+        veh, _, _, p = build_world(sc)
+        prog, args, *_ = corridor_engine._stage_run(
+            sc, veh, p, seed=0, eval_every=10, interpretation="mixing",
+            use_kernel=False, batch_size=128, mesh=mesh,
+            record_cohorts=False, init_params=None, selection=None)
+    replicated = NamedSharding(mesh, PartitionSpec())
+    compiled = prog.lower(*jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=replicated),
+        args)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 1
+    if axis == "rsu":
+        assert "all-reduce" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < V5E_HBM_BYTES

@@ -114,7 +114,7 @@ def test_single_rsu_scenario_rejects_corridor_engine():
 
 
 def test_corridor_scenario_rejects_single_rsu_engines():
-    for eng in ("batched", "jit", "unbatched"):
+    for eng in ("batched", "jit"):
         with pytest.raises(ValueError, match="cannot run multi-RSU"):
             run_scenario("corridor-quick-r2-k8", engine=eng, rounds=2)
     with pytest.raises(ValueError, match="unknown engine"):
@@ -205,13 +205,19 @@ def test_corridor_engine_use_kernel_matches_plain():
 # ---------------------------------------------------------------------------
 # RSU-sharded mesh path (forced host devices, isolated subprocess)
 # ---------------------------------------------------------------------------
-@pytest.mark.slow
-def test_corridor_rsu_sharded_matches_unsharded():
+@pytest.mark.parametrize("mesh_form", [
+    "jax.make_mesh((2,), ('rsu',))",
+    "Mesh(np.asarray(jax.devices()[:2]), ('rsu',))"])
+def test_corridor_rsu_sharded_matches_unsharded(mesh_form):
+    """The cohort stack sharded over an "rsu" mesh, from either mesh
+    constructor: Explicit axes (``jax.make_mesh``) and Auto axes
+    (``Mesh``) run the same program as the unsharded corridor."""
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         import jax
         import numpy as np
+        from jax.sharding import Mesh
         from repro.core.scenarios import build_world, get_scenario
         from repro.corridor.engine import run_corridor_simulation
         import dataclasses
@@ -221,7 +227,7 @@ def test_corridor_rsu_sharded_matches_unsharded():
         veh, te_i, te_l, p = build_world(sc, seed=0)
         kw = dict(seed=0, eval_every=3)
         r0 = run_corridor_simulation(sc, veh, te_i, te_l, p, **kw)
-        mesh = jax.make_mesh((2,), ("rsu",))
+        mesh = MESH_FORM
         r1 = run_corridor_simulation(sc, veh, te_i, te_l, p, mesh=mesh,
                                      **kw)
         assert ([(x.round, x.vehicle, x.rsu) for x in r0.rounds]
@@ -231,7 +237,7 @@ def test_corridor_rsu_sharded_matches_unsharded():
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-5)
         print("CORRIDOR_MESH_OK")
-    """)
+    """).replace("MESH_FORM", mesh_form)
     from test_hierarchical import SUBPROC_ENV
     res = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=600, env=SUBPROC_ENV)

@@ -72,8 +72,8 @@ def _run(world, engine, rounds, l_iters=2, scheme="mafl", **kw):
                           seed=0, params=p, engine=engine, **kw)
 
 
-def _assert_conformant(results: dict, param_atol=1e-5):
-    ref = results["serial"]
+def _assert_conformant(results: dict, param_atol=1e-5, ref="serial"):
+    ref = results[ref]
     ref_seq = [(r.round, r.vehicle) for r in ref.rounds]
     ref_t = np.array([r.time for r in ref.rounds])
     ref_w = np.array([r.weight for r in ref.rounds])

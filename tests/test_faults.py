@@ -83,7 +83,7 @@ def test_faults_off_cache_identity_jit():
     kw = dict(scheme=sc.scheme, rounds=6, l_iters=1, lr=sc.lr, params=p,
               seed=0, eval_every=3, use_kernel=False, init_params=None,
               interpretation="mixing", batch_size=32, mesh=None,
-              selection=None, flat=True, ring_dtype="f32")
+              selection=None, ring_dtype="f32")
     base, *_ = _stage_run(veh, faults=None, **kw)
     off, *_ = _stage_run(veh, faults="off", **kw)
     noop, *_ = _stage_run(veh, faults=FaultSpec(), **kw)
@@ -99,8 +99,7 @@ def test_faults_off_cache_identity_corridor():
     from repro.corridor.engine import _stage_run
     kw = dict(seed=0, eval_every=4, interpretation="mixing",
               use_kernel=False, batch_size=32, mesh=None,
-              record_cohorts=False, init_params=None, selection=None,
-              flat=True)
+              record_cohorts=False, init_params=None, selection=None)
     base, *_ = _stage_run(sc, veh, p, faults=None, **kw)
     off, *_ = _stage_run(sc, veh, p, faults="off", **kw)
     live, *_ = _stage_run(sc, veh, p, faults=HEAVY, **kw)

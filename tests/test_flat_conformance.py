@@ -1,31 +1,26 @@
-"""Flat-vs-pytree conformance over the golden traces (DESIGN.md §12).
-
-The packed flat fast path is the device engines' default layout, so the
-golden-trace suite already pins it; this module additionally pins the
-*relationship*: on every golden fixture the flat path must produce the
-bit-identical final model of the legacy pytree path (use_kernel=False,
-admit-all — the configurations where XLA:CPU's context-dependent FMA
-contraction is pinned by the fixtures; see DESIGN.md §12 for why bitwise
-equality across program structures cannot be promised universally on this
-backend).  fedasync / active-selection flat runs are pinned to the pytree
-path at ulp tolerance with exact event traces instead.
+"""The device engines' packed layout against the host oracles (DESIGN.md
+§12), on the golden worlds and on fedasync / active-selection worlds:
+exact (round, vehicle[, rsu]) sequence, event times and delay weights to
+f32 tolerance, final parameters at the real-CNN conformance tolerance of
+``tests/test_engine_conformance.py``.
 
 Also covers the bf16 ring mode: explicit opt-in, exact timeline, bounded
-accuracy drift, and the host-engine / pytree gates that refuse it.
+accuracy drift, and the host-engine gates that refuse it.
 """
 import json
 import os
 
-import jax
 import numpy as np
 import pytest
 
 from repro.checkpointing.checkpoint import tree_digest
-from repro.core.codegen import codegen_matches
 from repro.core.scenarios import run_scenario
+from test_engine_conformance import (_assert_conformant,
+                                     _assert_corridor_conformant)
 
 GOLDEN_DIR = os.path.join(os.path.dirname(__file__), "golden")
-FIXTURES = ("paper-k10", "highway-k40-handover", "corridor-quick-r2-k8")
+# the real-CNN bound of test_real_cnn_small_world_conforms
+REAL_CNN_ATOL = 2e-3
 
 
 def _load(name):
@@ -36,87 +31,60 @@ def _load(name):
 _RUNS = {}
 
 
-def _run(name, engine, flat, **kw):
-    key = (name, engine, flat, tuple(sorted(kw.items())))
+def _run(name, engine, **kw):
+    key = (name, engine, tuple(sorted(kw.items())))
     if key not in _RUNS:
         fx = _load(name)
         _RUNS[key] = run_scenario(name, engine=engine, seed=fx["seed"],
-                                  eval_every=fx["eval_every"], flat=flat,
+                                  eval_every=fx["eval_every"],
                                   **dict(fx["overrides"]), **kw)
     return _RUNS[key]
-
-
-def _versions_match(fx) -> bool:
-    """Digest-comparison gate: library versions AND codegen environment
-    must both match the fixture (``repro.core.codegen``) — the committed
-    digests are pinned to the fixture machine's hardware-dependent f32
-    codegen.  The flat==pytree and trace assertions stay unconditional."""
-    return (fx["versions"] == {"jax": jax.__version__,
-                               "numpy": np.__version__}
-            and codegen_matches(fx.get("codegen")))
-
-
-def _device_engines(name):
-    fx = _load(name)
-    return [e for e in fx["engines"] if e in ("jit", "corridor")]
 
 
 def _trace(r):
     return [(rec.round, rec.vehicle, rec.rsu, rec.time) for rec in r.rounds]
 
 
-@pytest.mark.parametrize("name,engine", [
-    (n, e) for n in FIXTURES for e in _device_engines(n)])
-def test_flat_bitwise_matches_pytree_on_golden_world(name, engine):
-    fx = _load(name)
-    flat = _run(name, engine, True)
-    pyt = _run(name, engine, False)
-    assert _trace(flat) == _trace(pyt)
-    assert tree_digest(flat.final_params) == tree_digest(pyt.final_params)
-    if _versions_match(fx):
-        # and both equal the committed fixture — the PR-4 goldens pin the
-        # flat path for free
-        assert tree_digest(flat.final_params) == \
-            fx["engines"][engine]["digest"]
+QUICK = dict(seed=1, eval_every=4, rounds=12)
+ORACLE_CASES = {
+    # golden worlds: the device engine against the serial reference
+    "paper-k10": ("paper-k10", "jit", "serial", None),
+    "highway-k40-handover": ("highway-k40-handover", "corridor", "serial",
+                             None),
+    "corridor-quick-r2-k8": ("corridor-quick-r2-k8", "corridor", "serial",
+                             None),
+    # fedasync's staleness coefficient and the selection fold, against
+    # the batched engine
+    "quick-k5-fedasync": ("quick-k5", "jit", "batched",
+                          dict(QUICK, scheme="fedasync")),
+    "quick-k5-topk": ("quick-k5", "jit", "batched",
+                      dict(QUICK, selection="weighted-topk", selection_k=3,
+                           resel_every=4)),
+}
+
+
+@pytest.mark.parametrize("case", list(ORACLE_CASES))
+def test_device_engine_conforms_to_host_oracle(case):
+    name, engine, oracle, kw = ORACLE_CASES[case]
+    if kw is None:
+        ref, res = _run(name, oracle), _run(name, engine)
+    else:
+        ref = run_scenario(name, engine=oracle, **kw)
+        res = run_scenario(name, engine=engine, **kw)
+    if engine == "corridor":
+        _assert_corridor_conformant(ref, res, param_atol=REAL_CNN_ATOL)
+    else:
+        _assert_conformant({oracle: ref, engine: res},
+                           param_atol=REAL_CNN_ATOL, ref=oracle)
 
 
 @pytest.mark.parametrize("name,engine", [
     ("paper-k10", "jit"), ("corridor-quick-r2-k8", "corridor")])
 def test_flat_admit_all_selection_is_bitwise_noop(name, engine):
-    base = _run(name, engine, True)
-    sel = _run(name, engine, True, selection="admit-all")
+    base = _run(name, engine)
+    sel = _run(name, engine, selection="admit-all")
     assert tree_digest(sel.final_params) == tree_digest(base.final_params)
     assert _trace(sel) == _trace(base)
-
-
-def test_flat_fedasync_matches_pytree_to_ulp_tolerance():
-    """fedasync's staleness coefficient is a pow/mul chain whose FMA
-    contraction XLA:CPU picks per program — exact trace, ulp-level
-    parameter tolerance (DESIGN.md §12)."""
-    a = run_scenario("quick-k5", engine="jit", seed=1, eval_every=4,
-                     rounds=12, scheme="fedasync", flat=False)
-    b = run_scenario("quick-k5", engine="jit", seed=1, eval_every=4,
-                     rounds=12, scheme="fedasync", flat=True)
-    assert _trace(a) == _trace(b)
-    for k in a.final_params:
-        np.testing.assert_allclose(
-            np.asarray(a.final_params[k]), np.asarray(b.final_params[k]),
-            rtol=2e-6, atol=1e-7, err_msg=k)
-
-
-def test_flat_selection_matches_pytree_to_ulp_tolerance():
-    a = run_scenario("quick-k5", engine="jit", seed=1, eval_every=4,
-                     rounds=12, selection="weighted-topk", selection_k=3,
-                     resel_every=4, flat=False)
-    b = run_scenario("quick-k5", engine="jit", seed=1, eval_every=4,
-                     rounds=12, selection="weighted-topk", selection_k=3,
-                     resel_every=4, flat=True)
-    assert _trace(a) == _trace(b)
-    assert a.report.selection == b.report.selection
-    for k in a.final_params:
-        np.testing.assert_allclose(
-            np.asarray(a.final_params[k]), np.asarray(b.final_params[k]),
-            rtol=2e-6, atol=1e-7, err_msg=k)
 
 
 # ---------------------------------------------------------------------------
@@ -144,14 +112,11 @@ def test_bf16_ring_exact_timeline_bounded_drift_corridor():
     assert abs(f32.final_accuracy() - b16.final_accuracy()) <= 0.05
 
 
-def test_bf16_requires_flat_device_engine():
+def test_bf16_requires_device_engine():
     with pytest.raises(ValueError, match="bf16"):
         run_scenario("quick-k5", engine="batched", ring_dtype="bf16")
     with pytest.raises(ValueError, match="bf16"):
         run_scenario("quick-k5", engine="serial", ring_dtype="bf16")
-    with pytest.raises(ValueError, match="flat"):
-        run_scenario("quick-k5", engine="jit", ring_dtype="bf16",
-                     flat=False)
 
 
 def test_fleet_k10000_scenario_registered_with_bf16_ring():

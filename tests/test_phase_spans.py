@@ -119,13 +119,12 @@ def _lowered_text(engine):
             veh, scheme=sc.scheme, rounds=sc.rounds, l_iters=sc.l_iters,
             lr=sc.lr, params=p, seed=0, eval_every=3, use_kernel=True,
             init_params=None, interpretation="mixing", batch_size=128,
-            mesh=None, selection=None, flat=True, ring_dtype=sc.ring_dtype)
+            mesh=None, selection=None, ring_dtype=sc.ring_dtype)
     else:
         prog, args, *_ = corridor_engine._stage_run(
             sc, veh, p, seed=0, eval_every=3, interpretation="mixing",
             use_kernel=True, batch_size=128, mesh=None,
-            record_cohorts=False, init_params=None, selection=None,
-            flat=True)
+            record_cohorts=False, init_params=None, selection=None)
     return prog.lower(*args).as_text(debug_info=True)
 
 

@@ -194,10 +194,8 @@ def test_vmap_rejects_corridor_and_fedbuff_and_varied_alpha():
         run_sweep(spec)
 
 
-def test_vmap_rejects_metrics_kernel_and_pytree():
+def test_vmap_rejects_metrics_and_kernel():
     with pytest.raises(ValueError, match="telemetry|metrics"):
         run_scenario("quick-k5", engine="vmap", seed=0, metrics="on")
     with pytest.raises(ValueError, match="use_kernel"):
         run_scenario("quick-k5", engine="vmap", seed=0, use_kernel=True)
-    with pytest.raises(ValueError, match="flat-only"):
-        run_scenario("quick-k5", engine="vmap", seed=0, flat=False)
