@@ -110,6 +110,21 @@ class SlotGainCache:
             self._last_slot = slot
         return self._cache[slot]
 
+    def gather(self, t, vehicles) -> np.ndarray:
+        """``at(t[j])[vehicles[j]]`` for every j: the chain advances once
+        to the latest slot, which draws the same slots as advancing
+        through them one event at a time (:meth:`RayleighAR1.steps_block`
+        does not depend on how the slots are requested)."""
+        slots = np.asarray(t).astype(np.int64)
+        vehicles = np.asarray(vehicles)
+        out = np.empty(len(slots))
+        if len(slots):
+            self.at(int(slots.max()))
+        for s in np.unique(slots):
+            rows = slots == s
+            out[rows] = self._cache[int(s)][vehicles[rows]]
+        return out
+
     def prune_below(self, t: float) -> None:
         """Drop every slot older than ``int(t)``."""
         keep = int(t)

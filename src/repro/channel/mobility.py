@@ -46,15 +46,21 @@ class Mobility:
         ref = np.array([0.0, 0.0, self.p.H])
         return float(np.linalg.norm(pos - ref))
 
-    def distances(self, t) -> np.ndarray:
-        """All K distances to the RSU at time(s) ``t`` (vectorized Eq. 4,
-        same wrap as :meth:`position`).  The selection layer scores whole
-        fleets at one decision instant, so it reads this instead of K
-        scalar :meth:`distance` calls."""
+    def distance_at(self, i, t) -> np.ndarray:
+        """Distances of vehicle(s) ``i`` at time(s) ``t``, broadcast
+        (vectorized Eq. 4, same wrap as :meth:`position`).  Each element
+        takes :meth:`distance`'s operations in its order, so the two agree
+        bit for bit."""
         span = 2 * self.p.coverage
-        dx = self.x0 + self.p.v * np.asarray(t)
+        dx = self.x0[np.asarray(i)] + self.p.v * np.asarray(t)
         dx = ((dx + self.p.coverage) % span) - self.p.coverage
         return np.sqrt(dx ** 2 + self.p.d_y ** 2 + self.p.H ** 2)
+
+    def distances(self, t) -> np.ndarray:
+        """All K distances to the RSU at time(s) ``t``.  The selection
+        layer scores whole fleets at one decision instant, so it reads
+        this instead of K scalar :meth:`distance` calls."""
+        return self.distance_at(np.arange(self.p.K), t)
 
     def next_boundary_crossing(self, i, t):
         """Earliest time ``> t`` at which vehicle ``i`` reaches the coverage

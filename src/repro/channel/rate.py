@@ -18,5 +18,6 @@ def upload_delay(p: ChannelParams, rate: float) -> float:
 
 
 def training_delay(p: ChannelParams, i: int) -> float:
-    """Eq. (8): C_l = D_i C_y / delta_i   (i is 1-based)."""
+    """Eq. (8): C_l = D_i C_y / delta_i   (i is 1-based).  Broadcasts
+    over an array of indices, each element with the scalar's bits."""
     return p.data_count(i) * p.C_y / p.delta(i)

@@ -97,6 +97,7 @@ class FleetPlan:
     sel: object = None          # SelectionPlan (DESIGN.md §11) or None
     sel_bandit: object = None   # (rew_sum f64[K], rew_cnt f64[K]) or None
     flt: object = None          # FaultPlan (DESIGN.md §16) or None
+    scheduled: dict = None      # uploads scheduled {"bulk": n, "single": m}
 
     def tables(self) -> dict:
         """Fixed-shape padded plan tables for the multi-world sweep tier
@@ -153,8 +154,7 @@ def plan_fleet(p: ChannelParams, seed: int, rounds: int,
     sel = make_selection_state(selection, p, Mobility(p), seed, rounds)
     flt = make_fault_state(faults, p, seed, rounds, l_iters)
     tl = _Timeline(p, seed, cl_scale=None if flt is None else flt.cl_scale)
-    for k in initial_vehicles(sel, flt, p.K):
-        tl.schedule(k, 0.0)
+    tl.schedule_initial(initial_vehicles(sel, flt, p.K))
 
     ev0 = tl.queue.as_struct_arrays()
     if sel is None and flt is None:
@@ -169,8 +169,7 @@ def plan_fleet(p: ChannelParams, seed: int, rounds: int,
         "time": np.full(p.K, np.inf),
         "download_time": np.zeros(p.K),
         "upload_delay": np.zeros(p.K),
-        "train_delay": np.array(
-            [training_delay(p, i) for i in range(1, p.K + 1)]),
+        "train_delay": training_delay(p, np.arange(1, p.K + 1)),
     }
     if flt is not None:
         q0["train_delay"] = q0["train_delay"] * flt.cl_scale
@@ -236,7 +235,8 @@ def plan_fleet(p: ChannelParams, seed: int, rounds: int,
                      q0=q0, sel=None if sel is None else sel.plan(),
                      sel_bandit=None if sel is None
                      else sel.bandit_expectation(),
-                     flt=None if flt is None else flt.plan())
+                     flt=None if flt is None else flt.plan(),
+                     scheduled=dict(tl.counts))
 
 
 # ---------------------------------------------------------------------------
@@ -1015,6 +1015,6 @@ def run_simulation_jit(
         metrics_on=met is not None,
         spec=None if met is None else met.to_json(),
         phases=timers.snapshot(), compile=timers.compile_counts(),
-        memory=memory, selection=sel_summary, faults=flt_report,
-        waves=waves, channels=channels)
+        plan=plan.scheduled, memory=memory, selection=sel_summary,
+        faults=flt_report, waves=waves, channels=channels)
     return result

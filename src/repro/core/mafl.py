@@ -400,6 +400,8 @@ class _Timeline:
     ``distance_fn(vehicle, t) -> meters`` defaults to the single-RSU
     :class:`Mobility`; the multi-RSU scenario engine substitutes its
     corridor geometry while keeping every other scheduling rule identical.
+    It broadcasts over arrays of vehicles and times for
+    :meth:`schedule_initial`.
 
     Channel gains are sampled per discrete slot and kept only for the live
     event window (``SlotGainCache``): pops are globally time-ordered, so
@@ -408,13 +410,21 @@ class _Timeline:
     def __init__(self, p: ChannelParams, seed: int, distance_fn=None,
                  cl_scale=None):
         self.p = p
-        self.distance = distance_fn or Mobility(p).distance
+        if distance_fn is None:
+            mobility = Mobility(p)
+            self.distance = mobility.distance
+            self.distance_at = mobility.distance_at
+        else:
+            self.distance = self.distance_at = distance_fn
         self.gains = SlotGainCache(RayleighAR1(p, seed=seed))
         self.queue = EventQueue()
         self._cycle = [0] * p.K
         # per-vehicle straggler multipliers on the Eq. 8 training delay
         # (DESIGN.md §16) — f64, constant over the run, default identity
         self.cl_scale = cl_scale
+        # uploads scheduled by schedule_initial's column block and by
+        # schedule one at a time (the planners report it)
+        self.counts = {"bulk": 0, "single": 0}
 
     def schedule(self, vehicle: int, t_download: float, payload=None):
         """Vehicle downloads w_g at t_download, trains C_l, uploads C_u.
@@ -424,6 +434,7 @@ class _Timeline:
         global model, so this is what makes the uploads genuinely stale
         (the dynamics the paper's weighting is designed around)."""
         p = self.p
+        self.counts["single"] += 1
         i1 = vehicle + 1                                    # 1-based index
         c_l = training_delay(p, i1)
         if self.cl_scale is not None:
@@ -437,6 +448,37 @@ class _Timeline:
         return self.queue.push(t_up + c_u, vehicle,
                                download_time=t_download, train_delay=c_l,
                                upload_delay=c_u, payload=payload, cycle=cyc)
+
+    def schedule_initial(self, vehicles) -> None:
+        """:meth:`schedule` at t = 0 for each of ``vehicles`` (distinct),
+        as one f64 evaluation entering the queue as one column block.
+
+        Bitwise equal to ``for k in vehicles: schedule(k, 0.0)``, sequence
+        numbers included: every step is the scalar path's own IEEE
+        operation applied elementwise, the gains come from the same AR(1)
+        slots (``SlotGainCache.gather``), and the distance must broadcast
+        with the scalar call's arithmetic (``Mobility.distance_at``,
+        ``CorridorMobility.distance``).  The one exception is the path
+        loss ``d ** -alpha``: ``np.power`` takes SIMD kernels whose low
+        bits differ from the scalar ``pow``, so it is taken per element."""
+        p = self.p
+        v = np.asarray(vehicles, np.int64)
+        c_l = training_delay(p, v + 1)                      # Eq. 8
+        if self.cl_scale is not None:
+            c_l = c_l * np.asarray(self.cl_scale, np.float64)[v]
+        t_up = 0.0 + c_l
+        gain = self.gains.gather(t_up, v)
+        path = np.array([d ** (-p.alpha) for d in
+                         self.distance_at(v, t_up).tolist()], np.float64)
+        rate = p.B * np.log2(1.0 + p.p_m * gain * path / p.sigma2)  # Eq. 5
+        c_u = p.model_bits / np.maximum(rate, 1e-12)                # Eq. 6
+        cyc = np.array(self._cycle, np.int64)
+        self.queue.push_block(t_up + c_u, v, download_time=np.zeros(len(v)),
+                              train_delay=c_l, upload_delay=c_u,
+                              cycle=cyc[v])
+        cyc[v] += 1
+        self._cycle = cyc.tolist()
+        self.counts["bulk"] += len(v)
 
     def prune(self):
         if len(self.queue):

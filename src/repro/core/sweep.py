@@ -652,6 +652,7 @@ def run_simulation_vmap(worlds, *, eval_every: int = 10, batch_size: int = 128,
             metrics_on=False, spec=None, phases=timers.snapshot(),
             compile=timers.compile_counts(), memory=memory_stats(),
             world=veh_w[0].pool.world_counts(len(veh_w)),
+            plan=plan_w.scheduled,
             selection=(None if plan_w.sel is None
                        else plan_w.sel.summary()),
             waves=wave_stats(plan_w.waves, K),

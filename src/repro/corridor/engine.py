@@ -707,8 +707,8 @@ def run_corridor_simulation(
         seed=seed, metrics_on=met is not None,
         spec=None if met is None else met.to_json(),
         phases=timers.snapshot(), compile=timers.compile_counts(),
-        memory=memory, selection=sel_summary, faults=flt_report,
-        waves=waves, channels=channels)
+        plan=plan.scheduled, memory=memory, selection=sel_summary,
+        faults=flt_report, waves=waves, channels=channels)
     return result
 
 

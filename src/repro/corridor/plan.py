@@ -40,6 +40,7 @@ class CorridorPlan:
     sel: object = None          # SelectionPlan (DESIGN.md §11) or None
     sel_bandit: object = None   # (rew_sum f64[K], rew_cnt f64[K]) or None
     flt: object = None          # FaultPlan (DESIGN.md §16) or None
+    scheduled: dict = None      # uploads scheduled {"bulk": n, "single": m}
 
     def tables(self) -> dict:
         """Fixed-shape padded plan tables (DESIGN.md §15) — the corridor
@@ -106,8 +107,7 @@ def plan_corridor(p: ChannelParams, n_rsus: int, seed: int, rounds: int,
                            recheck_every=reconcile_every)
     tl = _Timeline(p, seed, distance_fn=corridor.distance,
                    cl_scale=None if flt is None else flt.cl_scale)
-    for k in initial_vehicles(sel, flt, p.K):
-        tl.schedule(k, 0.0)
+    tl.schedule_initial(initial_vehicles(sel, flt, p.K))
 
     ev0 = tl.queue.as_struct_arrays()
     if sel is None and flt is None:
@@ -121,8 +121,7 @@ def plan_corridor(p: ChannelParams, n_rsus: int, seed: int, rounds: int,
         "time": np.full(p.K, np.inf),
         "download_time": np.zeros(p.K),
         "upload_delay": np.zeros(p.K),
-        "train_delay": np.array(
-            [training_delay(p, i) for i in range(1, p.K + 1)]),
+        "train_delay": training_delay(p, np.arange(1, p.K + 1)),
     }
     if flt is not None:
         q0["train_delay"] = q0["train_delay"] * flt.cl_scale
@@ -202,7 +201,8 @@ def plan_corridor(p: ChannelParams, n_rsus: int, seed: int, rounds: int,
                         sel=None if sel is None else sel.plan(),
                         sel_bandit=None if sel is None
                         else sel.bandit_expectation(),
-                        flt=None if flt is None else flt.plan())
+                        flt=None if flt is None else flt.plan(),
+                        scheduled=dict(tl.counts))
 
 
 def rsu_chain_groups(plan: CorridorPlan, s: int, e: int,

@@ -12,7 +12,9 @@ report splits into:
   spent tracing, lowering and compiling them) and ``memory`` peaks from
   :mod:`repro.telemetry.timers`, and ``world`` counts (vehicles, shard
   rows, host bytes of the pool and its row indices, rows gathered into
-  minibatches, samplers built) from ``ShardPool.world_counts``,
+  minibatches, samplers built) from ``ShardPool.world_counts``, and
+  ``plan`` counts (the planner's uploads scheduled as one column block,
+  ``bulk``, and one at a time, ``single``) on the device engines,
 - plan-derived statics: ``selection`` (the former extras entry) and
   ``waves`` fill/utilization — known before the device runs,
 - device channels (``metrics=on`` only): staleness histogram, occupancy
@@ -62,6 +64,7 @@ class RunReport:
     phases: dict = field(default_factory=dict)
     compile: dict = field(default_factory=dict)     # compile_counts()
     world: dict = field(default_factory=dict)       # world_counts()
+    plan: dict = field(default_factory=dict)        # uploads scheduled
     memory: dict = field(default_factory=dict)
     selection: Optional[dict] = None     # SelectionPlan.summary()
     faults: Optional[dict] = None        # fault spec + decision counts

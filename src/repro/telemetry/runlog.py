@@ -109,6 +109,10 @@ def render(runs: list[dict]) -> str:
                        f"{world['rows_gathered']} rows gathered"
                        + (f", {world['samplers']} samplers built"
                           if "samplers" in world else ""))
+        plan = d.get("plan") or {}
+        if plan:
+            out.append(f"  plan: {plan['bulk']} uploads scheduled as one "
+                       f"block, {plan['single']} one at a time")
         mem = d.get("memory") or {}
         if "peak_rss_bytes" in mem:
             out.append(f"  peak rss: {mem['peak_rss_bytes'] / 2**30:.2f} GiB")
